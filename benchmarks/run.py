@@ -95,6 +95,8 @@ def main():
                     help="reduced sizes for CI-style runs")
     ap.add_argument("--only", nargs="*", default=None)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     benches = {
         "splitnets_fig2": bench_splitnets,
         "serving_plans": bench_serving,

@@ -17,9 +17,11 @@ wrappers kept for API stability; ``run_trace_engine`` /
 Executables are cached on ``(engine, T, A, K, F, n, substeps,
 interval_s, swap)`` — engines are frozen hashable dataclasses — so a
 whole λ-sweep with common shapes compiles exactly once per engine.
-Everything runs under ``jax.experimental.enable_x64`` so the float64
-elementwise physics matches ``env/soa.py``; the global x64 flag is left
-untouched for the rest of the process (models/optimizers stay float32).
+Everything runs under ``jax.enable_x64(True)`` so the float64
+elementwise physics matches ``env/soa.py``.  That scope is a
+thread-local context: the global x64 flag is left untouched for the rest
+of the process (models/optimizers stay float32), and each dispatch
+thread enters it itself.
 """
 from __future__ import annotations
 
@@ -34,7 +36,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.env.cluster import Cluster, make_cluster
 from repro.env.jaxsim import engines, kernels
@@ -199,11 +200,18 @@ def _resolve_substep_impl(substep_impl):
     """Resolve the substep execution strategy: an explicit argument wins,
     then the ``JAXSIM_SUBSTEP_IMPL`` environment variable (how the CI
     Pallas leg flips the whole suite), then the byte-stable ``"xla"``
-    default."""
+    default.  ``"pallas"`` runs in interpret mode and so only on the
+    CPU: anywhere else it raises rather than quietly interpreting."""
     impl = substep_impl or os.environ.get("JAXSIM_SUBSTEP_IMPL", "xla")
     if impl not in ("xla", "pallas", "ref"):
         raise ValueError(f"substep_impl={impl!r} "
                          "(want 'xla', 'pallas' or 'ref')")
+    if impl == "pallas" and jax.default_backend() != "cpu":
+        raise ValueError(
+            f"substep_impl='pallas' is refused on the "
+            f"{jax.default_backend()!r} backend: the edge-substep kernel "
+            "computes in float64, which Mosaic does not lower, so it "
+            "cannot compile for this device (use 'xla')")
     return impl
 
 
@@ -520,7 +528,7 @@ def _run_chunks(prepped):
         i, rl = irl
         with led.span("chunk", parent=parent, idx=i,
                       n_traces=int(rl[1]["valid"].shape[0])):
-            with enable_x64():   # config contexts are thread-local
+            with jax.enable_x64(True):   # config contexts are thread-local
                 return rl[0](rl[1])
 
     if len(prepped) == 1:
@@ -581,43 +589,40 @@ def _es_shard_spec(axes):
     raise ValueError(f"unsupported engine batch axis {axes!r}")
 
 
-def _get_sharded_runner(key, mesh):
+def _sharded_program(key, mesh, donate: bool):
     """``jit(shard_map(vmap(program)))`` over the 1-D grid mesh: every
     device runs the vmapped interval program on its contiguous slice of
     the stacked-trace axis.  Trace leaves and per-cell engine-state
     leaves shard over ``"grid"``; cluster rows and shared engine state
-    replicate.  The trace-leaf and engine-state carries are donated
-    wherever the backend's donation probe passes (``_donation_ok`` —
-    accelerators always, XLA:CPU on the jaxlib builds that actually
-    support donation)."""
+    replicate.  ``donate`` donates the trace-leaf and engine-state
+    arguments."""
+    from jax.sharding import PartitionSpec as P
+    engine = key[0]
+    prog = jax.vmap(_trace_program(*key),
+                    in_axes=(0, None, engine.batch_axes()))
+    # cells are independent, so there is nothing cross-device to
+    # validate: skip the varying-manual-axes check, which the interval
+    # program's fori/while loops do not pass
+    sharded = jax.shard_map(
+        prog, mesh=mesh,
+        in_specs=(P("grid"), P(), _es_shard_spec(engine.batch_axes())),
+        out_specs=P("grid"), check_vma=False)
+    return jax.jit(sharded, donate_argnums=(0, 2) if donate else ())
+
+
+def _get_sharded_runner(key, mesh):
+    """Compile-cached ``_sharded_program``, donating wherever the
+    backend's donation probe passes (``_donation_ok`` — accelerators
+    always, XLA:CPU on the jaxlib builds that actually support
+    donation)."""
     d = int(np.prod(mesh.devices.shape))
     ck = key + ("smap", d)
     hit = ck in _RUNNER_CACHE
     _note_cache(ck, hit)
     if not hit:
-        from jax.sharding import PartitionSpec as P
-        if hasattr(jax, "shard_map"):            # jax >= 0.6
-            smap = jax.shard_map
-        else:
-            from jax.experimental.shard_map import shard_map as smap
-        engine = key[0]
-        with get_ledger().span("compile", engine=engine.name,
+        with get_ledger().span("compile", engine=key[0].name,
                                sharded=True, mesh=d):
-            prog = jax.vmap(_trace_program(*key),
-                            in_axes=(0, None, engine.batch_axes()))
-            # the interval program's while/fori loops have no shard_map
-            # replication rule — skip the rep check (cells are
-            # independent, nothing cross-device to validate); kwarg name
-            # varies by version
-            import inspect
-            chk = {p: False for p in ("check_rep", "check_vma")
-                   if p in inspect.signature(smap).parameters}
-            sharded = smap(prog, mesh=mesh,
-                           in_specs=(P("grid"), P(),
-                                     _es_shard_spec(engine.batch_axes())),
-                           out_specs=P("grid"), **chk)
-            donate = (0, 2) if _donation_ok() else ()
-            _cache_put(ck, jax.jit(sharded, donate_argnums=donate))
+            _cache_put(ck, _sharded_program(key, mesh, _donation_ok()))
     return _cache_get(ck)
 
 
@@ -653,9 +658,14 @@ def _run_grid_sharded(engine, traces, es_builder, cl, cld, K,
     key = _static_key(engine, leaves, K, cl.n, t0.substeps, t0.interval_s,
                       swap_slowdown, substep_impl, telemetry)
     runner = _get_sharded_runner(key, mesh)
-    with get_ledger().span("dispatch", engine=engine.name, sharded=True,
-                           n_traces=G, mesh=d):
+    led = get_ledger()
+    with led.span("dispatch", engine=engine.name, sharded=True,
+                  n_traces=G, mesh=d):
         out = runner(leaves, cld, es0)
+        # where the (padded) cells' outputs live: one counter per device
+        for shard in out["metrics"].addressable_shards:
+            led.count(f"grid.cells_on_device.{shard.device.id}",
+                      int(shard.data.shape[0]))
         return jax.tree_util.tree_map(np.asarray, out)
 
 
@@ -680,7 +690,7 @@ def run_trace_engine(engine, trace, es0, cluster: Optional[Cluster] = None,
     cl = ClusterArrays.from_cluster(cluster)
     K = max_active or default_capacity([trace])
     impl = _resolve_substep_impl(substep_impl)
-    with enable_x64():
+    with jax.enable_x64(True):
         leaves = {k: jnp.asarray(v) for k, v in trace.kernel_dict().items()}
         cld = {k: jnp.asarray(v) for k, v in cl.as_dict().items()}
         es0 = jax.tree_util.tree_map(jnp.asarray, es0)
@@ -733,7 +743,7 @@ def run_grid_engine(engine, traces, es_builder: Callable,
     impl = _resolve_substep_impl(substep_impl)
     if devices is not None:
         _check_grid_homogeneous(traces)
-        with enable_x64():
+        with jax.enable_x64(True):
             cld = {k: jnp.asarray(v) for k, v in cl.as_dict().items()}
             out = _run_grid_sharded(engine, traces, es_builder, cl, cld,
                                     K, swap_slowdown, impl, devices,
@@ -743,7 +753,7 @@ def run_grid_engine(engine, traces, es_builder: Callable,
         chunks, outs = [list(traces)], [out]
     else:
         chunks = _grid_chunks(traces, threads)
-        with enable_x64():
+        with jax.enable_x64(True):
             cld = {k: jnp.asarray(v) for k, v in cl.as_dict().items()}
             A = max(t.max_arrivals for t in traces)
             F = max(t.max_frags for t in traces)
@@ -834,7 +844,7 @@ def _deploy_es(mab_state, theta):
 def _train_es(daso_cfg, mab_state, theta, daso_opt_state, keys):
     """Training-carry starting state; built under ``enable_x64`` so the
     replay window is float64 like the in-carry appends."""
-    with enable_x64():
+    with jax.enable_x64(True):
         import repro.core.daso as daso_mod
         win = daso_mod.window_init(daso_cfg) if daso_cfg is not None else {}
         opt = _trained_opt_state(daso_cfg, theta, daso_opt_state)

@@ -29,6 +29,9 @@ receives a BestFit target in the same interval), so it is omitted.
 """
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -293,7 +296,11 @@ def _run_substeps_fused(state, acc, bw_mult, cl, *, substeps: int,
     inline XLA path below; ``ram`` collapses to its per-task column
     (fragments of one task share one RAM footprint by construction)."""
     if impl == "pallas":
-        from repro.kernels.edge_substep import edge_substep as fn
+        from repro.kernels.edge_substep import edge_substep
+        # interpret mode on the CPU only; elsewhere the kernel would have
+        # to compile (``driver._resolve_substep_impl`` refuses it there)
+        fn = functools.partial(edge_substep,
+                               interpret=jax.default_backend() == "cpu")
     elif impl == "ref":
         from repro.kernels.ref import edge_substep_ref as fn
     else:

@@ -161,8 +161,8 @@ def _daso_assignment(sim, cfg, theta, warm):
     enumeration (admission order, ``max_containers`` head), same
     warm-start logits, same float64 ``optimize_placement`` — so both
     backends feed the feasibility repair identical requests."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core import daso as daso_mod
 
@@ -178,7 +178,7 @@ def _daso_assignment(sim, cfg, theta, warm):
         dec[i] = min(task.decision, 1)
         w = f.worker if f.worker >= 0 else warm[(task.id, f.idx)]
         warm_w[i] = w
-    with enable_x64():
+    with jax.enable_x64(True):
         logits = daso_mod.warm_start_logits(cfg, jnp.asarray(warm_w),
                                             jnp.asarray(rowvalid))
         p_opt, _, _ = daso_mod.optimize_placement(
@@ -225,7 +225,6 @@ def replay_trace_edgesim_trained(trace, mab_state, daso_theta=None,
     (DASO runs) the finetuned ``theta`` under ``"daso_theta"``."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core import daso as daso_mod
     from repro.core import mab as mab_mod
@@ -243,7 +242,7 @@ def replay_trace_edgesim_trained(trace, mab_state, daso_theta=None,
     sim.gen = acc_map
     bestfit = BestFitPlacer()
     acc = MetricsAccumulator(interval_s=trace.interval_s, telemetry=tel)
-    with enable_x64():
+    with jax.enable_x64(True):
         mab = jax.tree_util.tree_map(jnp.asarray, mab_state)
         theta = jax.tree_util.tree_map(jnp.asarray, daso_theta) \
             if daso_theta is not None else None
@@ -258,7 +257,7 @@ def replay_trace_edgesim_trained(trace, mab_state, daso_theta=None,
         sla_n = (trace.arr_sla[t, rows] * 40000.0
                  / np.maximum(trace.arr_batch[t, rows].astype(np.float64),
                               1.0)).astype(np.float32)
-        with enable_x64():
+        with jax.enable_x64(True):
             key_t = jax.random.fold_in(key, t)
             d, _ = mab_mod.decide_train_rows(
                 mab, key_t, jnp.asarray(sla_n),
@@ -271,7 +270,7 @@ def replay_trace_edgesim_trained(trace, mab_state, daso_theta=None,
             head, warm_w, rowvalid, dec = _daso_rows_host(sim, daso_cfg,
                                                           warm)
             feat = sim.state_features()
-            with enable_x64():
+            with jax.enable_x64(True):
                 logits = daso_mod.warm_start_logits(
                     daso_cfg, jnp.asarray(warm_w), jnp.asarray(rowvalid))
                 mask = jnp.asarray(rowvalid, jnp.float64)
@@ -296,7 +295,7 @@ def replay_trace_edgesim_trained(trace, mab_state, daso_theta=None,
         sim.apply_placement(warm)
         stats = sim.advance()
         fin = sorted(stats.finished, key=lambda task: task.id)
-        with enable_x64():
+        with jax.enable_x64(True):
             batch = np.maximum(np.array([task.batch for task in fin],
                                         np.float64), 1.0)
             mab = mab_mod.end_of_interval_masked(
@@ -364,7 +363,6 @@ def replay_trace_edgesim_learned(trace, mab_state, daso_theta=None,
     schema, including the final MAB scalars."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core import mab as mab_mod
     from repro.core.splitplace import BestFitPlacer
@@ -379,7 +377,7 @@ def replay_trace_edgesim_learned(trace, mab_state, daso_theta=None,
     sim.gen = acc_map
     bestfit = BestFitPlacer()
     acc = MetricsAccumulator(interval_s=trace.interval_s, telemetry=tel)
-    with enable_x64():
+    with jax.enable_x64(True):
         mab = jax.tree_util.tree_map(jnp.asarray, mab_state)
         theta = jax.tree_util.tree_map(jnp.asarray, daso_theta) \
             if daso_theta is not None else None
@@ -388,7 +386,7 @@ def replay_trace_edgesim_learned(trace, mab_state, daso_theta=None,
         sla_n = (trace.arr_sla[t, rows] * 40000.0
                  / np.maximum(trace.arr_batch[t, rows].astype(np.float64),
                               1.0)).astype(np.float32)
-        with enable_x64():
+        with jax.enable_x64(True):
             d, _ = mab_mod.decide_ucb_batch(
                 mab, jnp.asarray(sla_n),
                 jnp.asarray(trace.arr_app[t, rows]), ucb_c)
@@ -401,7 +399,7 @@ def replay_trace_edgesim_learned(trace, mab_state, daso_theta=None,
         sim.apply_placement(warm)
         stats = sim.advance()
         fin = sorted(stats.finished, key=lambda task: task.id)
-        with enable_x64():
+        with jax.enable_x64(True):
             batch = np.maximum(np.array([task.batch for task in fin],
                                         np.float64), 1.0)
             mab = mab_mod.end_of_interval_masked(
@@ -450,7 +448,6 @@ def replay_trace_edgesim_static_daso(trace, policy: str, daso_theta=None,
     same algorithm, different bitstreams."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core.splitplace import BestFitPlacer
     from repro.env.jaxsim.driver import STATIC_DASO_ARMS, trace_train_key
@@ -465,13 +462,13 @@ def replay_trace_edgesim_static_daso(trace, policy: str, daso_theta=None,
     sim.gen = acc_map
     bestfit = BestFitPlacer()
     acc = MetricsAccumulator(interval_s=trace.interval_s, telemetry=tel)
-    with enable_x64():
+    with jax.enable_x64(True):
         theta = jax.tree_util.tree_map(jnp.asarray, daso_theta)
         key = trace_train_key(trace.seed)
     for t in range(trace.n_intervals):
         rows = np.nonzero(trace.arr_valid[t])[0]
         if arm < 0:
-            with enable_x64():
+            with jax.enable_x64(True):
                 key_t = jax.random.fold_in(key, t)
                 decisions = np.array(
                     [int(jax.random.bernoulli(jax.random.fold_in(key_t, r)))
@@ -509,7 +506,6 @@ def replay_trace_edgesim_gillis(trace, gillis_state=None,
     same algorithm, different bitstreams."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core import mab as mab_mod
     from repro.core.splitplace import BestFitPlacer
@@ -526,7 +522,7 @@ def replay_trace_edgesim_gillis(trace, gillis_state=None,
     sim.gen = acc_map
     bestfit = BestFitPlacer()
     acc = MetricsAccumulator(interval_s=trace.interval_s, telemetry=tel)
-    with enable_x64():
+    with jax.enable_x64(True):
         layer_ref = jnp.asarray(gillis_layer_ref(num_apps))
         if gillis_state is None:
             Q = mab_mod.gillis_init(num_apps)
@@ -537,7 +533,7 @@ def replay_trace_edgesim_gillis(trace, gillis_state=None,
         key = trace_train_key(trace.seed)
     for t in range(trace.n_intervals):
         rows = np.nonzero(trace.arr_valid[t])[0]
-        with enable_x64():
+        with jax.enable_x64(True):
             key_t = jax.random.fold_in(key, t)
             arms, _ = mab_mod.gillis_decide_rows(
                 Q, eps, key_t, jnp.asarray(trace.arr_sla[t, rows]),
@@ -550,7 +546,7 @@ def replay_trace_edgesim_gillis(trace, gillis_state=None,
         sim.apply_placement(bestfit.place(sim))
         stats = sim.advance()
         fin = sorted(stats.finished, key=lambda task: task.id)
-        with enable_x64():
+        with jax.enable_x64(True):
             sla = jnp.asarray(np.array([task.sla_s for task in fin],
                                        np.float64))
             batch = jnp.asarray(np.array([task.batch for task in fin],

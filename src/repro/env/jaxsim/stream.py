@@ -53,7 +53,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.env.cluster import Cluster, make_cluster
 from repro.env.jaxsim import driver, engines, kernels
@@ -275,14 +274,14 @@ class StreamRunner:
         self.donated = driver._donation_ok()
         self._es0 = es0
         self.carry = None          # built on the first chunk (needs F)
-        with enable_x64():
+        with jax.enable_x64(True):
             self._cld = {k: jnp.asarray(v)
                          for k, v in self.cl.as_dict().items()}
 
     def _ensure_carry(self, F: int):
         if self.carry is not None:
             return
-        with enable_x64():
+        with jax.enable_x64(True):
             state = kernels.init_state(self.K, F, self.cl.n)
             acc = driver._init_acc(self.cl.n)
             es = jax.tree_util.tree_map(jnp.asarray, self._es0)
@@ -291,7 +290,7 @@ class StreamRunner:
     def run_chunk(self, tape: dict) -> np.ndarray:
         """Advance the stream by one chunk tape; returns the chunk's
         ``(T, C)`` float64 telemetry series as NumPy."""
-        with enable_x64():
+        with jax.enable_x64(True):
             leaves = {k: jnp.asarray(v) for k, v in tape.items()}
             frag = leaves["vinstr"] if "vinstr" in leaves \
                 else leaves["instr"]
@@ -305,16 +304,17 @@ class StreamRunner:
             carry, series = runner(leaves, self._cld, prev,
                                    jnp.asarray(self.t0, jnp.int64))
         leaf = jax.tree_util.tree_leaves(carry)[0]
-        assert isinstance(leaf, jax.Array), \
-            "streaming carry left the device"
+        if not isinstance(leaf, jax.Array):
+            raise RuntimeError("streaming carry left the device")
         if self.donated:
             jax.block_until_ready(leaf)
             prev_leaf = jax.tree_util.tree_leaves(prev)[0]
             # the donated input dying in place is the proof that the
             # chunk-to-chunk carry is updated without a second copy of
             # the slot arrays (and never round-trips through the host)
-            assert prev_leaf.is_deleted(), \
-                "streaming carry was copied instead of donated"
+            if not prev_leaf.is_deleted():
+                raise RuntimeError(
+                    "streaming carry was copied instead of donated")
         self.carry = carry
         self.t0 += int(tape["valid"].shape[0])
         self.n_chunks += 1
@@ -526,18 +526,25 @@ def make_stream_policy(policy: str, *, cluster: Optional[Cluster] = None,
     their engine state: ``"mab"``/``"splitplace"`` continue a pretrained
     ``mab_state`` (fresh ``mab.init_state`` when None — cold-start
     serving), ``"splitplace"``/``"mab+gobi"`` add the frozen DASO
-    surrogate, ``"gillis"`` carries its Q-table/ε."""
+    surrogate — ``daso_cfg``/``daso_theta`` are required for them, since
+    without a surrogate the engine would not be SplitPlace's placer —
+    and ``"gillis"`` carries its Q-table/ε."""
     cluster = cluster or make_cluster()
     from repro.env.jaxsim import policies as pol
     if policy in pol.STATIC_POLICIES:
         dec = pol.make_static_decider(policy, mab_state=mab_state)
         return engines.StaticEngine(), (), {"decider": dec}
-    if policy in ("mab", "splitplace", "mab+gobi"):
+    if policy in pol.MAB_LEARNED_POLICIES:
         if mab_state is None:
             from repro.core import mab
             mab_state = mab.init_state(num_apps)
         cfg = daso_cfg
-        if policy == "mab+gobi" and cfg is not None:
+        if policy in pol.DASO_LEARNED_POLICIES and cfg is None:
+            raise ValueError(f"policy {policy!r} places with the DASO "
+                             "surrogate: pass daso_cfg/daso_theta (from "
+                             "launch.experiments.pretrain or "
+                             "seeded_surrogate)")
+        if policy == "mab+gobi":
             cfg = cfg._replace(decision_aware=False)
         if policy == "mab":
             cfg = None

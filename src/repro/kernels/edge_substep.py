@@ -12,7 +12,9 @@ are computed once on loaded values, and the substep loop is a
 substeps — on XLA:CPU the same fusion runs via ``interpret=True``
 (the driver's ``substep_impl="pallas"`` switch), where the kernel
 traces into the surrounding jit instead of bouncing ~10 small tuned
-ops per substep through the scheduler.
+ops per substep through the scheduler.  The kernel computes in float64,
+which Mosaic does not lower, so it does not compile for the TPU yet and
+the driver refuses ``"pallas"`` on any backend but the CPU.
 
 Validated against the pure-jnp oracle ``repro.kernels.ref
 .edge_substep_ref`` (rtol=1e-12 on the float64 carries) and — through
@@ -168,12 +170,13 @@ def edge_substep(instr, done, transfer, stage, task_done, resp, now,
                  metrics, worker, ram_task, out_bytes, nfrag, chain,
                  placed, sla, arrival, acc_t, wait_s, decision, bw_mult,
                  mips, cap, net_bw, *, substeps, dt, swap_slowdown,
-                 nic_cap, interpret=True):
+                 nic_cap, interpret):
     """One interval of fused substep physics; see ``_kernel`` and the
     module docstring.  Argument order is ``CARRY_NAMES + STATIC_NAMES``;
     returns the ``OUT_NAMES`` tuple (updated carries + per-worker busy
-    seconds and completion census).  ``interpret=True`` is the CPU
-    execution mode; the call batches transparently under ``vmap`` (the
+    seconds and completion census).  ``interpret`` has no default: the
+    caller chooses it, ``True`` on the CPU only.  The call batches
+    transparently under ``vmap`` (the
     batching rule prepends a grid axis), which is how the grid driver
     runs one kernel instance per trace cell."""
     n = mips.shape[0]

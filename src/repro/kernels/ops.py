@@ -1,6 +1,6 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On TPU the compiled kernels run natively; on CPU (this container) they run
+On TPU the compiled kernels run natively; on the CPU they run
 in interpret mode so every call is still exercised end-to-end.  Callers use
 these entry points; models fall back to the jnp twins for SPMD tracing
 (Pallas-TPU ops do not lower on the CPU dry-run backend).
@@ -16,7 +16,7 @@ from repro.kernels.selective_scan import selective_scan as _scan
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def flash_attention(q, k, v, causal=True, window=0, q_block=128,

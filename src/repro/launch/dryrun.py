@@ -1,8 +1,13 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch × input shape) on the
 production mesh, prove memory fits, and extract roofline terms.
+
+CPU-only: it forces 512 host devices and runs each combination in a
+child process, so it never holds a chip and nothing on the serving path
+imports it.
 
 Single combo:
     PYTHONPATH=src python -m repro.launch.dryrun --arch tinyllama-1.1b \
@@ -91,7 +96,7 @@ def lower_one(arch: str, shape_name: str, multi_pod: bool, sets=None):
     import jax
     from repro.configs import INPUT_SHAPES, get_config
     from repro.launch import sharding, specs, steps
-    from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
+    from repro.launch.mesh import (PRODUCTION_KIND, chip_peaks,
                                    make_production_mesh, num_chips)
     from repro.optim.optimizers import make_optimizer
 
@@ -174,9 +179,10 @@ def lower_one(arch: str, shape_name: str, multi_pod: bool, sets=None):
     n_active = cfg.active_param_count()
     model_flops = mf_factor * n_active * tokens
 
-    compute_s = flops_g / (chips * PEAK_FLOPS_BF16)
-    memory_s = bytes_g / (chips * HBM_BW)
-    collective_s = coll_dev / ICI_BW       # per-device bytes over link bw
+    peaks = chip_peaks(PRODUCTION_KIND)
+    compute_s = flops_g / (chips * peaks["flops_bf16"])
+    memory_s = bytes_g / (chips * peaks["hbm_bw"])
+    collective_s = coll_dev / peaks["ici_bw"]  # per-device bytes over link bw
 
     result = {
         "arch": arch, "shape": shape_name,

@@ -430,6 +430,19 @@ def run_grid_batched(policy: str = "mc", seeds: Sequence[int] = (0,),
             for (lam, seed), out in zip(cells, outs)]
 
 
+def seeded_surrogate(num_workers: int, seed: int = 0):
+    """SplitPlace's DASO placer without pretraining: a surrogate with
+    random weights drawn from ``seed``, at the sizes of the host
+    ``SurrogatePlacer`` (64 containers, 4 state features, the
+    ``DASOConfig`` network defaults).  Returns ``(theta, cfg)``."""
+    import jax
+
+    from repro.core import daso
+    cfg = daso.DASOConfig(num_workers=num_workers, max_containers=64,
+                          state_features=4)
+    return daso.init_surrogate(jax.random.PRNGKey(seed), cfg), cfg
+
+
 def run_stream(policy: str = "mc", lam: float = 6.0, seed: int = 0,
                target_tasks: int = 10_000, chunk_intervals: int = 64,
                max_active: int = 512, interval_s: float = 300.0,

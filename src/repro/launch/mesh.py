@@ -7,16 +7,37 @@ from __future__ import annotations
 
 import jax
 
-# hardware constants for the roofline (TPU v5e)
-PEAK_FLOPS_BF16 = 197e12       # per chip
-HBM_BW = 819e9                 # B/s per chip
-ICI_BW = 50e9                  # B/s per link
+#: published per-chip peaks keyed by ``device_kind`` (Google Cloud
+#: documentation, "TPU v5e"): bf16 FLOP/s, HBM bytes/s, ICI bytes/s per
+#: link.  Only the dry-run's described v5e pod and the plan engine's
+#: napkin cost model read them; a kind missing here is an error, never
+#: a v5e default.
+CHIP_PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+#: the chip the production mesh describes
+PRODUCTION_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """The peaks of one chip of ``device_kind``; raises for a kind that
+    has no published entry."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device_kind "
+                         f"{device_kind!r} (known: {sorted(CHIP_PEAKS)})"
+                         ) from None
 
 
 def make_production_mesh(*, multi_pod: bool = False):
+    """The 16x16 (or 2x16x16) pod mesh of the dry-run.  Its axes are
+    Auto: ``launch/sharding`` places activations with
+    ``with_sharding_constraint``, which only refers to Auto axes."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_grid_mesh(devices="auto"):

@@ -24,6 +24,8 @@ import numpy as np
 
 
 def _stream_main(args):
+    from repro.env.cluster import make_cluster
+    from repro.env.jaxsim import DASO_LEARNED_POLICIES
     from repro.launch import experiments
 
     pretrain_state = None
@@ -32,6 +34,14 @@ def _stream_main(args):
         wants = ("splitplace",) if args.policy != "gillis" else ("gillis",)
         pretrain_state = experiments.pretrain(args.pretrain, lam=args.lam,
                                               policies=wants)
+    elif args.policy in DASO_LEARNED_POLICIES:
+        theta, cfg = experiments.seeded_surrogate(make_cluster().n,
+                                                  seed=args.seed)
+        pretrain_state = experiments.PretrainState(daso_theta=theta,
+                                                   daso_cfg=cfg)
+        print(f"{args.policy}: DASO placer on an untrained surrogate "
+              f"(random weights from seed {args.seed}; --pretrain N "
+              f"trains one), cold-start MAB")
 
     def progress(i, runner, rolling):
         if i % args.report_every:
@@ -128,6 +138,8 @@ def main(argv=None):
                     help="stream mode: §6.3 pretraining intervals for "
                          "learned policies (0 = cold start)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.stream:
         _stream_main(args)
     else:

@@ -24,14 +24,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models import model as M
 
-# jax-version compat: shard_map moved to the jax namespace (and pvary
-# appeared) after 0.4.x; fall back to the experimental module / identity
-if hasattr(jax, "shard_map"):
-    _smap = jax.shard_map
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _smap
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
 
 def _uniform_kind(cfg):
     kinds = set(cfg.layer_kinds)
@@ -107,16 +99,24 @@ def pipeline_shard_map(params, batch, cfg, mesh: Mesh, num_microbatches: int,
             inbox = jax.lax.ppermute(out, stage_axis, right_perm)
             return (inbox, outputs), None
 
-        inbox0 = _pvary(jnp.zeros(mb_shape, x_mb_local.dtype), (stage_axis,))
-        outputs0 = _pvary(jnp.zeros_like(x_mb_local), (stage_axis,))
+        # zeros of the local shape, not zeros_like: the latter would carry
+        # the replicated input's sharding into the stage-varying carry
+        inbox0 = jax.lax.pcast(jnp.zeros(mb_shape, x_mb_local.dtype),
+                               (stage_axis,), to="varying")
+        outputs0 = jax.lax.pcast(
+            jnp.zeros(x_mb_local.shape, x_mb_local.dtype), (stage_axis,),
+            to="varying")
         (inbox, outputs), _ = jax.lax.scan(tick, (inbox0, outputs0),
                                            jnp.arange(T))
         # every stage returns its buffer; only the last stage's is real
         return outputs[None]
 
     body_specs = jax.tree.map(lambda _: P(stage_axis), body)
-    out = _smap(stage_fn, mesh=mesh,
-                in_specs=(body_specs, P()),
-                out_specs=P(stage_axis))(body, x_mb)
-    x_out = out[S - 1].reshape(b, seq, cfg.d_model)
+    out = jax.shard_map(stage_fn, mesh=mesh,
+                        in_specs=(body_specs, P()),
+                        out_specs=P(stage_axis))(body, x_mb)
+    # the last stage's buffer, replicated; the explicit output sharding
+    # is what an Explicit-axis mesh requires to index a sharded dim
+    x_out = out.at[S - 1].get(out_sharding=NamedSharding(mesh, P()))
+    x_out = x_out.reshape(b, seq, cfg.d_model)
     return M.lm_head(params, x_out, cfg)

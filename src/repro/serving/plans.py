@@ -136,13 +136,15 @@ def plan_cost_model(cfg, plan: PlanSpec, seq: int, batch: int,
     """Napkin latency model (seconds) used to seed the MAB estimates:
     layer pipeline pays sequential stages + hop latency; semantic branches
     run 1/B of the width in parallel."""
-    from repro.launch.mesh import ICI_BW, PEAK_FLOPS_BF16
+    from repro.launch.mesh import PRODUCTION_KIND, chip_peaks
+    peaks = chip_peaks(PRODUCTION_KIND)
     flops = 2.0 * cfg.active_param_count() * seq * batch
     if plan.kind == LAYER_PLAN:
         hop_bytes = batch * seq * cfg.d_model * 2
-        per_stage = flops / plan.num_stages / (chips_per_slice * PEAK_FLOPS_BF16 * 0.4)
+        per_stage = flops / plan.num_stages / (
+            chips_per_slice * peaks["flops_bf16"] * 0.4)
         return plan.num_stages * per_stage + \
-            (plan.num_stages - 1) * hop_bytes / ICI_BW
+            (plan.num_stages - 1) * hop_bytes / peaks["ici_bw"]
     per_branch = (flops / plan.num_branches) / \
-        (chips_per_slice * PEAK_FLOPS_BF16 * 0.4)
+        (chips_per_slice * peaks["flops_bf16"] * 0.4)
     return per_branch

@@ -72,12 +72,12 @@ def _rand_inputs(rng: np.random.RandomState):
 
 @pytest.mark.parametrize("seed", range(8))
 def test_pallas_matches_ref_fuzzed(seed):
-    from jax.experimental import enable_x64
+    import jax
 
     from repro.kernels.edge_substep import OUT_NAMES, edge_substep
     from repro.kernels.ref import edge_substep_ref
     args = _rand_inputs(np.random.RandomState(seed))
-    with enable_x64():       # f64 carries, the driver's execution regime
+    with jax.enable_x64(True):  # f64 carries, the driver's execution regime
         outs_p = edge_substep(*args, **KW, interpret=True)
         outs_r = edge_substep_ref(*args, **KW)
     for name, p, r in zip(OUT_NAMES, outs_p, outs_r):
@@ -94,14 +94,13 @@ def test_pallas_under_vmap_matches_per_row():
     """The grid driver runs the kernel under vmap — the batching rule
     must agree with stacking per-row calls."""
     import jax
-    from jax.experimental import enable_x64
 
     from repro.kernels.edge_substep import edge_substep
 
     rows = [_rand_inputs(np.random.RandomState(100 + i)) for i in range(3)]
     stacked = [np.stack(cols) for cols in zip(*rows)]
     f = lambda *a: edge_substep(*a, **KW, interpret=True)
-    with enable_x64():
+    with jax.enable_x64(True):
         outs_v = jax.vmap(f)(*stacked)
         for i, row in enumerate(rows):
             outs_1 = f(*row)

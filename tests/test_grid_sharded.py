@@ -40,9 +40,14 @@ dec = jaxsim.make_static_decider("mc")
 traces = [jaxsim.compile_trace(dec, lam=lam, seed=s, n_intervals=4,
                                substeps=4)
           for lam in (3.0, 6.0) for s in (0, 1, 2)][:5]
-check("static",
-      jaxsim.run_grid_arrays(traces, threads=2),
-      jaxsim.run_grid_arrays(traces, devices=8))
+from repro.obs import RunLedger, use_ledger
+with use_ledger(RunLedger("sharded")) as led:
+    sharded = jaxsim.run_grid_arrays(traces, devices=8)
+check("static", jaxsim.run_grid_arrays(traces, threads=2), sharded)
+# every device holds one (possibly dead, padded) cell of the 8-cell mesh
+spread = {k: v for k, v in led.counters.items()
+          if k.startswith("grid.cells_on_device.")}
+assert len(spread) == 8 and set(spread.values()) == {1}, spread
 
 st = mab.init_state(3)._replace(
     R=jnp.array([700.0, 1800.0, 3500.0], jnp.float32),

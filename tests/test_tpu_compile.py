@@ -1,0 +1,141 @@
+"""The simulator's device programs compile for a described TPU v5e.
+
+Nothing here runs on a chip: each program is lowered against a
+``v5e:2x2`` topology that JAX describes without one, compiled by the
+TPU compiler, and its memory analysis checked against one chip's
+16 GiB of HBM.  Covered at the serving size (K=512 ring slots, 64
+intervals per chunk, the 50-worker Table-3 fleet):
+
+  * the streaming chunk program for the static ``mc`` engine and for
+    SplitPlace (MAB decider + DASO placer at the host
+    ``SurrogatePlacer`` sizes), on one described chip;
+  * the sharded grid program (``shard_map`` over a 1-D ``"grid"`` mesh)
+    on four described chips, for an 8-cell (seed x λ) grid.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library.
+"""
+import os
+
+import numpy as np
+import pytest
+
+HBM_BYTES = 16 * 2**30
+K, T, LAM = 512, 64, 6.0
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to rehearse
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep the cache off here."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", old)
+
+
+def _specs(tree, sharding_of):
+    """ShapeDtypeStructs of a host/CPU pytree, each leaf placed by
+    ``sharding_of(leaf)``."""
+    import jax
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype,
+                                       sharding=sharding_of(x)), tree)
+
+
+def _fits(compiled, what):
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < total < HBM_BYTES, (what, total)
+    return mem
+
+
+@pytest.mark.parametrize("policy", ["mc", "splitplace"])
+def test_stream_chunk_compiles_for_v5e(policy, one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.env.cluster import make_cluster
+    from repro.env.jaxsim import driver, kernels, stream
+    from repro.env.jaxsim.arrays import ClusterArrays
+    from repro.launch.experiments import seeded_surrogate
+    cluster = make_cluster()
+    theta, cfg = seeded_surrogate(cluster.n, seed=0)
+    engine, es0, feeder_kw = stream.make_stream_policy(
+        policy, cluster=cluster, daso_theta=theta, daso_cfg=cfg)
+    if policy == "splitplace":
+        assert engine.daso_cfg == cfg           # the DASO placer is in
+    feeder = stream.StreamFeeder(lam=LAM, seed=0, cluster=cluster,
+                                 **feeder_kw)
+    tape = feeder.next_chunk(T)
+    frag = tape["vinstr" if "vinstr" in tape else "instr"]
+    with jax.enable_x64(True):
+        cld = ClusterArrays.from_cluster(cluster).as_dict()
+        carry = (kernels.init_state(K, frag.shape[-1], cluster.n),
+                 driver._init_acc(cluster.n),
+                 jax.tree_util.tree_map(jnp.asarray, es0))
+        key = driver._static_key(engine, tape, K, cluster.n, feeder.substeps,
+                                 feeder.interval_s, 0.5, "xla", "stream")
+        prog = jax.jit(driver._stream_program(*key[:-1]), donate_argnums=(2,))
+        on_chip = lambda _: one_chip
+        compiled = prog.lower(_specs(tape, on_chip), _specs(cld, on_chip),
+                              _specs(carry, on_chip),
+                              jax.ShapeDtypeStruct((), jnp.int64,
+                                                   sharding=one_chip)
+                              ).compile()
+    _fits(compiled, policy)
+    assert "f64[" in compiled.as_text()         # the physics stays float64
+
+
+def test_sharded_grid_compiles_for_v5e_2x2(topo):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.env.cluster import make_cluster
+    from repro.env.jaxsim import (compile_trace, default_capacity, driver,
+                                  engines, make_static_decider, stack_traces)
+    from repro.env.jaxsim.arrays import ClusterArrays
+    cluster = make_cluster()
+    dec = make_static_decider("mc")
+    traces = [compile_trace(dec, lam=lam, seed=s, n_intervals=100)
+              for lam in (6.0, 12.0) for s in range(4)]
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("grid",))
+    engine = engines.StaticEngine()
+    with jax.enable_x64(True):
+        leaves = stack_traces(traces)
+        cld = ClusterArrays.from_cluster(cluster).as_dict()
+        key = driver._static_key(engine, leaves, default_capacity(traces),
+                                 cluster.n, traces[0].substeps,
+                                 traces[0].interval_s, 0.5, "xla")
+        prog = driver._sharded_program(key, mesh, donate=True)
+        compiled = prog.lower(
+            _specs(leaves, lambda _: NamedSharding(mesh, P("grid"))),
+            _specs(cld, lambda _: NamedSharding(mesh, P())), ()).compile()
+    _fits(compiled, "sharded grid")
+    out = compiled.output_shardings
+    metrics = out["metrics"]
+    assert metrics.spec == P("grid") and len(metrics.device_set) == 4
