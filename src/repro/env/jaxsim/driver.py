@@ -118,13 +118,11 @@ def _cache_get(ck):
 
 
 def _note_cache(ck, hit: bool):
-    led = get_ledger()
     if hit:
         _CACHE_STATS["hits"] += 1
-        led.count("runner_cache.hit")
         return
     _CACHE_STATS["misses"] += 1
-    led.count("runner_cache.miss")
+    led = get_ledger()
     kr = repr(ck)
     _CACHE_KEYS[kr] = _CACHE_KEYS.get(kr, 0) + 1
     er = repr(ck[0])
@@ -262,66 +260,87 @@ def _telemetry_base_row(state, acc, m0, e0, d0, util, fin):
     return jnp.concatenate([md, extras])
 
 
+#: the phases of one interval, each a stable ``jax.named_scope`` that
+#: names every operation of its hook in the compiled program's metadata
+#: (``op_name``) and so in a device profile of it
+PHASES = ("decide", "admit", "place", "apply", "substeps", "feedback",
+          "telemetry")
+
+
+def _interval(engine, trace, cl, t, state, acc, es, substeps, dt,
+              interval_s, swap_slowdown, substep_impl):
+    """THE hook sequence of one interval, shared by every program: the
+    engine's decide, admission, the engine's place, request apply (with
+    its RAM repair), the physics and the engine's feedback, each under
+    its phase scope.  Returns ``(state, acc, es, util, fin)``, ``fin``
+    the tasks that finished in this interval."""
+    with jax.named_scope("decide"):
+        arr, es = engine.decide(es, trace, t)
+    with jax.named_scope("admit"):
+        state = kernels.admit(state, arr)
+    with jax.named_scope("place"):
+        req, es, aux = engine.place(es, state, cl, trace, t, interval_s)
+    with jax.named_scope("apply"):
+        state = kernels.apply_requests(state, cl, req)
+    prev_done = state["task_done"]
+    with jax.named_scope("substeps"):
+        state, acc, util = _interval_physics(
+            state, acc, trace["bw_mult"][t], cl, substeps, dt, interval_s,
+            swap_slowdown, substep_impl)
+    with jax.named_scope("feedback"):
+        fin = state["task_done"] & ~prev_done
+        es = engine.feedback(es, state, fin, util, aux, t, interval_s)
+        state["alive"] = state["alive"] & ~state["task_done"]
+    return state, acc, es, util, fin
+
+
+def _interval_tel(engine, trace, cl, t, row_t, carry, *args):
+    """``_interval`` plus the telemetry row of the interval, written to
+    row ``row_t`` of the carried ``(T, C)`` series: the interval-entry
+    snapshots the deltas subtract, then the base
+    ``metrics.TELEMETRY_COLS`` columns and the engine's
+    ``telemetry_cols()``."""
+    state, acc, es, series = carry
+    m0, e0, d0 = acc["metrics"], acc["energy"], state["dropped"]
+    state, acc, es, util, fin = _interval(engine, trace, cl, t, state, acc,
+                                          es, *args)
+    with jax.named_scope("telemetry"):
+        row = _telemetry_base_row(state, acc, m0, e0, d0, util, fin)
+        erow = engine.telemetry_row(es)
+        if erow is not None:
+            row = jnp.concatenate([row, erow.astype(jnp.float64)])
+        series = lax.dynamic_update_slice(series, row[None, :], (row_t, 0))
+    return state, acc, es, series
+
+
 def _trace_program(engine, T, A, K, F, n, substeps, interval_s,
                    swap_slowdown, substep_impl="xla", telemetry="summary"):
-    """THE interval program: one carry layout, one hook sequence, every
-    policy.  ``engine`` is compile-time static (part of the cache key);
-    its dynamic state rides the carry as ``es``.
+    """THE interval program: one carry layout, one hook sequence
+    (``_interval``), every policy.  ``engine`` is compile-time static
+    (part of the cache key); its dynamic state rides the carry as
+    ``es``.
 
     ``telemetry="interval"`` appends a preallocated ``(T, C)`` float64
     series to the fori_loop carry and writes one row per interval via
-    ``dynamic_update_slice`` — the base ``metrics.TELEMETRY_COLS``
-    columns plus the engine's ``telemetry_cols()``.  The default
-    ``"summary"`` path is byte-identical to a build without the knob
-    (the telemetry branch never traces), which is what keeps the golden
-    fixtures valid unregenerated."""
+    ``dynamic_update_slice`` (``_interval_tel``).  The default
+    ``"summary"`` path computes no row and takes no interval-entry
+    snapshot, which is what keeps the golden fixtures valid
+    unregenerated."""
     dt = interval_s / substeps
     tel = telemetry == "interval"
     if tel:
         n_cols = len(TELEMETRY_COLS) + len(tuple(engine.telemetry_cols()))
+    hp = (substeps, dt, interval_s, swap_slowdown, substep_impl)
 
     def run_one(trace, cl, es0):
         state = kernels.init_state(K, F, n)
         acc = _init_acc(n)
 
         def interval(t, carry):
-            state, acc, es = carry
-            arr, es = engine.decide(es, trace, t)
-            state = kernels.admit(state, arr)
-            req, es, aux = engine.place(es, state, cl, trace, t, interval_s)
-            state = kernels.apply_requests(state, cl, req)
-            prev_done = state["task_done"]
-            state, acc, util = _interval_physics(
-                state, acc, trace["bw_mult"][t], cl, substeps, dt,
-                interval_s, swap_slowdown, substep_impl)
-            fin = state["task_done"] & ~prev_done
-            es = engine.feedback(es, state, fin, util, aux, t, interval_s)
-            state["alive"] = state["alive"] & ~state["task_done"]
-            return state, acc, es
+            return _interval(engine, trace, cl, t, *carry, *hp)[:3]
 
         def interval_tel(t, carry):
-            # the same hook sequence as ``interval`` (kept verbatim above
-            # so the summary path's trace is untouched), plus the
-            # interval-entry snapshots and the end-of-interval row write
-            state, acc, es, series = carry
-            m0, e0, d0 = acc["metrics"], acc["energy"], state["dropped"]
-            arr, es = engine.decide(es, trace, t)
-            state = kernels.admit(state, arr)
-            req, es, aux = engine.place(es, state, cl, trace, t, interval_s)
-            state = kernels.apply_requests(state, cl, req)
-            prev_done = state["task_done"]
-            state, acc, util = _interval_physics(
-                state, acc, trace["bw_mult"][t], cl, substeps, dt,
-                interval_s, swap_slowdown, substep_impl)
-            fin = state["task_done"] & ~prev_done
-            es = engine.feedback(es, state, fin, util, aux, t, interval_s)
-            state["alive"] = state["alive"] & ~state["task_done"]
-            row = _telemetry_base_row(state, acc, m0, e0, d0, util, fin)
-            erow = engine.telemetry_row(es)
-            if erow is not None:
-                row = jnp.concatenate([row, erow.astype(jnp.float64)])
-            series = lax.dynamic_update_slice(series, row[None, :], (t, 0))
-            return state, acc, es, series
+            return _interval_tel(engine, trace, cl, t, t, carry, *hp)
 
         if tel:
             series0 = jnp.zeros((T, n_cols), jnp.float64)
@@ -396,8 +415,8 @@ class _ShiftedLeaf:
 def _stream_program(engine, T, A, K, F, n, substeps, interval_s,
                     swap_slowdown, substep_impl="xla"):
     """Carry-re-entrant chunk program for the streaming serve driver:
-    the same hook sequence as ``_trace_program``'s telemetry body, but
-    the carry ``(state, acc, es)`` enters as an ARGUMENT and leaves as a
+    ``_trace_program``'s telemetry body (``_interval_tel``), but the
+    carry ``(state, acc, es)`` enters as an ARGUMENT and leaves as a
     result, so consecutive ``chunk_intervals``-sized calls continue one
     endless episode (``T`` here is the chunk length — one compile per
     chunk shape).  ``t0`` is the chunk's absolute start interval, traced
@@ -407,31 +426,13 @@ def _stream_program(engine, T, A, K, F, n, substeps, interval_s,
     it is the substrate of the serving layer's rolling metrics."""
     dt = interval_s / substeps
     n_cols = len(TELEMETRY_COLS) + len(tuple(engine.telemetry_cols()))
+    hp = (substeps, dt, interval_s, swap_slowdown, substep_impl)
 
     def run_chunk(trace, cl, carry, t0):
         tr = {k: _ShiftedLeaf(v, t0) for k, v in trace.items()}
 
         def interval_tel(t, c):
-            state, acc, es, series = c
-            m0, e0, d0 = acc["metrics"], acc["energy"], state["dropped"]
-            arr, es = engine.decide(es, tr, t)
-            state = kernels.admit(state, arr)
-            req, es, aux = engine.place(es, state, cl, tr, t, interval_s)
-            state = kernels.apply_requests(state, cl, req)
-            prev_done = state["task_done"]
-            state, acc, util = _interval_physics(
-                state, acc, tr["bw_mult"][t], cl, substeps, dt,
-                interval_s, swap_slowdown, substep_impl)
-            fin = state["task_done"] & ~prev_done
-            es = engine.feedback(es, state, fin, util, aux, t, interval_s)
-            state["alive"] = state["alive"] & ~state["task_done"]
-            row = _telemetry_base_row(state, acc, m0, e0, d0, util, fin)
-            erow = engine.telemetry_row(es)
-            if erow is not None:
-                row = jnp.concatenate([row, erow.astype(jnp.float64)])
-            series = lax.dynamic_update_slice(series, row[None, :],
-                                              (t - t0, 0))
-            return state, acc, es, series
+            return _interval_tel(engine, tr, cl, t, t - t0, c, *hp)
 
         state, acc, es = carry
         series0 = jnp.zeros((T, n_cols), jnp.float64)

@@ -29,7 +29,9 @@ deciding and placing under a live deadline stream:
   * ``serve`` overlaps the two: a feeder thread fills chunk N+1's
     arrival tape into a small queue while the device executes chunk N
     (double buffering — jitted executions release the GIL), with ledger
-    spans for both sides so the overlap is visible in the run ledger;
+    spans for both sides and for each host step of a chunk
+    (``StreamRunner.run_chunk``), so the overlap is visible in a
+    recording run ledger;
   * rolling metrics (``RollingMetrics``) replace end-of-episode
     summaries: QPS, p50/p99 response, deadline-violation rate and ring
     occupancy over a sliding window of the per-interval telemetry rows
@@ -289,36 +291,53 @@ class StreamRunner:
 
     def run_chunk(self, tape: dict) -> np.ndarray:
         """Advance the stream by one chunk tape; returns the chunk's
-        ``(T, C)`` float64 telemetry series as NumPy."""
+        ``(T, C)`` float64 telemetry series as NumPy.
+
+        Each host step is a span of the active ledger: ``stream_put``
+        (the tape's leaves, the first chunk's carry and ``t0`` onto the
+        device), ``stream_dispatch`` (the cache lookup and the call until
+        it returns), ``stream_sync`` (waiting for the chunk and the
+        donation check) and ``stream_fetch`` (the series back to the
+        host)."""
+        led = get_ledger()
         with jax.enable_x64(True):
-            leaves = {k: jnp.asarray(v) for k, v in tape.items()}
-            frag = leaves["vinstr"] if "vinstr" in leaves \
-                else leaves["instr"]
-            self._ensure_carry(int(frag.shape[-1]))
-            key = driver._static_key(self.engine, leaves, self.K,
-                                     self.cl.n, self.substeps,
-                                     self.interval_s, self.swap_slowdown,
-                                     self.impl, "stream")
-            runner = driver._get_stream_runner(key)
-            prev = self.carry
-            carry, series = runner(leaves, self._cld, prev,
-                                   jnp.asarray(self.t0, jnp.int64))
+            attrs = {"leaves": len(tape),
+                     "bytes": sum(int(v.nbytes) for v in tape.values())} \
+                if led.record else {}
+            with led.span("stream_put", **attrs):
+                leaves = {k: jnp.asarray(v) for k, v in tape.items()}
+                frag = leaves["vinstr"] if "vinstr" in leaves \
+                    else leaves["instr"]
+                self._ensure_carry(int(frag.shape[-1]))
+                t0 = jnp.asarray(self.t0, jnp.int64)
+            with led.span("stream_dispatch"):
+                key = driver._static_key(self.engine, leaves, self.K,
+                                         self.cl.n, self.substeps,
+                                         self.interval_s,
+                                         self.swap_slowdown, self.impl,
+                                         "stream")
+                runner = driver._get_stream_runner(key)
+                prev = self.carry
+                carry, series = runner(leaves, self._cld, prev, t0)
         leaf = jax.tree_util.tree_leaves(carry)[0]
         if not isinstance(leaf, jax.Array):
             raise RuntimeError("streaming carry left the device")
-        if self.donated:
-            jax.block_until_ready(leaf)
-            prev_leaf = jax.tree_util.tree_leaves(prev)[0]
-            # the donated input dying in place is the proof that the
-            # chunk-to-chunk carry is updated without a second copy of
-            # the slot arrays (and never round-trips through the host)
-            if not prev_leaf.is_deleted():
-                raise RuntimeError(
-                    "streaming carry was copied instead of donated")
+        with led.span("stream_sync"):
+            if self.donated:
+                jax.block_until_ready(leaf)
+                prev_leaf = jax.tree_util.tree_leaves(prev)[0]
+                # the donated input dying in place is the proof that the
+                # chunk-to-chunk carry is updated without a second copy
+                # of the slot arrays (and never round-trips through the
+                # host)
+                if not prev_leaf.is_deleted():
+                    raise RuntimeError(
+                        "streaming carry was copied instead of donated")
         self.carry = carry
         self.t0 += int(tape["valid"].shape[0])
         self.n_chunks += 1
-        return np.asarray(series)
+        with led.span("stream_fetch"):
+            return np.asarray(series)
 
     # --------------------------------------------------------- summary
 

@@ -9,12 +9,17 @@ how the runner cache behaved (``driver.cache_stats()`` counters feed
 measured (``provenance_stamp`` — the single shared helper behind the
 benchmark artifact stamps in ``benchmarks/_provenance``).
 
-One process-global ledger is always active (``get_ledger``); scoped
-recording swaps it with ``use_ledger``.  Recording is cheap — a lock
-plus a dict append per event — so the driver instruments every run
-unconditionally and benchmarks stay honest.  ``tools/obs_report.py``
-renders a dumped ledger into a text report (span tree, cache stats,
-sparkline interval curves).
+The process-global default ledger (``get_ledger`` outside any scope)
+records nothing: the driver and the streaming path instrument every
+call, and with recording off each span, counter or warning costs one
+flag test, so a long serve keeps flat memory.  ``use_ledger`` scopes a
+recording ``RunLedger``: a lock plus a dict append per event.  Each span
+keeps its start on the ``time.perf_counter`` clock beside its duration;
+a ledger made with ``annotate=True`` also opens a
+``jax.profiler.TraceAnnotation("repro.<span>")`` around each span, so a
+profiler trace shows the program's spans on the device events' clock.
+``tools/obs_report.py`` renders a dumped ledger into a text report
+(span tree, cache stats, sparkline interval curves).
 """
 from __future__ import annotations
 
@@ -22,7 +27,13 @@ import json
 import os
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
+
+#: what a span of a ledger that records nothing enters
+_OFF = nullcontext()
+
+#: prefix of the profiler annotations of an ``annotate=True`` ledger
+ANNOTATION_PREFIX = "repro."
 
 
 def provenance_stamp(**knobs) -> dict:
@@ -49,10 +60,16 @@ class RunLedger:
     """Append-only trace of one run: spans (nested via a thread-local
     stack, or an explicit ``parent=`` id for worker threads), counters,
     warnings, named interval series, and an optional cache-stats
-    snapshot.  ``dump`` writes one JSON object per line."""
+    snapshot.  ``dump`` writes one JSON object per line.
 
-    def __init__(self, name: str = "run"):
+    ``record=False`` (the process default) keeps nothing;
+    ``annotate=True`` also writes every span into a running profiler
+    trace as ``repro.<name>``."""
+
+    def __init__(self, name: str = "run", annotate: bool = False,
+                 record: bool = True):
         self.name = name
+        self.record = record
         self.created_s = time.time()
         self.provenance = None
         self.cache_stats = None
@@ -62,6 +79,10 @@ class RunLedger:
         self._lock = threading.Lock()
         self._tls = threading.local()
         self._next_id = 0
+        self._annotation = None
+        if annotate:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
 
     # ------------------------------------------------------------ spans
 
@@ -77,37 +98,57 @@ class RunLedger:
         st = self._stack()
         return st[-1] if st else None
 
-    @contextmanager
     def span(self, name: str, parent=None, **attrs):
-        """Record a wall-clock span.  Nesting comes from the per-thread
-        span stack; ``parent`` overrides it (how thread-pool chunk spans
-        attach under the dispatch span that forked them)."""
+        """Record a wall-clock span: its start (``perf_counter``) and
+        duration.  Nesting comes from the per-thread span stack;
+        ``parent`` overrides it (how thread-pool chunk spans attach under
+        the dispatch span that forked them).  A ledger that records
+        nothing returns a shared no-op context."""
+        if not self.record:
+            return _OFF
+        return self._span(name, parent, attrs)
+
+    @contextmanager
+    def _span(self, name, parent, attrs):
         with self._lock:
             sid = self._next_id
             self._next_id += 1
         st = self._stack()
         pid = parent if parent is not None else (st[-1] if st else None)
+        ann = self._annotation(ANNOTATION_PREFIX + name) \
+            if self._annotation else _OFF
         st.append(sid)
         t0 = time.perf_counter()
         try:
-            yield sid
+            with ann:
+                yield sid
         finally:
             dur = time.perf_counter() - t0
             st.pop()
             ev = {"kind": "span", "id": sid, "parent": pid, "name": name,
-                  "dur_s": dur}
+                  "start_s": t0, "dur_s": dur}
             if attrs:
                 ev["attrs"] = attrs
             with self._lock:
                 self.events.append(ev)
 
+    def spans(self, name: str):
+        """The recorded spans called ``name``, in the order they ended."""
+        with self._lock:
+            return [e for e in self.events
+                    if e["kind"] == "span" and e["name"] == name]
+
     # ------------------------------------------- counters / warnings / data
 
     def count(self, name: str, n: int = 1):
+        if not self.record:
+            return
         with self._lock:
             self.counters[name] = self.counters.get(name, 0) + n
 
     def warn(self, message: str, **attrs):
+        if not self.record:
+            return
         ev = {"kind": "warning", "message": message}
         if attrs:
             ev["attrs"] = attrs
@@ -121,6 +162,8 @@ class RunLedger:
     def add_series(self, name: str, cols, data):
         """Attach a named (T, C) interval series (e.g. one trace's
         ``summary["telemetry"]`` payload) for the report's curves."""
+        if not self.record:
+            return
         import numpy as np
         arr = np.asarray(data, np.float64)
         if arr.ndim != 2 or arr.shape[1] != len(tuple(cols)):
@@ -133,6 +176,8 @@ class RunLedger:
     def add_cache_stats(self, stats: dict):
         """Snapshot ``driver.cache_stats()`` into the ledger (last call
         wins — take it after the runs you are reporting on)."""
+        if not self.record:
+            return
         with self._lock:
             self.cache_stats = dict(stats)
 
@@ -189,12 +234,12 @@ def load_ledger_lines(path: str):
         return [json.loads(ln) for ln in f if ln.strip()]
 
 
-_ACTIVE = RunLedger("default")
+_ACTIVE = RunLedger("default", record=False)
 
 
 def get_ledger() -> RunLedger:
-    """The currently-active ledger (a process-global default unless a
-    ``use_ledger`` scope is open)."""
+    """The currently-active ledger: the process-global default, which
+    records nothing, unless a ``use_ledger`` scope is open."""
     return _ACTIVE
 
 

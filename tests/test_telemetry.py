@@ -1,6 +1,6 @@
 """Interval telemetry + run-ledger observability contracts.
 
-Five pins, mirroring docs/ARCHITECTURE.md's "Observability" section:
+Six pins, mirroring docs/ARCHITECTURE.md's "Observability" section:
 
   * **series parity** — the kernel's in-carry ``(T, C)`` telemetry
     series (``telemetry="interval"``) matches the host replay oracles
@@ -11,8 +11,8 @@ Five pins, mirroring docs/ARCHITECTURE.md's "Observability" section:
   * **zero-perturbation** — a ``telemetry="interval"`` run's summary
     scalars are identical (rtol=1e-12) to the ``"summary"`` run of the
     same trace: recording the series must not perturb the physics or
-    the learning carries (the summary-mode interval body is verbatim,
-    so this is near-bitwise);
+    the learning carries (both modes run the one hook sequence,
+    ``driver._interval``, so this is near-bitwise);
   * **percentile bound** — kernel-path binned p50/p95/p99 estimates sit
     within the reported ``percentile_err_s`` of the host's exact
     percentiles, and the host's own error is exactly 0;
@@ -21,7 +21,11 @@ Five pins, mirroring docs/ARCHITECTURE.md's "Observability" section:
     static shapes) raises a ledger warning;
   * **RunLedger round-trip** — spans nest, JSONL dump/load round-trips,
     and ``tools/obs_report.py`` renders the cache and span sections the
-    CI smoke step greps for.
+    CI smoke step greps for;
+  * **program spans** — spans keep their start, the process default
+    ledger records nothing over a long stream, ``annotate=True`` writes
+    ``repro.`` profiler annotations, and ``StreamRunner.run_chunk``
+    records its put, dispatch, sync and fetch steps.
 """
 import json
 import os
@@ -142,8 +146,9 @@ def test_series_parity_gillis():
 
 def test_interval_mode_preserves_summary():
     """Turning the series on must not move any summary scalar: the
-    interval-mode body duplicates the summary-mode hooks verbatim, so
-    everything the ``"summary"`` run reports is reproduced at 1e-12."""
+    interval-mode body runs the summary-mode hooks (``driver._interval``)
+    and adds the row, so everything the ``"summary"`` run reports is
+    reproduced at 1e-12."""
     from repro.env import jaxsim
     dec = jaxsim.make_static_decider("bestfit-rr")
     tr = jaxsim.compile_trace(dec, lam=5.0, seed=0, n_intervals=8,
@@ -268,3 +273,108 @@ def test_provenance_stamp_keys():
         assert k in st, st
     assert st["telemetry"] == "interval"
     assert json.dumps(st)                  # JSON-serializable
+
+
+# ------------------------------------------- program spans on the trace
+
+
+def test_spans_carry_starts():
+    """Every span keeps its ``perf_counter`` start beside its duration,
+    so spans can be laid on a profiler trace's clock."""
+    import time
+
+    from repro.obs import RunLedger
+    led = RunLedger("starts")
+    t_before = time.perf_counter()
+    with led.span("outer"):
+        with led.span("inner", k=1):
+            time.sleep(0.002)
+    t_after = time.perf_counter()
+    outer, = led.spans("outer")
+    inner, = led.spans("inner")
+    assert t_before <= outer["start_s"] <= inner["start_s"]
+    assert inner["start_s"] + inner["dur_s"] \
+        <= outer["start_s"] + outer["dur_s"] <= t_after
+    assert inner["dur_s"] >= 0.002 and inner["attrs"] == {"k": 1}
+    assert inner["parent"] == outer["id"]
+
+
+def test_default_ledger_records_nothing_over_a_long_stream():
+    """The process-global ledger keeps nothing: a 200-chunk stream and
+    a short ``serve`` leave it empty, so serving memory stays flat."""
+    from repro.env import jaxsim
+    from repro.env.jaxsim import stream
+    from repro.obs import get_ledger
+    led = get_ledger()
+    assert not led.record
+    dec = jaxsim.make_static_decider("mc")
+    tr = jaxsim.compile_trace(dec, lam=3.0, seed=0, n_intervals=200,
+                              substeps=2)
+    eng = jaxsim.engines.StaticEngine()
+    stream.replay_stream(eng, tr, (), chunk_intervals=1)
+    eng, es0, fkw = stream.make_stream_policy("mc")
+    feeder = stream.StreamFeeder(lam=3.0, seed=0, substeps=2, **fkw)
+    stream.serve(eng, es0, feeder, chunk_intervals=2, max_active=32,
+                 target_tasks=60)
+    assert get_ledger() is led
+    assert led.events == [] and led.counters == {} and led.series == []
+    assert led.cache_stats is None
+
+
+def test_annotated_ledger_writes_repro_annotations(tmp_path):
+    """``annotate=True`` opens a ``repro.<span>`` profiler annotation
+    around each span; a ledger without it writes none."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.obs import RunLedger
+    from repro.obs.ledger import ANNOTATION_PREFIX
+    on, off = RunLedger("on", annotate=True), RunLedger("off")
+    jax.profiler.start_trace(str(tmp_path))
+    with on.span("stream_put"):
+        with on.span("inner"):
+            pass
+    with off.span("unannotated"):
+        pass
+    jax.profiler.stop_trace()
+    path, = tmp_path.glob("**/*.xplane.pb")
+    names = [e.name for plane in ProfileData.from_file(str(path)).planes
+             for ln in plane.lines for e in ln.events]
+    got = sorted(n for n in names if n.startswith(ANNOTATION_PREFIX))
+    assert got == ["repro.inner", "repro.stream_put"], got
+    assert {e["name"] for e in off.events} == {"unannotated"}
+
+
+def test_run_chunk_records_its_four_steps():
+    """Under a recording ledger each ``StreamRunner.run_chunk`` call
+    records put, dispatch, sync and fetch once, in that order and
+    without overlap; the put span names the tape's leaves and bytes."""
+    from repro.env import jaxsim
+    from repro.env.jaxsim import arrays, stream
+    from repro.obs import RunLedger, use_ledger
+    steps = ("stream_put", "stream_dispatch", "stream_sync",
+             "stream_fetch")
+    dec = jaxsim.make_static_decider("mc")
+    tr = jaxsim.compile_trace(dec, lam=3.0, seed=0, n_intervals=6,
+                              substeps=2)
+    tapes = [tape for _, tape in arrays.chunk_tapes(tr, 2)]
+    r = stream.StreamRunner(jaxsim.engines.StaticEngine(), (),
+                            interval_s=tr.interval_s, substeps=tr.substeps,
+                            max_active=jaxsim.default_capacity([tr]))
+    led = RunLedger("chunks")
+    with use_ledger(led):
+        for tape in tapes:
+            r.run_chunk(tape)
+    spans = [e for e in led.events
+             if e["kind"] == "span" and e["name"] in steps]
+    assert len(spans) == 4 * len(tapes)
+    for i in range(len(tapes)):
+        chunk = sorted(spans[4 * i:4 * i + 4], key=lambda e: e["start_s"])
+        assert tuple(e["name"] for e in chunk) == steps
+        for a, b in zip(chunk, chunk[1:]):
+            assert a["start_s"] + a["dur_s"] <= b["start_s"]
+        put = chunk[0]["attrs"]
+        assert put["leaves"] == len(tapes[i])
+        assert put["bytes"] == sum(v.nbytes for v in tapes[i].values())
+    assert not any(k.startswith("runner_cache.h")
+                   or k.startswith("runner_cache.m") for k in led.counters)

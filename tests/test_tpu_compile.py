@@ -8,7 +8,8 @@ intervals per chunk, the 50-worker Table-3 fleet):
 
   * the streaming chunk program for the static ``mc`` engine and for
     SplitPlace (MAB decider + DASO placer at the host
-    ``SurrogatePlacer`` sizes), on one described chip;
+    ``SurrogatePlacer`` sizes), on one described chip, with every phase
+    scope of the interval body in its operations' metadata;
   * the sharded grid program (``shard_map`` over a 1-D ``"grid"`` mesh)
     on four described chips, for an 8-cell (seed x λ) grid.
 
@@ -73,8 +74,10 @@ def _fits(compiled, what):
     return mem
 
 
-@pytest.mark.parametrize("policy", ["mc", "splitplace"])
-def test_stream_chunk_compiles_for_v5e(policy, one_chip):
+@pytest.fixture(scope="module", params=["mc", "splitplace"])
+def stream_compiled(request, one_chip):
+    """(policy, the stream chunk program compiled for one described
+    v5e chip), one compile per engine for every test that reads it."""
     import jax
     import jax.numpy as jnp
 
@@ -82,6 +85,7 @@ def test_stream_chunk_compiles_for_v5e(policy, one_chip):
     from repro.env.jaxsim import driver, kernels, stream
     from repro.env.jaxsim.arrays import ClusterArrays
     from repro.launch.experiments import seeded_surrogate
+    policy = request.param
     cluster = make_cluster()
     theta, cfg = seeded_surrogate(cluster.n, seed=0)
     engine, es0, feeder_kw = stream.make_stream_policy(
@@ -106,8 +110,28 @@ def test_stream_chunk_compiles_for_v5e(policy, one_chip):
                               jax.ShapeDtypeStruct((), jnp.int64,
                                                    sharding=one_chip)
                               ).compile()
+    return policy, compiled
+
+
+def test_stream_chunk_compiles_for_v5e(stream_compiled):
+    policy, compiled = stream_compiled
     _fits(compiled, policy)
     assert "f64[" in compiled.as_text()         # the physics stays float64
+
+
+def test_stream_chunk_carries_phase_scopes(stream_compiled):
+    """Every phase scope of the interval body survives the TPU
+    compiler into the operations' ``op_name`` metadata, inside the
+    chunk loop's body, where a device profile reads it."""
+    import re
+
+    from repro.env.jaxsim import driver
+    policy, compiled = stream_compiled
+    bodies = {"/" + n.split("/while/body/", 1)[1]
+              for n in re.findall(r'op_name="([^"]*)"', compiled.as_text())
+              if "/while/body/" in n}
+    for phase in driver.PHASES:
+        assert any(f"/{phase}/" in b for b in bodies), (policy, phase)
 
 
 def test_sharded_grid_compiles_for_v5e_2x2(topo):
