@@ -19,6 +19,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import sliced_dot
 from repro.optim.optimizers import adamw_init, adamw_update
 
 
@@ -51,12 +52,30 @@ def init_surrogate(key, cfg: DASOConfig):
             for k, a, b in zip(ks, dims[:-1], dims[1:])]
 
 
-def surrogate_apply(theta, x):
-    for i, layer in enumerate(theta):
-        x = x @ layer["w"] + layer["b"]
+def _plain(w, x_dtype):
+    return lambda x: x @ w
+
+
+def _sliced(w, x_dtype):
+    """The TPU's ascent dot: for a float64 product, ``w`` cut once into
+    integer slices for the MXU (``core/sliced_dot``); others keep ``@``."""
+    if jnp.result_type(x_dtype, w.dtype) != jnp.float64:
+        return _plain(w, x_dtype)
+    sw = sliced_dot.slice_weight(w.astype(jnp.float64))
+    return lambda x: sliced_dot.sliced_matmul(x, sw)
+
+
+def _forward(theta, muls, x):
+    for i, (layer, mul) in enumerate(zip(theta, muls)):
+        x = mul(x) + layer["b"]
         if i < len(theta) - 1:
             x = jnp.tanh(x)
     return x[..., 0]
+
+
+def surrogate_apply(theta, x):
+    return _forward(theta, [_plain(layer["w"], x.dtype) for layer in theta],
+                    x)
 
 
 def pack_input(cfg: DASOConfig, state, placement, decisions, mask):
@@ -210,10 +229,30 @@ def optimize_placement(cfg: DASOConfig, theta, state, placement0, decisions,
     """Gradient ascent of the surrogate w.r.t. placement logits (eq. 12).
 
     Iterates with momentum until the L2 step norm falls below tol (or
-    place_iters), mirroring GOBI's converged-iteration rule.
+    place_iters), mirroring GOBI's converged-iteration rule.  Lowered for
+    the TPU, the ascent's surrogate products run as exact integer slices
+    on the MXU (``_sliced``); elsewhere as the plain float64 dot.
     """
+    def ascend(dot):
+        return lambda: _ascend(cfg, theta, state, placement0, decisions,
+                               mask, dot)
+
+    p, iters = jax.lax.platform_dependent(tpu=ascend(_sliced),
+                                          default=ascend(_plain))
+    score = surrogate_apply(theta, pack_input(cfg, state, p, decisions, mask))
+    return p, score, iters
+
+
+def _ascend(cfg, theta, state, placement0, decisions, mask, dot):
+    """The ascent of ``optimize_placement``, with each layer's product
+    ``x @ w`` taken as ``dot(w, x0.dtype)(x)``; ``dot`` prepares its
+    weights once, before the loop."""
+    x0 = jax.eval_shape(
+        lambda: pack_input(cfg, state, placement0, decisions, mask))
+    muls = [dot(layer["w"], x0.dtype) for layer in theta]
+
     def score(p):
-        return surrogate_apply(theta, pack_input(cfg, state, p, decisions,
+        return _forward(theta, muls, pack_input(cfg, state, p, decisions,
                                                  mask))
 
     def cond(carry):
@@ -228,10 +267,11 @@ def optimize_placement(cfg: DASOConfig, theta, state, placement0, decisions,
         delta = jnp.linalg.norm(new_p - p)
         return new_p, vel, i + 1, delta
 
+    # a strongly typed ``inf``, as ``body`` returns: the loop traces once
     p, _, iters, _ = jax.lax.while_loop(
         cond, body, (placement0, jnp.zeros_like(placement0),
-                     jnp.asarray(0), jnp.asarray(jnp.inf)))
-    return p, score(p), iters
+                     jnp.asarray(0), jnp.asarray(jnp.inf, placement0.dtype)))
+    return p, iters
 
 
 def placement_to_assignment(placement_logits, mask):

@@ -320,3 +320,41 @@ def test_feeder_requires_exactly_one_mode():
         stream.StreamFeeder(lam=3.0,
                             decider=jaxsim.make_static_decider("mc"),
                             variants=jaxsim.engines.MAB_VARIANTS)
+
+
+@pytest.mark.parametrize("policy,dots", [("mc", 0), ("splitplace", 8)])
+def test_chunk_program_counts_sliced_dots(policy, dots):
+    """Tracing one chunk program under a recording ledger counts the
+    DASO ascent's sliced dots once per traced ascent: 8 for SplitPlace
+    (one ascent in the interval body; four layers, forward and
+    backward), none for ``mc``, which runs no ascent."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import daso
+    from repro.env.cluster import make_cluster
+    from repro.env.jaxsim import driver, kernels, stream
+    from repro.env.jaxsim.arrays import ClusterArrays
+    from repro.launch.experiments import seeded_surrogate
+    from repro.obs import RunLedger, use_ledger
+    cluster = make_cluster()
+    theta, cfg = seeded_surrogate(cluster.n, seed=0)
+    engine, es0, feeder_kw = stream.make_stream_policy(
+        policy, cluster=cluster, daso_theta=theta, daso_cfg=cfg)
+    feeder = stream.StreamFeeder(lam=6.0, seed=0, cluster=cluster,
+                                 **feeder_kw)
+    tape = feeder.next_chunk(2)
+    frag = tape["vinstr" if "vinstr" in tape else "instr"]
+    K = 48
+    ledger = RunLedger("trace")
+    daso.optimize_placement.clear_cache()      # trace the ascent afresh
+    with jax.enable_x64(True), use_ledger(ledger):
+        cld = ClusterArrays.from_cluster(cluster).as_dict()
+        carry = (kernels.init_state(K, frag.shape[-1], cluster.n),
+                 driver._init_acc(cluster.n),
+                 jax.tree_util.tree_map(jnp.asarray, es0))
+        key = driver._static_key(engine, tape, K, cluster.n, feeder.substeps,
+                                 feeder.interval_s, 0.5, "xla", "stream")
+        jax.jit(driver._stream_program(*key[:-1])).trace(
+            tape, cld, carry, jnp.asarray(0, jnp.int64))
+    assert ledger.counters.get("daso.sliced_dot", 0) == dots

@@ -9,7 +9,8 @@ intervals per chunk, the 50-worker Table-3 fleet):
   * the streaming chunk program for the static ``mc`` engine and for
     SplitPlace (MAB decider + DASO placer at the host
     ``SurrogatePlacer`` sizes), on one described chip, with every phase
-    scope of the interval body in its operations' metadata;
+    scope of the interval body in its operations' metadata, and the
+    DASO ascent's float64 products as int8 dots on the MXU;
   * the sharded grid program (``shard_map`` over a 1-D ``"grid"`` mesh)
     on four described chips, for an 8-cell (seed x λ) grid.
 
@@ -132,6 +133,84 @@ def test_stream_chunk_carries_phase_scopes(stream_compiled):
               if "/while/body/" in n}
     for phase in driver.PHASES:
         assert any(f"/{phase}/" in b for b in bodies), (policy, phase)
+
+
+def _computations(text):
+    """{computation name: its instruction lines} of an HLO module."""
+    import re
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if m and not line.startswith(" "):
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and line.startswith("  "):
+            cur.append(line)
+    return comps
+
+
+def _closure(comps, root):
+    """``root`` and every computation it calls, transitively."""
+    import re
+    seen, todo = set(), [root]
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            todo += re.findall(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)",
+                               line)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                todo += [b.strip().lstrip("%") for b in group.split(",")]
+    return seen
+
+
+def test_stream_ascent_dots_run_on_the_mxu(stream_compiled):
+    """SplitPlace's DASO ascent runs its float64 surrogate products as
+    int8 x int8 -> int32 dots (``core/sliced_dot``) under the ``place``
+    scope of the chunk loop's body.  Inside the ascent's loop every
+    array as large as the first-layer weight is int8, the weight's
+    slices as read: no float copy of it, no per-step limb split or cut
+    (no ``X64SplitHigh`` of it).  ``mc``, which runs no ascent, has no
+    integer dot."""
+    import math
+    import re
+
+    from repro.core import daso
+    from repro.launch.experiments import seeded_surrogate
+    policy, compiled = stream_compiled
+    text = compiled.as_text()
+    comps = _computations(text)
+    types = dict(re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (\S+) ", text,
+                            re.M))
+    int_dot = re.compile(r"= s32\[[^\]]*\]\S* (?:convolution|dot)\(([^)]*)\)")
+
+    def int_dots(names):
+        return [line for c in names for line in comps[c]
+                if (m := int_dot.search(line)) and all(
+                    types.get(a.strip().lstrip("%"), "").startswith("s8[")
+                    for a in m.group(1).split(","))]
+
+    if policy == "mc":
+        assert not int_dots(comps)
+        return
+    loops = [_closure(comps, body) for lines in comps.values()
+             for line in lines
+             for body in re.findall(r" while\(.*body=%?([\w.\-]+)", line)]
+    ascent = min((c for c in loops if int_dots(c)), key=len)
+    assert any("/while/body/" in d and "/place/" in d
+               for d in int_dots(ascent))
+    _, cfg = seeded_surrogate(50)
+    w0 = daso.feature_size(cfg) * cfg.hidden              # W0 is K x H
+    large = []
+    for line in (line for c in ascent for line in comps[c]):
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) ", line)
+        for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]",
+                                      m.group(2) if m else ""):
+            size = math.prod(int(d) for d in dims.split(",") if d)
+            if size >= w0 and dtype != "s8":
+                large.append(line[:160])
+    assert not large, large[:5]
 
 
 def test_sharded_grid_compiles_for_v5e_2x2(topo):
