@@ -1,8 +1,10 @@
 """TPU-native SplitPlace: MAB plan selection over real executions.
 
 Measures the layer-pipeline vs semantic-branch latency/fidelity trade-off
-on a reduced model and shows the engine's UCB converging to
-deadline-appropriate plans (DESIGN.md §2.2)."""
+on a reduced Kimi K2 (MLA, sigmoid-routed experts; ``.reduced()``) and
+shows the engine's UCB converging to deadline-appropriate plans
+(DESIGN.md §2.2).  The chip measures the published widths in the
+benchmark cell ``kimi-k2-ep32.splitplace.plan``."""
 from __future__ import annotations
 
 import argparse
@@ -24,7 +26,7 @@ from repro.serving.plans import LAYER_PLAN
 
 
 def run(n_requests=40, seed=0, out_json=None):
-    cfg = get_config("tinyllama-1.1b").reduced(max_d_model=512, max_layers=6)
+    cfg = get_config("kimi-k2-1t-a32b").reduced(max_d_model=512, max_layers=6)
     params = init_params(jax.random.PRNGKey(0), cfg)
     eng = SplitPlaceEngine(params, cfg, num_stages=2, num_branches=2,
                            seed=seed)
@@ -32,12 +34,13 @@ def run(n_requests=40, seed=0, out_json=None):
     tok = rng.randint(0, cfg.vocab_size, (4, 256)).astype(np.int32)
     eng.warmup(tok)
     # measure the plan latencies once for the report
-    _, t_layer = eng._run(0, {"tokens": tok})
-    _, t_sem = eng._run(1, {"tokens": tok})
+    t_layer = eng._run(0, {"tokens": tok})[-1]
+    t_sem = eng._run(1, {"tokens": tok})[-1]
     results = []
     for i in range(n_requests):
         tight = rng.rand() < 0.5
-        # headroom covers the engine's slice-queue penalty (steady ~1.5x)
+        # headroom covers the engine's simulated slice-queue penalty
+        # (steady ~1.5x)
         ddl = (t_sem * 2.5) if tight else (t_layer * 4.0)
         results.append(eng.serve(Request(tokens=tok, deadline_s=float(ddl))))
     tail = results[n_requests // 2:]

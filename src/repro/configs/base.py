@@ -24,7 +24,42 @@ class MoEConfig:
     router_dtype: str = "float32"
     first_k_dense: int = 0        # leading dense (non-MoE) layers
     dispatch: str = "onehot"      # "onehot" (GShard baseline) | "gather" (optimized)
+                                  # | "dropless" (grouped matmul over the held experts)
     group_size: int = 4096        # dispatch group (capacity is per group)
+    scoring_func: str = "softmax" # router scores: softmax | sigmoid
+    correction_bias: bool = False # a per-expert bias added to the scores to
+                                  # select the top-k only (DeepSeek noaux_tc)
+    routed_scaling_factor: float = 1.0
+    shared_gate: bool = True      # Qwen2-MoE's sigmoid gate on the shared expert
+    held: Optional[Tuple[int, int]] = None  # (first, count) of the experts this
+                                  # chip holds (expert parallelism); None = all
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): queries through a
+    low-rank projection, keys and values from one latent plus a RoPE key
+    shared by all heads."""
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN RoPE scaling (DeepSeek-V3's ``rope_scaling`` of type yarn)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -62,6 +97,7 @@ class ModelConfig:
     sliding_window: int = 0       # 0 -> full causal attention
     rope_theta: float = 10000.0
     rope_fraction: float = 1.0    # nemotron uses partial rotary (0.5)
+    rope_scaling: Optional[YaRNConfig] = None
     pos_emb: str = "rope"         # rope | mrope | sinusoidal | none
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)
     # MLP
@@ -69,6 +105,7 @@ class ModelConfig:
     mlp_gated: bool = True
     # sub-family configs
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None   # latent attention in every attention layer
     ssm: Optional[SSMConfig] = None
     rglru: Optional[RGLRUConfig] = None
     # multimodal
@@ -87,6 +124,9 @@ class ModelConfig:
     optimizer: str = "adamw"      # adamw | adafactor
     grad_accum: int = 1           # microbatch count inside train_step
     remat: bool = True
+    # False: one parameter tree per layer and an unrolled stack (each
+    # layer's weights are read in place, never sliced out of a stack)
+    scan_layers: bool = True
     # serving: window used for the long-context sliding-window decode variant
     long_context_window: int = 8192
     source: str = ""              # citation for the config numbers
@@ -102,12 +142,18 @@ class ModelConfig:
         return self.ssm.dt_rank or math.ceil(self.d_model / 16)
 
     @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts held here."""
+        m = self.moe
+        return m.held if m.held is not None else (0, m.num_experts)
+
+    @property
     def layer_kinds(self) -> Tuple[str, ...]:
         """Fully unrolled per-layer block kinds, length == num_layers."""
         prefix = ()
         n = self.num_layers
         if self.moe is not None and self.moe.first_k_dense:
-            prefix = ("attn",) * self.moe.first_k_dense
+            prefix = ("mla" if self.mla else "attn",) * self.moe.first_k_dense
             n -= self.moe.first_k_dense
         reps = -(-n // len(self.block_pattern))
         body = (self.block_pattern * reps)[:n]
@@ -125,6 +171,8 @@ class ModelConfig:
         if self.moe is not None and self.moe.first_k_dense:
             pre = self.moe.first_k_dense
         body = kinds[pre:]
+        if not self.scan_layers:
+            return kinds, (self.block_pattern, 0), ()
         p = len(self.block_pattern)
         periods = len(body) // p
         rem = len(body) - periods * p
@@ -151,7 +199,7 @@ class ModelConfig:
         d, hd = self.d_model, self.resolved_head_dim
         total = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         for kind in self.layer_kinds:
-            if kind == "attn_moe":
+            if kind in ("attn_moe", "mla_moe"):
                 total += self._attn_params(d, hd)
                 m = self.moe
                 total += d * m.num_experts  # router
@@ -165,6 +213,14 @@ class ModelConfig:
         return total
 
     def _attn_params(self, d, hd):
+        if self.mla is not None:
+            a, h = self.mla, self.num_heads
+            return (d * a.q_lora_rank + a.q_lora_rank                 # q_a, norm
+                    + a.q_lora_rank * h * a.qk_head_dim                 # q_b
+                    + d * (a.kv_lora_rank + a.qk_rope_head_dim)         # kv_a
+                    + a.kv_lora_rank                                    # kv norm
+                    + a.kv_lora_rank * h * (a.qk_nope_head_dim + a.v_head_dim)
+                    + h * a.v_head_dim * d)                             # o
         q = d * self.num_heads * hd
         kv = 2 * d * self.num_kv_heads * hd
         o = self.num_heads * hd * d
@@ -176,15 +232,16 @@ class ModelConfig:
 
     def _block_params(self, kind, d, hd):
         norms = 2 * d
-        if kind == "attn":
+        if kind in ("attn", "mla"):
             return self._attn_params(d, hd) + self._mlp_params(d, self.d_ff) + norms
         if kind == "local_attn":
             return self._attn_params(d, hd) + self._mlp_params(d, self.d_ff) + norms
         if kind == "xattn":
             return 2 * self._attn_params(d, hd) + self._mlp_params(d, self.d_ff) + 3 * d
-        if kind == "attn_moe":
+        if kind in ("attn_moe", "mla_moe"):
             m = self.moe
             p = self._attn_params(d, hd) + norms + d * m.num_experts
+            p += m.num_experts if m.correction_bias else 0
             p += m.num_experts * 3 * d * m.d_ff_expert
             if m.num_shared_experts:
                 p += 3 * d * m.shared_d_ff
@@ -225,7 +282,8 @@ class ModelConfig:
                 top_k=min(self.moe.top_k, 2), d_ff_expert=d,
                 shared_d_ff=d if self.moe.num_shared_experts else 0,
                 first_k_dense=min(self.moe.first_k_dense, 1),
-                capacity_factor=4.0)  # lossless routing for smoke tests
+                capacity_factor=4.0,  # lossless routing for smoke tests
+                held=None)
         layers = max_layers
         if self.moe is not None and self.moe.first_k_dense:
             layers = max_layers + 1
@@ -234,10 +292,15 @@ class ModelConfig:
         half = hd // 2
         t = max(1, half // 4)
         sections = (t, (half - t) // 2, half - t - (half - t) // 2)
+        mla = None
+        if self.mla is not None:
+            mla = MLAConfig(q_lora_rank=2 * hd, kv_lora_rank=hd,
+                            qk_nope_head_dim=half, qk_rope_head_dim=half,
+                            v_head_dim=half)
         return dataclasses.replace(
             self, num_layers=layers, d_model=d, num_heads=heads,
             num_kv_heads=kv, head_dim=hd, d_ff=2 * d, vocab_size=vocab,
-            moe=moe, mrope_sections=sections,
+            moe=moe, mla=mla, mrope_sections=sections,
             sliding_window=min(self.sliding_window, 8) if self.sliding_window else 0,
             rglru=dataclasses.replace(self.rglru, lru_width=d, local_window=8) if self.rglru else None,
             param_dtype="float32", compute_dtype="float32",

@@ -37,16 +37,32 @@ class LayerCost:
 
 
 def model_layer_costs(cfg, seq: int, batch: int) -> List[LayerCost]:
-    """Analytic per-layer cost table for any assigned architecture."""
+    """Analytic per-layer cost table for any assigned architecture.
+
+    A MoE layer holds only the experts of its share (``held_experts``)
+    and computes, per token, the expected share of its top-k that lands
+    on them; attention costs its score and value products (MLA: query-key
+    and value head dims apart)."""
     d, hd = cfg.d_model, cfg.resolved_head_dim
+    qk = v = hd
+    if cfg.mla is not None:
+        qk, v = cfg.mla.qk_head_dim, cfg.mla.v_head_dim
     act_bytes = batch * seq * d * 2.0
     out = []
     for kind in cfg.layer_kinds:
-        p = cfg._block_params(kind, d, hd)
-        flops = 2.0 * p * batch * seq
-        if kind in ("attn", "local_attn", "xattn", "attn_moe"):
+        p = active = cfg._block_params(kind, d, hd)
+        if kind in ("attn_moe", "mla_moe"):
+            m = cfg.moe
+            held = cfg.held_experts[1]
+            expert = 3 * d * m.d_ff_expert
+            p -= (m.num_experts - held) * expert
+            active = p - held * expert \
+                + m.top_k * held / m.num_experts * expert
+        flops = 2.0 * active * batch * seq
+        if kind in ("attn", "local_attn", "xattn", "attn_moe", "mla",
+                    "mla_moe"):
             w = cfg.sliding_window or seq
-            flops += 4.0 * batch * seq * min(w, seq) * cfg.num_heads * hd
+            flops += 2.0 * batch * seq * min(w, seq) * cfg.num_heads * (qk + v)
         out.append(LayerCost(flops, act_bytes, p * 2.0))
     return out
 

@@ -90,8 +90,8 @@ def _plan_main(args):
     tok = rng.randint(0, cfg.vocab_size,
                       (args.batch, args.seq)).astype(np.int32)
     eng.warmup(tok)
-    _, t_layer = eng._run(0, {"tokens": jax.numpy.asarray(tok)})
-    _, t_sem = eng._run(1, {"tokens": jax.numpy.asarray(tok)})
+    t_layer = eng._run(0, {"tokens": jax.numpy.asarray(tok)})[-1]
+    t_sem = eng._run(1, {"tokens": jax.numpy.asarray(tok)})[-1]
     print(f"plan latencies: layer-pipeline {t_layer*1e3:.1f}ms, "
           f"semantic-branch {t_sem*1e3:.1f}ms")
     for i in range(args.requests):
@@ -100,14 +100,16 @@ def _plan_main(args):
         r = eng.serve(Request(tokens=tok, deadline_s=float(ddl)))
         print(f"req {i:3d} deadline={'tight' if tight else 'loose'} -> "
               f"plan={'layer' if r.plan == 0 else 'semantic'} "
-              f"lat={r.latency_s*1e3:.1f}ms fid={r.fidelity:.3f} "
+              f"lat={r.latency_s*1e3:.1f}ms "
+              f"simulated={r.sim_latency_s*1e3:.1f}ms fid={r.fidelity:.3f} "
               f"met={r.met_deadline} reward={r.reward:.3f}")
     print(f"final MAB Q:\n{np.asarray(eng.state.Q).round(3)}")
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="tinyllama-1.1b")
+    ap.add_argument("--arch", default="kimi-k2-1t-a32b",
+                    help="plan mode: architecture, run .reduced()")
     ap.add_argument("--requests", type=int, default=20)
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--seq", type=int, default=64)

@@ -75,7 +75,7 @@ def param_pspec(mesh, cfg, path, leaf) -> P:
     if nd - lead <= 1:  # norms, 1D biases, Lambda, D, dt_bias, conv_b
         return spec(_fit(mesh, d[0], tp) if base in ("Lambda", "D", "conv_b", "b_a", "b_i", "dt_bias") else None)
 
-    if base in ("wq", "wk", "wv"):
+    if base in ("wq", "wk", "wv", "wq_b", "wkv_b"):     # (d or rank, heads, e)
         heads = d[1]
         if _fit(mesh, heads, tp):
             return spec(_fit(mesh, d[0], fsdp), tp, None)
@@ -91,6 +91,8 @@ def param_pspec(mesh, cfg, path, leaf) -> P:
         return spec(_fit(mesh, d[0], fsdp), _fit(mesh, d[1], tp))
     if base == "w_down" and nd - lead == 2:
         return spec(_fit(mesh, d[0], tp), _fit(mesh, d[1], fsdp))
+    if base in ("wq_a", "wkv_a"):             # MLA latent down-projections
+        return spec(_fit(mesh, d[0], fsdp), None)
     if base == "router":
         return spec(_fit(mesh, d[0], fsdp), None)
     if base == "shared_gate":

@@ -1,4 +1,6 @@
-"""GQA attention: full, blockwise (flash-style online-softmax), and decode.
+"""GQA and multi-head latent attention (MLA): full, blockwise
+(flash-style online-softmax), and decode.  The query-key head dim may
+differ from the value head dim (MLA: 192 against 128).
 
 The blockwise path is the pure-JAX twin of ``repro.kernels.flash_attention``
 (the Pallas TPU kernel) and doubles as its oracle; the model uses this path
@@ -11,7 +13,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_mrope, apply_rope, dense_init
+from repro.models.layers import (apply_mrope, apply_rope, dense_init,
+                                 rmsnorm, yarn_inv_freq, yarn_mscale)
 
 NEG_INF = -1e30
 
@@ -56,14 +59,16 @@ def _group(q, num_kv):
     return q.reshape(b, s, num_kv, h // num_kv, hd)
 
 
-def full_attention(q, k, v, pos_q, pos_k, window=0, kv_mask=None, causal=True):
+def full_attention(q, k, v, pos_q, pos_k, window=0, kv_mask=None, causal=True,
+                   scale=None):
     """Reference full-materialization attention.
 
-    q (b,sq,h,hd); k,v (b,sk,kv,hd); pos_q (b,sq); pos_k (b,sk).
+    q (b,sq,h,hd); k (b,sk,kv,hd); v (b,sk,kv,hv); pos_q (b,sq);
+    pos_k (b,sk).  ``scale`` defaults to hd^-0.5.
     """
     kvh = k.shape[2]
     qg = _group(q, kvh)                                     # (b,sq,kv,g,hd)
-    scale = q.shape[-1] ** -0.5
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
     scores = jnp.einsum("bqkgh,bskh->bkgqs", qg, k).astype(jnp.float32) * scale
     mask = jnp.ones(scores.shape[-2:], bool)[None]
     if causal:
@@ -76,11 +81,12 @@ def full_attention(q, k, v, pos_q, pos_k, window=0, kv_mask=None, causal=True):
     w = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskh->bqkgh", w, v)
     b, sq = q.shape[:2]
-    return out.reshape(b, sq, -1, q.shape[-1])
+    return out.reshape(b, sq, -1, v.shape[-1])
 
 
 def blockwise_attention(q, k, v, pos_q, pos_k, window=0,
-                        q_block=512, kv_block=1024, causal_skip=False):
+                        q_block=512, kv_block=1024, causal_skip=False,
+                        scale=None):
     """Flash-style attention: scan q blocks; stream kv blocks (online softmax).
 
     With ``causal_skip`` the kv scan for q-block i only covers kv blocks
@@ -88,7 +94,7 @@ def blockwise_attention(q, k, v, pos_q, pos_k, window=0,
     the compute term for causal attention.
     """
     b, sq, h, hd = q.shape
-    sk, kvh = k.shape[1], k.shape[2]
+    sk, kvh, hv = k.shape[1], k.shape[2], v.shape[-1]
     g = h // kvh
     nq, nk = -(-sq // q_block), -(-sk // kv_block)
     pq = nq * q_block - sq
@@ -100,9 +106,9 @@ def blockwise_attention(q, k, v, pos_q, pos_k, window=0,
     pkp = jnp.pad(pos_k, ((0, 0), (0, pk)), constant_values=2**30)
     qb = qp.reshape(b, nq, q_block, kvh, g, hd).transpose(1, 0, 2, 3, 4, 5)
     kb = kp.reshape(b, nk, kv_block, kvh, hd)
-    vb = vp.reshape(b, nk, kv_block, kvh, hd)
+    vb = vp.reshape(b, nk, kv_block, kvh, hv)
     pqb = pqp.reshape(b, nq, q_block).transpose(1, 0, 2)
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
 
     def one_q_block(args, kv_hi=None):
         qi, posq, q_idx = args                              # (b,qb,kv,g,hd)
@@ -125,7 +131,7 @@ def blockwise_attention(q, k, v, pos_q, pos_k, window=0,
 
         m0 = jnp.full((b, kvh, g, q_block), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, kvh, g, q_block), jnp.float32)
-        a0 = jnp.zeros((b, kvh, g, q_block, hd), jnp.float32)
+        a0 = jnp.zeros((b, kvh, g, q_block, hv), jnp.float32)
         hi = nk if kv_hi is None else kv_hi
         (m, l, acc), _ = jax.lax.scan(
             kv_step, (m0, l0, a0),
@@ -153,7 +159,7 @@ def blockwise_attention(q, k, v, pos_q, pos_k, window=0,
         # instead of saving the O(s^2) inner-scan residuals
         out = jax.lax.map(jax.checkpoint(one_q_block),
                           (qb, pqb, jnp.arange(nq)))
-    out = out.transpose(1, 0, 2, 3, 4, 5).reshape(b, nq * q_block, h, hd)
+    out = out.transpose(1, 0, 2, 3, 4, 5).reshape(b, nq * q_block, h, hv)
     return out[:, :sq].astype(q.dtype)
 
 
@@ -186,15 +192,121 @@ def cross_attention(p, x, cond, cfg):
     return jnp.einsum("bshe,hed->bsd", out, p["wo"])
 
 
+# ------------------------------------------------ multi-head latent attention
+
+def mla_init(key, cfg, dtype):
+    """MLA weights (DeepSeek-V3 names in comments).  Heads are an axis of
+    ``wq_b``, ``wkv_b`` and ``wo``, so a head slice is a view."""
+    a, d, h = cfg.mla, cfg.d_model, cfg.num_heads
+    ks = jax.random.split(key, 5)
+    return {
+        "wq_a": dense_init(ks[0], (d, a.q_lora_rank), dtype),      # q_a_proj
+        "q_norm": jnp.zeros((a.q_lora_rank,), dtype),
+        "wq_b": dense_init(ks[1], (a.q_lora_rank, h, a.qk_head_dim), dtype),
+        "wkv_a": dense_init(ks[2], (d, a.kv_lora_rank + a.qk_rope_head_dim),
+                            dtype),                    # kv_a_proj_with_mqa
+        "kv_norm": jnp.zeros((a.kv_lora_rank,), dtype),
+        "wkv_b": dense_init(ks[3], (a.kv_lora_rank, h,
+                                    a.qk_nope_head_dim + a.v_head_dim), dtype),
+        "wo": dense_init(ks[4], (h, a.v_head_dim, d), dtype,
+                         fan_in=h * a.v_head_dim),
+    }
+
+
+def mla_rope_and_scale(cfg):
+    """(RoPE frequencies of the rope dims or None, softmax scale): YaRN
+    divides slow frequencies and multiplies the scale by mscale^2."""
+    a, y = cfg.mla, cfg.rope_scaling
+    scale = a.qk_head_dim ** -0.5
+    if y is None:
+        return None, scale
+    scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return jnp.asarray(yarn_inv_freq(a.qk_rope_head_dim, cfg.rope_theta, y)), \
+        scale
+
+
+def mla_qkv(p, x, positions, cfg):
+    """q (b,s,h,qk), k (b,s,h,qk), v (b,s,h,v) of one MLA layer; the
+    RoPE key is one per position, shared by every head."""
+    a = cfg.mla
+    inv_freq, _ = mla_rope_and_scale(cfg)
+    y = cfg.rope_scaling
+    rs = 1.0 if y is None else (yarn_mscale(y.factor, y.mscale)
+                                / yarn_mscale(y.factor, y.mscale_all_dim))
+    cq = rmsnorm(x @ p["wq_a"], p["q_norm"], cfg.norm_eps)
+    q = jnp.einsum("bsr,rhe->bshe", cq, p["wq_b"])
+    kv = x @ p["wkv_a"]
+    ckv = rmsnorm(kv[..., :a.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
+    k_pe = kv[..., None, a.kv_lora_rank:]                   # (b,s,1,rope)
+    kvb = jnp.einsum("bsr,rhe->bshe", ckv, p["wkv_b"])
+    k_nope, v = kvb[..., :a.qk_nope_head_dim], kvb[..., a.qk_nope_head_dim:]
+    q_pe = apply_rope(q[..., a.qk_nope_head_dim:], positions, cfg.rope_theta,
+                      inv_freq=inv_freq)
+    k_pe = apply_rope(k_pe, positions, cfg.rope_theta, inv_freq=inv_freq)
+    if rs != 1.0:
+        q_pe, k_pe = q_pe * rs, k_pe * rs
+    q = jnp.concatenate([q[..., :a.qk_nope_head_dim], q_pe], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:-1] + k_pe.shape[-1:])],
+        axis=-1)
+    return q, k, v
+
+
+def mla_attention(p, x, ctx, cfg):
+    """Full-sequence MLA (train / prefill); blockwise above the
+    ``blockwise_threshold`` length or once the float32 scores would pass
+    512 MiB (64 heads: 4 x 1024 tokens).  Returns (y, (k, v))."""
+    _, scale = mla_rope_and_scale(cfg)
+    pos = ctx["positions"]
+    q, k, v = mla_qkv(p, x, pos, cfg)
+    b, s, h = q.shape[:3]
+    if s > ctx.get("blockwise_threshold", 2048) or b * h * s * s * 4 > 2**29:
+        out = blockwise_attention(q, k, v, pos, pos, scale=scale,
+                                  causal_skip=ctx.get("causal_skip", False))
+    else:
+        out = full_attention(q, k, v, pos, pos, scale=scale)
+    return jnp.einsum("bshe,hed->bsd", out, p["wo"]), (k, v)
+
+
+def mla_decode(p, x, cache, pos, cfg):
+    """One-token MLA decode over a ring cache of the expanded keys and
+    values (not the latent: decoding is not on the plan engine's path)."""
+    _, scale = mla_rope_and_scale(cfg)
+    b = x.shape[0]
+    pos_b = jnp.full((b, 1), pos, jnp.int32)
+    q, k, v = mla_qkv(p, x, pos_b, cfg)
+    ck, cv, pos_k = _ring_write(cache, k, v, pos, b)
+    out = full_attention(q, ck, cv, jnp.ones_like(pos_b), pos_k, scale=scale)
+    return jnp.einsum("bshe,hed->bsd", out, p["wo"]), {"k": ck, "v": cv}
+
+
 # ----------------------------------------------------------- decoding
 
 def init_attn_cache(cfg, batch, ctx_len, window=0, dtype=jnp.bfloat16):
     w = min(ctx_len, window) if window else ctx_len
-    kvh, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+    kvh, hd, hv = cfg.num_kv_heads, cfg.resolved_head_dim, cfg.resolved_head_dim
+    if cfg.mla is not None:
+        kvh, hd, hv = cfg.num_heads, cfg.mla.qk_head_dim, cfg.mla.v_head_dim
     return {
         "k": jnp.zeros((batch, w, kvh, hd), dtype),
-        "v": jnp.zeros((batch, w, kvh, hd), dtype),
+        "v": jnp.zeros((batch, w, kvh, hv), dtype),
     }
+
+
+def _ring_write(cache, k, v, pos, b):
+    """Write one position's k, v into the ring cache; returns (k, v,
+    key positions with empty slots masked by the pos trick)."""
+    W = cache["k"].shape[1]
+    slot = (pos % W).astype(jnp.int32)
+    # mask-based ring write: dynamic_update_slice on a sharded cache dim
+    # makes GSPMD all-gather the cache; a select against iota is purely
+    # elementwise and keeps the seq-sharded layout (§Perf iteration 0)
+    hit = (jnp.arange(W, dtype=jnp.int32) == slot)[None, :, None, None]
+    ck = jnp.where(hit, k.astype(cache["k"].dtype), cache["k"])
+    cv = jnp.where(hit, v.astype(cache["v"].dtype), cache["v"])
+    valid = jnp.arange(W)[None, :] < jnp.minimum(pos + 1, W)
+    valid = jnp.broadcast_to(valid, (b, W))
+    return ck, cv, jnp.where(valid, 0, 2**30)              # mask via pos trick
 
 
 def decode_attention(p, x, cache, pos, ctx, cfg, window=0):
@@ -213,17 +325,7 @@ def decode_attention(p, x, cache, pos, ctx, cfg, window=0):
     elif cfg.pos_emb == "rope":
         q = apply_rope(q, pos_b, cfg.rope_theta, cfg.rope_fraction)
         k = apply_rope(k, pos_b, cfg.rope_theta, cfg.rope_fraction)
-    W = cache["k"].shape[1]
-    slot = (pos % W).astype(jnp.int32)
-    # mask-based ring write: dynamic_update_slice on a sharded cache dim
-    # makes GSPMD all-gather the cache; a select against iota is purely
-    # elementwise and keeps the seq-sharded layout (§Perf iteration 0)
-    hit = (jnp.arange(W, dtype=jnp.int32) == slot)[None, :, None, None]
-    ck = jnp.where(hit, k.astype(cache["k"].dtype), cache["k"])
-    cv = jnp.where(hit, v.astype(cache["v"].dtype), cache["v"])
-    valid = jnp.arange(W)[None, :] < jnp.minimum(pos + 1, W)
-    valid = jnp.broadcast_to(valid, (b, W))
-    pos_k = jnp.where(valid, 0, 2**30)                      # mask via pos trick
+    ck, cv, pos_k = _ring_write(cache, k, v, pos, b)
     out = full_attention(q, ck, cv, jnp.ones_like(pos_b), pos_k,
                          causal=True, window=0)
     y = jnp.einsum("bshe,hed->bsd", out, p["wo"])

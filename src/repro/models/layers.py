@@ -1,6 +1,8 @@
 """Shared neural building blocks: norms, MLPs, position embeddings, init."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -66,13 +68,39 @@ def rope_angles(positions, dim, theta, dtype=jnp.float32):
     return jnp.cos(ang).astype(dtype), jnp.sin(ang).astype(dtype)
 
 
-def apply_rope(x, positions, theta=10000.0, fraction=1.0):
-    """x (b, s, h, hd); positions (b, s). Rotates leading `fraction` of hd."""
+def yarn_mscale(scale: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(scale) + 1.0 if scale > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, y):
+    """YaRN's per-pair frequencies (DeepSeek-V3 ``yarn`` rope_scaling):
+    pairs faster than ``beta_fast`` rotations over the original context
+    keep their frequency, those slower than ``beta_slow`` are divided by
+    ``factor``, with a linear ramp between."""
+    base = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def corr_dim(rot):
+        return dim * math.log(y.original_max_position_embeddings
+                              / (rot * 2 * math.pi)) / (2 * math.log(theta))
+    low = max(math.floor(corr_dim(y.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(y.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    keep = 1.0 - ramp
+    return (base / y.factor * (1 - keep) + base * keep).astype(np.float32)
+
+
+def apply_rope(x, positions, theta=10000.0, fraction=1.0, inv_freq=None):
+    """x (b, s, h, hd); positions (b, s). Rotates leading `fraction` of hd
+    (adjacent pairs), at ``inv_freq`` if given, else theta's frequencies."""
     hd = x.shape[-1]
     rot = int(hd * fraction)
     rot -= rot % 2
     xr, xp = x[..., :rot], x[..., rot:]
-    cos, sin = rope_angles(positions, rot, theta)          # (b, s, rot/2)
+    if inv_freq is None:
+        cos, sin = rope_angles(positions, rot, theta)      # (b, s, rot/2)
+    else:
+        ang = positions[..., None].astype(jnp.float32) * inv_freq
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
     cos, sin = cos[:, :, None, :], sin[:, :, None, :]
     x1, x2 = xr[..., ::2], xr[..., 1::2]
     y1 = x1 * cos - x2 * sin

@@ -55,6 +55,12 @@ def init_block(key, kind, cfg):
                 "attn": attn.attn_init(ks[0], cfg, dtype),
                 "norm2": jnp.zeros((d,), dtype),
                 "moe": moe_mod.moe_init(ks[1], cfg, dtype)}
+    if kind in ("mla", "mla_moe"):
+        ffn = ({"moe": moe_mod.moe_init(ks[1], cfg, dtype)} if kind == "mla_moe"
+               else {"mlp": mlp_init(ks[1], d, cfg.d_ff, cfg, dtype)})
+        return {"norm1": jnp.zeros((d,), dtype),
+                "attn": attn.mla_init(ks[0], cfg, dtype),
+                "norm2": jnp.zeros((d,), dtype), **ffn}
     if kind == "mamba":
         return {"norm1": jnp.zeros((d,), dtype),
                 "mamba": ssm_mod.mamba_init(ks[0], cfg, dtype)}
@@ -74,14 +80,31 @@ def _block_window(kind, cfg):
 
 # --------------------------------------------------------- block apply
 
+ATTN_KINDS = ("attn", "local_attn", "xattn", "attn_moe", "mla", "mla_moe")
+MOE_KINDS = ("attn_moe", "mla_moe")
+
+
+def mixer(kind, p, x, ctx, cfg):
+    """The block's normed attention: (output, (k, v))."""
+    xn = rmsnorm(x, p["norm1"], cfg.norm_eps)
+    if kind in ("mla", "mla_moe"):
+        with jax.named_scope("mla"):
+            return attn.mla_attention(p["attn"], xn, ctx, cfg)
+    return attn.self_attention(p["attn"], xn, ctx, cfg,
+                               window=_block_window(kind, cfg))
+
+
 def apply_block(kind, p, x, ctx, cfg, collect_cache=False):
-    """Returns (x, aux_loss, cache_or_None)."""
+    """Returns (x, aux_loss, cache_or_None, route_or_None).
+
+    ``route`` is a dropless MoE layer's record (``moe.moe_routed``), its
+    probe taken at ``ctx["probe"]`` if the batch gave one."""
     con = ctx.get("constrain", _identity_constrain)
     aux = jnp.zeros((), jnp.float32)
-    cache = None
-    if kind in ("attn", "local_attn", "xattn", "attn_moe"):
-        h, kv = attn.self_attention(p["attn"], rmsnorm(x, p["norm1"], cfg.norm_eps),
-                                    ctx, cfg, window=_block_window(kind, cfg))
+    cache = route = None
+    dropless = kind in MOE_KINDS and cfg.moe.dispatch == "dropless"
+    if kind in ATTN_KINDS:
+        h, kv = mixer(kind, p, x, ctx, cfg)
         x = con(x + h, "residual")
         if collect_cache:
             w = _block_window(kind, cfg) or ctx["cache_len"]
@@ -103,15 +126,20 @@ def apply_block(kind, p, x, ctx, cfg, collect_cache=False):
                                       rmsnorm(x, p["norm_x"], cfg.norm_eps),
                                       ctx["cond"], cfg)
             x = con(x + hx, "residual")
-        if kind == "attn_moe":
-            xn = rmsnorm(x, p["norm2"], cfg.norm_eps)
+        xn = rmsnorm(x, p["norm2"], cfg.norm_eps)
+        if dropless:
+            b, s, d = xn.shape
+            h2, route = moe_mod.moe_dropless(p["moe"], xn.reshape(1, b * s, d),
+                                             cfg, probe=ctx.get("probe"))
+            h2 = h2.reshape(xn.shape)
+        elif kind in MOE_KINDS:
             h2 = moe_mod.moe_apply(p["moe"], xn, cfg, con)
             aux = moe_mod.aux_load_balance_loss(p["moe"], xn, cfg)
-            x = con(x + h2, "residual")
         else:
-            h2 = mlp_apply(p["mlp"], rmsnorm(x, p["norm2"], cfg.norm_eps), cfg, con)
-            x = con(x + h2, "residual")
-        return x, aux, cache
+            with jax.named_scope("dense_mlp"):
+                h2 = mlp_apply(p["mlp"], xn, cfg, con)
+        x = con(x + h2, "residual")
+        return x, aux, cache, route
     if kind == "mamba":
         if collect_cache:
             y, cache = ssm_mod.mamba_prefill(p["mamba"],
@@ -120,7 +148,7 @@ def apply_block(kind, p, x, ctx, cfg, collect_cache=False):
         else:
             y = ssm_mod.mamba_apply(p["mamba"],
                                     rmsnorm(x, p["norm1"], cfg.norm_eps), cfg, con)
-        return con(x + y, "residual"), aux, cache
+        return con(x + y, "residual"), aux, cache, route
     if kind == "rglru":
         if collect_cache:
             y, cache = rglru_mod.rglru_prefill(
@@ -129,16 +157,21 @@ def apply_block(kind, p, x, ctx, cfg, collect_cache=False):
             y = rglru_mod.rglru_apply(p["rglru"],
                                       rmsnorm(x, p["norm1"], cfg.norm_eps), cfg, con)
         x = con(x + y, "residual")
-        h2 = mlp_apply(p["mlp"], rmsnorm(x, p["norm2"], cfg.norm_eps), cfg, con)
-        return con(x + h2, "residual"), aux, cache
+        with jax.named_scope("dense_mlp"):
+            h2 = mlp_apply(p["mlp"], rmsnorm(x, p["norm2"], cfg.norm_eps), cfg, con)
+        return con(x + h2, "residual"), aux, cache, route
     raise ValueError(kind)
 
 
 def decode_block(kind, p, x, cache, pos, ctx, cfg):
     con = ctx.get("constrain", _identity_constrain)
-    if kind in ("attn", "local_attn", "xattn", "attn_moe"):
-        h, cache_a = attn.decode_attention(
-            p["attn"], rmsnorm(x, p["norm1"], cfg.norm_eps), cache, pos, ctx, cfg)
+    if kind in ATTN_KINDS:
+        xn = rmsnorm(x, p["norm1"], cfg.norm_eps)
+        if kind in ("mla", "mla_moe"):
+            h, cache_a = attn.mla_decode(p["attn"], xn, cache, pos, cfg)
+        else:
+            h, cache_a = attn.decode_attention(p["attn"], xn, cache, pos,
+                                               ctx, cfg)
         x = x + h
         if kind == "xattn":
             hx = attn.cross_attention(p["xattn"],
@@ -146,7 +179,7 @@ def decode_block(kind, p, x, cache, pos, ctx, cfg):
                                       ctx["cond"], cfg)
             x = x + hx
         xn = rmsnorm(x, p["norm2"], cfg.norm_eps)
-        if kind == "attn_moe":
+        if kind in MOE_KINDS:
             x = x + moe_mod.moe_apply(p["moe"], xn, cfg, con)
         else:
             x = x + mlp_apply(p["mlp"], xn, cfg, con)
@@ -168,7 +201,7 @@ def decode_block(kind, p, x, cache, pos, ctx, cfg):
 
 def init_block_cache(kind, cfg, batch, ctx_len, sliding=None):
     dtype = dtype_of(cfg.compute_dtype)
-    if kind in ("attn", "xattn", "attn_moe"):
+    if kind in ("attn", "xattn", "attn_moe", "mla", "mla_moe"):
         w = cfg.sliding_window or (sliding or ctx_len)
         return attn.init_attn_cache(cfg, batch, ctx_len, window=w, dtype=dtype)
     if kind == "local_attn":
@@ -228,11 +261,19 @@ def embed_tokens(params, batch, cfg, positions):
 
 
 def lm_head(params, x, cfg):
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    w = params["embed"].swapaxes(-1, -2) if cfg.tie_embeddings else params["head"]
+    """Final norm and output head; float32 logits."""
+    with jax.named_scope("lm_head"):
+        return output_head(params, rmsnorm(x, params["final_norm"],
+                                           cfg.norm_eps), cfg)
+
+
+def output_head(params, xn, cfg):
+    """Float32 logits of final-normed states."""
+    w = params["embed"].swapaxes(-1, -2) if cfg.tie_embeddings \
+        else params["head"]
     if cfg.num_codebooks:
-        return jnp.einsum("bsd,cdv->bscv", x, w).astype(jnp.float32)
-    return (x @ w).astype(jnp.float32)
+        return jnp.einsum("bsd,cdv->bscv", xn, w).astype(jnp.float32)
+    return (xn @ w).astype(jnp.float32)
 
 
 def _make_ctx(batch, cfg, constrain, cache_len=0):
@@ -243,6 +284,8 @@ def _make_ctx(batch, cfg, constrain, cache_len=0):
     ctx = {"positions": positions, "constrain": constrain or _identity_constrain,
            "cache_len": cache_len,
            "causal_skip": getattr(cfg, "attn_causal_skip", False)}
+    if "probe" in batch:
+        ctx["probe"] = batch["probe"]
     if cfg.pos_emb == "mrope":
         p3 = batch.get("positions3")
         if p3 is None:
@@ -259,9 +302,24 @@ def _make_ctx(batch, cfg, constrain, cache_len=0):
 
 # ------------------------------------------------------------- forward
 
+def stack_routes(routes):
+    """One record of the dropless MoE layers' routes (``moe.moe_routed``):
+    each array stacked over the layers ((layers, C, ...)) and the pairs
+    computed, summed."""
+    routes = [r for r in routes if r is not None]
+    if not routes:
+        return {"topk": None, "pairs": jnp.zeros((), jnp.int32)}
+    out = {name: jnp.concatenate([r[name].reshape((-1,) + r[name].shape[-3:])
+                                  for r in routes])
+           for name in routes[0] if name != "pairs"}
+    out["pairs"] = sum(r["pairs"].sum() for r in routes)
+    return out
+
+
 def forward(params, batch, cfg, constrain=None, collect_cache=False,
-            max_ctx=None):
-    """Full-sequence forward.  Returns (logits, aux_loss[, cache])."""
+            max_ctx=None, with_routes=False):
+    """Full-sequence forward.  Returns (logits, aux_loss[, cache][,
+    routes]); ``routes`` is ``stack_routes`` of the dropless MoE layers."""
     ctx = _make_ctx(batch, cfg, constrain,
                     cache_len=max_ctx or batch["tokens"].shape[1])
     x = embed_tokens(params, batch, cfg, ctx["positions"])
@@ -269,34 +327,43 @@ def forward(params, batch, cfg, constrain=None, collect_cache=False,
     prefix, (pattern, periods), suffix = cfg.scan_segments
     aux = jnp.zeros((), jnp.float32)
     caches = {"prefix": [], "suffix": []}
+    routes = []
     for p, kind in zip(params["prefix"], prefix):
-        x, a, c = apply_block(kind, p, x, ctx, cfg, collect_cache)
+        x, a, c, r = apply_block(kind, p, x, ctx, cfg, collect_cache)
         aux, _ = aux + a, caches["prefix"].append(c)
+        routes.append(r)
 
     if periods:
         def period_fn(carry, pp):
             x, aux = carry
-            cs = {}
+            cs, rs = {}, []
             for j, kind in enumerate(pattern):
-                x, a, c = apply_block(kind, pp[f"b{j}"], x, ctx, cfg,
-                                      collect_cache)
+                x, a, c, r = apply_block(kind, pp[f"b{j}"], x, ctx, cfg,
+                                         collect_cache)
                 aux = aux + a
+                rs.append(r)
                 if collect_cache:
                     cs[f"b{j}"] = c
-            return (x, aux), cs
+            return (x, aux), (cs, rs)
         fn = jax.checkpoint(period_fn) if cfg.remat else period_fn
-        (x, aux), body_cache = jax.lax.scan(fn, (x, aux), params["body"])
+        (x, aux), (body_cache, body_routes) = jax.lax.scan(
+            fn, (x, aux), params["body"])
         if collect_cache:
             caches["body"] = body_cache
+        routes += body_routes
 
     for p, kind in zip(params["suffix"], suffix):
-        x, a, c = apply_block(kind, p, x, ctx, cfg, collect_cache)
+        x, a, c, r = apply_block(kind, p, x, ctx, cfg, collect_cache)
         aux, _ = aux + a, caches["suffix"].append(c)
+        routes.append(r)
 
     logits = lm_head(params, x, cfg)
+    out = (logits, aux)
     if collect_cache:
-        return logits, aux, caches
-    return logits, aux
+        out += (caches,)
+    if with_routes:
+        out += (stack_routes(routes),)
+    return out
 
 
 def loss_fn(params, batch, cfg, constrain=None, aux_weight=0.01):

@@ -5,19 +5,27 @@ Per request batch:
   1. context = deadline vs EMA estimate of the layer-pipeline latency
      (eq. 2 semantics, measured wall-clock here);
   2. the MAB (UCB at serve time) picks layer_pipeline or semantic_branch;
-  3. the plan executes (really — pipeline_forward / branch_forward);
-  4. reward couples deadline satisfaction with fidelity (agreement of the
+  3. DASO places the plan's fragments on mesh slices;
+  4. the plan executes (really — pipeline_forward / branch_forward, the
+     branches batched in one program) and its wall time is measured;
+  5. reward couples deadline satisfaction with fidelity (agreement of the
      plan's argmax tokens vs the monolithic forward), eqs. 3–5.
 
-On hardware the two plans map to mesh-slice pipelining vs branch-parallel
-execution; on CPU the latency separation is real (branch_forward does
-~1/B of the FLOPs per branch).
+The deadline is judged on a simulated latency: the measured one times
+``1 + 0.25 * queue cost`` of the slices DASO placed the fragments on
+(``ServeResult.sim_latency_s``; the slices are not real devices).  Each
+step is a span of the active ``RunLedger`` (``plan.decide``,
+``plan.place``, ``plan.run``, ``plan.fidelity``, ``plan.feedback``);
+counters ``plan.layer`` / ``plan.semantic`` (requests by plan),
+``plan.tokens``, and, for the dropless MoE layers of the plan and the
+fidelity forward, ``moe.tokens`` and ``moe.routed_pairs`` (token-expert
+pairs computed on the held experts).
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import List
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -25,9 +33,10 @@ import numpy as np
 
 from repro.core import daso as daso_mod
 from repro.core import mab as mab_mod
-from repro.models.model import forward
+from repro.models.model import MOE_KINDS, forward
+from repro.obs import get_ledger
 from repro.serving.plans import (LAYER_PLAN, SEMANTIC_PLAN, PlanSpec,
-                                 branch_forward, pipeline_forward)
+                                 branch_forward, pipeline_forward, unrolled)
 
 
 @dataclasses.dataclass
@@ -35,20 +44,33 @@ class Request:
     tokens: np.ndarray          # (b, s)
     deadline_s: float
     app: int = 0
+    probe: Optional[np.ndarray] = None  # (P,) flat token positions at which
+                                # the plan's dropless MoE layers report
+                                # their input and held-expert output
 
 
 @dataclasses.dataclass
 class ServeResult:
     plan: int
-    latency_s: float
+    latency_s: float            # measured wall of the plan's program
+    sim_latency_s: float        # simulated: latency_s * (1 + 0.25 * queue
+                                # cost of the DASO-placed slices)
     fidelity: float             # argmax agreement with monolithic forward
-    met_deadline: bool
+    met_deadline: bool          # sim_latency_s <= deadline
     reward: float
+    logits: object = None       # the plan's logits (device array)
+    routes: object = None       # its dropless MoE routes (stack_routes),
+                                # probed at Request.probe
 
 
 class SplitPlaceEngine:
     def __init__(self, params, cfg, num_stages=2, num_branches=2,
                  phi=0.9, gamma=0.3, ucb_c=0.5, seed=0, num_slices=4):
+        # one weight tree per layer: the plans read each layer's weights
+        # in place (slicing a layer out of a stacked body copies it), and
+        # the layer plan and the monolithic forward unroll alike, so
+        # that they are one computation
+        params, cfg = unrolled(params, cfg)
         self.params = params
         self.cfg = cfg
         self.layer_plan = PlanSpec(LAYER_PLAN, num_stages=num_stages)
@@ -58,11 +80,31 @@ class SplitPlaceEngine:
         from repro.serving.plans import optimal_stage_bounds
         self._stage_bounds = optimal_stage_bounds(cfg, seq=256, batch=1,
                                                   num_stages=num_stages)
-        self._pipe = jax.jit(lambda p, b: pipeline_forward(
-            p, b, cfg, num_stages, bounds=self._stage_bounds))
-        self._branch = jax.jit(lambda p, b: branch_forward(
-            p, b, cfg, num_branches))
-        self._mono = jax.jit(lambda p, b: forward(p, b, cfg)[0])
+
+        # named programs: a device profile tells them apart by name
+        def layer_plan(p, b):
+            return pipeline_forward(p, b, cfg, num_stages,
+                                    bounds=self._stage_bounds,
+                                    with_routes=True)
+
+        def semantic_plan(p, b):
+            return branch_forward(p, b, cfg, num_branches, with_routes=True)
+
+        def monolithic(p, b):
+            return forward(p, b, cfg, with_routes=True)[::2]
+
+        def fidelity(a, b):
+            return (jnp.argmax(a, -1) == jnp.argmax(b, -1)).mean()
+
+        self._pipe, self._branch, self._mono, self._fid = map(
+            jax.jit, (layer_plan, semantic_plan, monolithic, fidelity))
+        # the MAB's decision and Algorithm-1 bookkeeping as programs
+        # (op by op, the bookkeeping's scan would compile on every call)
+        self._decide = jax.jit(mab_mod.decide_ucb, static_argnames=("c",))
+        self._end = jax.jit(mab_mod.end_of_interval,
+                            static_argnames=("phi", "gamma"))
+        self._moe_layers = sum(k in MOE_KINDS for k in cfg.layer_kinds) \
+            if cfg.moe is not None and cfg.moe.dispatch == "dropless" else 0
         # DASO fragment->mesh-slice placement (the paper's placement
         # sub-problem): per-slice queue depth is the state; fragments are
         # pipeline stages or semantic branches
@@ -76,6 +118,7 @@ class SplitPlaceEngine:
             self._daso_cfg, jax.random.PRNGKey(seed))
         self.slice_load = np.zeros(num_slices)
         self._replay = []
+        self._placer_warm = False
 
     def place_fragments(self, plan: int):
         """DASO placement of the plan's fragments onto mesh slices given
@@ -124,48 +167,92 @@ class SplitPlaceEngine:
                 self._theta, self._daso_opt, _ = daso_mod.train_epoch(
                     self._daso_cfg, self._theta, self._daso_opt, xs, ys)
 
-    def warmup(self, batch):
-        b = {"tokens": jnp.asarray(batch)}
-        self._pipe(self.params, b).block_until_ready()
-        self._branch(self.params, b).block_until_ready()
-        self._mono(self.params, b).block_until_ready()
+    def warmup(self, batch, probe=None):
+        """Compile the three programs (and the fidelity score) for this
+        batch shape (and probe size) and, once, the DASO ascent and every
+        trainer batch the replay will reach, so that serving compiles
+        nothing."""
+        b = self._batch(batch, probe)
+        logits = None
+        for fn in (self._pipe, self._branch, self._mono):
+            logits = jax.block_until_ready(fn(self.params, b))[0]
+        self._fid(logits, logits).block_until_ready()
+        if not self._placer_warm:
+            self._warm_placer()
+            self._placer_warm = True
+
+    def _warm_placer(self):
+        saved = (self.slice_load.copy(), self._replay, self._theta,
+                 self._daso_opt)
+        self._replay = [None] * 16
+        for plan in (LAYER_PLAN, SEMANTIC_PLAN):
+            _, _, x = self.place_fragments(plan)
+        for n in range(16, 65, 4):
+            jax.block_until_ready(daso_mod.train_epoch(
+                self._daso_cfg, self._theta, self._daso_opt,
+                jnp.zeros((n,) + x.shape, jnp.float32),
+                jnp.zeros((n,), jnp.float32)))
+        self.slice_load, self._replay, self._theta, self._daso_opt = saved
+
+    @staticmethod
+    def _batch(tokens, probe=None):
+        b = {"tokens": jnp.asarray(tokens)}
+        if probe is not None:
+            b["probe"] = jnp.asarray(probe, jnp.int32)
+        return b
 
     def _run(self, plan_kind: int, batch) -> tuple:
+        """(logits, routes, measured wall seconds) of one plan's program."""
         fn = self._pipe if plan_kind == LAYER_PLAN else self._branch
         t0 = time.perf_counter()
-        logits = fn(self.params, batch)
-        logits.block_until_ready()
-        wall = time.perf_counter() - t0
-        if plan_kind != LAYER_PLAN:
-            # branches run on disjoint mesh slices in parallel on hardware;
-            # this CPU executes them serially, so wall time over-counts by
-            # the branch count
-            wall /= self.sem_plan.num_branches
-        return logits, wall
+        logits, routes = jax.block_until_ready(fn(self.params, batch))
+        return logits, routes, time.perf_counter() - t0
+
+    def _count(self, led, plan, tokens, routes, ref_routes):
+        led.count("plan.semantic" if plan else "plan.layer")
+        led.count("plan.tokens", tokens)
+        if self._moe_layers:
+            branches = self.sem_plan.num_branches if plan else 1
+            led.count("moe.tokens", tokens * self._moe_layers * (branches + 1))
+            led.count("moe.routed_pairs", int(routes["pairs"])
+                      + int(ref_routes["pairs"]))
 
     def serve(self, req: Request) -> ServeResult:
-        batch = {"tokens": jnp.asarray(req.tokens)}
-        d, ctx = mab_mod.decide_ucb(self.state, jnp.float32(req.deadline_s),
-                                    req.app, self.ucb_c)
-        plan = int(d)                     # 0=LAYER(pipeline) 1=SEMANTIC(branch)
-        assign, qcost, daso_x = self.place_fragments(plan)
-        logits, latency = self._run(plan, batch)
-        latency = latency * (1.0 + 0.25 * qcost)   # queueing on busy slices
-        ref = self._mono(self.params, batch)
-        fid = float((jnp.argmax(logits, -1) == jnp.argmax(ref, -1)).mean())
-        met = latency <= req.deadline_s
+        led = get_ledger()
+        batch = self._batch(req.tokens, req.probe)
+        with led.span("plan.decide"):
+            d, ctx = self._decide(self.state, jnp.float32(req.deadline_s),
+                                  req.app, c=self.ucb_c)
+            plan = int(d)                 # 0=LAYER(pipeline) 1=SEMANTIC(branch)
+        with led.span("plan.place"):
+            assign, qcost, daso_x = self.place_fragments(plan)
+        with led.span("plan.run"):
+            logits, routes, latency = self._run(plan, batch)
+        # simulated queueing on the busy slices DASO placed the fragments on
+        sim_latency = latency * (1.0 + 0.25 * qcost)
+        with led.span("plan.fidelity"):
+            # the same batch, probe included: the fidelity forward is
+            # then the layer plan's computation, bit for bit
+            ref, ref_routes = self._mono(self.params, batch)
+            fid = float(self._fid(logits, ref))
+        met = sim_latency <= req.deadline_s
         reward = 0.5 * (float(met) + fid)
-        # Algorithm-1 bookkeeping (single leaving task)
-        self.state = mab_mod.end_of_interval(
-            self.state,
-            jnp.array([req.app], jnp.int32),
-            jnp.array([req.deadline_s], jnp.float32),
-            jnp.array([latency], jnp.float32),
-            jnp.array([fid], jnp.float32),
-            jnp.array([plan], jnp.int32),
-            self.phi, self.gamma)
-        self._daso_feedback(daso_x, reward)
-        return ServeResult(plan, latency, fid, met, reward)
+        with led.span("plan.feedback"):
+            # Algorithm-1 bookkeeping (single leaving task)
+            self.state = self._end(
+                self.state,
+                np.array([req.app], np.int32),
+                np.array([req.deadline_s], np.float32),
+                np.array([sim_latency], np.float32),
+                np.array([fid], np.float32),
+                np.array([plan], np.int32),
+                phi=self.phi, gamma=self.gamma)
+            self._daso_feedback(daso_x, reward)
+        if led.record:
+            self._count(led, plan, int(np.prod(req.tokens.shape)), routes,
+                        ref_routes)
+        return ServeResult(plan, latency, sim_latency, fid, met, reward,
+                           logits, routes)
 
     def serve_many(self, reqs: List[Request]) -> List[ServeResult]:
         return [self.serve(r) for r in reqs]

@@ -74,8 +74,8 @@ def pipeline_shard_map(params, batch, cfg, mesh: Mesh, num_microbatches: int,
             out = act
             for i in range(per_stage):
                 layer = jax.tree.map(lambda a: a[i], local_body)
-                out, _, _ = M.apply_block(kind, layer[f"b0"] if isinstance(
-                    layer, dict) and "b0" in layer else layer, out, ctx, cfg)
+                out = M.apply_block(kind, layer[f"b0"] if isinstance(
+                    layer, dict) and "b0" in layer else layer, out, ctx, cfg)[0]
             return out
 
         right_perm = [(i, i + 1) for i in range(S - 1)]

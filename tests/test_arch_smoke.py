@@ -110,5 +110,5 @@ def test_layer_kinds_cover_patterns():
     assert kinds[:3] == ("rglru", "rglru", "local_attn")
     assert kinds.count("local_attn") == 12          # 12 full periods
     kimi = get_config("kimi-k2-1t-a32b")
-    assert kimi.layer_kinds[0] == "attn"            # first_k_dense
-    assert set(kimi.layer_kinds[1:]) == {"attn_moe"}
+    assert kimi.layer_kinds[0] == "mla"             # first_k_dense
+    assert set(kimi.layer_kinds[1:]) == {"mla_moe"}

@@ -242,3 +242,120 @@ def test_sharded_grid_compiles_for_v5e_2x2(topo):
     out = compiled.output_shardings
     metrics = out["metrics"]
     assert metrics.spec == P("grid") and len(metrics.device_set) == 4
+
+
+# ------------------------------------------------------- the plan engine
+
+PLAN_HBM = 15.5e9
+
+
+@pytest.fixture(scope="module")
+def plan_compiled(one_chip):
+    """(the configuration file, the cut's params by shape, {program:
+    compiled}): the plan engine's three programs for the Kimi-K2 cut
+    (``bench/configs/kimi-k2-ep32.json``, published widths) compiled for
+    one described v5e chip at the traffic's largest request, with the
+    cell's probe positions."""
+    import json
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.paths.plan import program_config
+    from repro.models import init_params
+    from repro.serving.engine import SplitPlaceEngine
+    with open(os.path.join(root, "bench", "configs",
+                           "kimi-k2-ep32.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "bench", "workloads",
+                           "kimi-k2-ep32.splitplace.plan.json")) as f:
+        tr = json.load(f)
+    mcfg = program_config(cfg)
+    L = max(tr["pool"]["lengths"])
+    b = tr["batch"][str(L)]
+    shapes = jax.eval_shape(lambda k: init_params(k, mcfg),
+                            jax.random.PRNGKey(0))
+    on_chip = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), shapes)
+    eng = SplitPlaceEngine(shapes, mcfg, num_stages=tr["stages"],
+                           num_branches=tr["branches"])
+    batch = {"tokens": jax.ShapeDtypeStruct((b, L), jnp.int32,
+                                            sharding=one_chip),
+             "probe": jax.ShapeDtypeStruct((tr["compare"]["positions"],),
+                                           jnp.int32, sharding=one_chip)}
+    progs = {"layer_plan": eng._pipe, "semantic_plan": eng._branch,
+             "monolithic": eng._mono}
+    return cfg, shapes, {name: fn.lower(on_chip, batch).compile()
+                         for name, fn in progs.items()}
+
+
+@pytest.mark.parametrize("program", ["layer_plan", "semantic_plan",
+                                     "monolithic"])
+def test_plan_program_fits_one_v5e(plan_compiled, program):
+    """Each plan program at published widths and the largest request
+    fits under 15.5 GB with its 7 GB of weights."""
+    _, _, compiled = plan_compiled
+    mem = compiled[program].memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 7e9 < mem.argument_size_in_bytes and total < PLAN_HBM, total
+
+
+def test_layer_plan_is_the_monolithic_program(plan_compiled):
+    """The layer plan and the fidelity forward compile to one program
+    (names and source metadata aside), so that they agree bit for bit
+    on the chip: the same batch, probe included, reaches both."""
+    import re
+
+    def body(text):
+        text = re.sub(r", metadata=\{[^}]*\}", "", text)
+        text = re.sub(r",? ?stack_frame_id=\d+", "", text)
+        text = re.sub(r"^HloModule \S+", "HloModule", text, flags=re.M)
+        return [line for line in text.splitlines()
+                if not re.match(r'^\s*\d+ ["{]', line)]
+    _, _, compiled = plan_compiled
+    assert body(compiled["layer_plan"].as_text()) \
+        == body(compiled["monolithic"].as_text())
+
+
+def test_semantic_plan_copies_no_weight(plan_compiled):
+    """The branches read their head and channel slices in place: outside
+    the fused computations (whose operands are not buffers of their own)
+    and the loops' pass-through, the semantic program materialises no
+    array with the size of a weight that is at least half a layer's
+    held-expert stack, whole or one branch's slice of it.  (Activations
+    larger than that stack exist: 2 branches x 16384 tokens x 7168.)"""
+    import math
+    import re
+
+    cfg, shapes, compiled = plan_compiled
+    import jax
+    stack = cfg["n_routed_experts"] * cfg["hidden_size"] \
+        * cfg["moe_intermediate_size"]
+    B = 2
+    big = {math.prod(a.shape) for a in jax.tree.leaves(shapes)
+           if math.prod(a.shape) * B >= stack}
+    forbidden = big | {n // B for n in big}
+    text = compiled["semantic_plan"].as_text()
+    comps = _computations(text)
+    fused = {c for lines in comps.values() for line in lines if " fusion(" in line
+             for c in re.findall(r"calls=%?([\w.\-]+)", line)}
+    skip = ("parameter", "get-tuple-element", "bitcast", "tuple", "while",
+            "conditional", "copy-start", "copy-done")
+    copies = []
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\(.*?\)|\S+) ([\w\-]+)\(",
+                         line)
+            if not m or m.group(2) in skip:
+                continue
+            for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1)):
+                n = math.prod(int(d) for d in dims.split(",") if d)
+                if n in forbidden:
+                    copies.append(line.strip()[:160])
+    assert not copies, copies[:5]
