@@ -5,6 +5,8 @@ differ from the value head dim (MLA: 192 against 128).
 The blockwise path is the pure-JAX twin of ``repro.kernels.flash_attention``
 (the Pallas TPU kernel) and doubles as its oracle; the model uses this path
 for long sequences so compiled temporaries stay O(block) instead of O(seq^2).
+MLA's prefill lowers to ``repro.kernels.causal_flash`` on the TPU
+(``mla_core``).
 """
 from __future__ import annotations
 
@@ -13,6 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.causal_flash import LANES, causal_flash_attention
 from repro.models.layers import (apply_mrope, apply_rope, dense_init,
                                  rmsnorm, yarn_inv_freq, yarn_mscale)
 
@@ -225,47 +228,97 @@ def mla_rope_and_scale(cfg):
         scale
 
 
-def mla_qkv(p, x, positions, cfg):
+def mla_qkv(p, x, positions, cfg, heads_major=False):
     """q (b,s,h,qk), k (b,s,h,qk), v (b,s,h,v) of one MLA layer; the
-    RoPE key is one per position, shared by every head."""
+    RoPE key is one per position, shared by every head.  With
+    ``heads_major`` each comes (b,h,s,.) straight from its projection,
+    and in place of v the key-value projection (b,h,s,nope+v) whose last
+    ``v_head_dim`` columns the values are."""
     a = cfg.mla
     inv_freq, _ = mla_rope_and_scale(cfg)
     y = cfg.rope_scaling
     rs = 1.0 if y is None else (yarn_mscale(y.factor, y.mscale)
                                 / yarn_mscale(y.factor, y.mscale_all_dim))
+    hs = "bhse" if heads_major else "bshe"
     cq = rmsnorm(x @ p["wq_a"], p["q_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsr,rhe->bshe", cq, p["wq_b"])
+    q = jnp.einsum(f"bsr,rhe->{hs}", cq, p["wq_b"])
     kv = x @ p["wkv_a"]
     ckv = rmsnorm(kv[..., :a.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
     k_pe = kv[..., None, a.kv_lora_rank:]                   # (b,s,1,rope)
-    kvb = jnp.einsum("bsr,rhe->bshe", ckv, p["wkv_b"])
+    kvb = jnp.einsum(f"bsr,rhe->{hs}", ckv, p["wkv_b"])
     k_nope, v = kvb[..., :a.qk_nope_head_dim], kvb[..., a.qk_nope_head_dim:]
-    q_pe = apply_rope(q[..., a.qk_nope_head_dim:], positions, cfg.rope_theta,
-                      inv_freq=inv_freq)
+    q_pe = q[..., a.qk_nope_head_dim:]
+    if heads_major:                               # RoPE runs token-major
+        q_pe = q_pe.swapaxes(1, 2)
+    q_pe = apply_rope(q_pe, positions, cfg.rope_theta, inv_freq=inv_freq)
     k_pe = apply_rope(k_pe, positions, cfg.rope_theta, inv_freq=inv_freq)
+    if heads_major:
+        q_pe, k_pe = q_pe.swapaxes(1, 2), k_pe.swapaxes(1, 2)
     if rs != 1.0:
         q_pe, k_pe = q_pe * rs, k_pe * rs
     q = jnp.concatenate([q[..., :a.qk_nope_head_dim], q_pe], axis=-1)
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:-1] + k_pe.shape[-1:])],
         axis=-1)
-    return q, k, v
+    return q, k, (kvb if heads_major else v)
+
+
+def mla_core(cfg, b, h, s, platform, arange_positions=True,
+             threshold=2048) -> str:
+    """The attention core of a full-sequence MLA call of b x s tokens
+    and h heads, lowered for ``platform``: ``"fused"``, the causal flash
+    kernel (on the TPU, for positions 0..s-1 on every row, at least one
+    128-key block, and values that fill whole 128-lane column blocks of
+    the key-value projection); else ``"blockwise"`` above ``threshold``
+    tokens or once the float32 scores would pass 512 MiB (64 heads: 4 x
+    1024 tokens); else ``"full"``."""
+    a = cfg.mla
+    if (platform == "tpu" and arange_positions and s >= LANES
+            and a.v_head_dim % LANES == 0
+            and a.qk_nope_head_dim % a.v_head_dim == 0):
+        return "fused"
+    if s > threshold or b * h * s * s * 4 > 2**29:
+        return "blockwise"
+    return "full"
 
 
 def mla_attention(p, x, ctx, cfg):
-    """Full-sequence MLA (train / prefill); blockwise above the
-    ``blockwise_threshold`` length or once the float32 scores would pass
-    512 MiB (64 heads: 4 x 1024 tokens).  Returns (y, (k, v))."""
+    """Full-sequence MLA (train / prefill) through the core ``mla_core``
+    names: where it may fuse (``ctx["arange_positions"]``, set by
+    ``model._make_ctx`` for a batch that brings no positions), the TPU
+    lowers the causal flash kernel on heads-major operands with the
+    queries scaled in float32, and every other platform the XLA core.
+    Returns (y, (k, v))."""
     _, scale = mla_rope_and_scale(cfg)
     pos = ctx["positions"]
-    q, k, v = mla_qkv(p, x, pos, cfg)
-    b, s, h = q.shape[:3]
-    if s > ctx.get("blockwise_threshold", 2048) or b * h * s * s * 4 > 2**29:
-        out = blockwise_attention(q, k, v, pos, pos, scale=scale,
-                                  causal_skip=ctx.get("causal_skip", False))
-    else:
-        out = full_attention(q, k, v, pos, pos, scale=scale)
-    return jnp.einsum("bshe,hed->bsd", out, p["wo"]), (k, v)
+    b, s = x.shape[:2]
+    h = p["wq_b"].shape[1]
+    threshold = ctx.get("blockwise_threshold", 2048)
+    arange = ctx.get("arange_positions", False)
+
+    def xla(p, x):
+        q, k, v = mla_qkv(p, x, pos, cfg)
+        if mla_core(cfg, b, h, s, None, arange, threshold) == "blockwise":
+            out = blockwise_attention(q, k, v, pos, pos, scale=scale,
+                                      causal_skip=ctx.get("causal_skip",
+                                                          False))
+        else:
+            out = full_attention(q, k, v, pos, pos, scale=scale)
+        return jnp.einsum("bshe,hed->bsd", out, p["wo"]), (k, v)
+
+    def fused(p, x):
+        a = cfg.mla
+        q, k, kvb = mla_qkv(p, x, pos, cfg, heads_major=True)
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+        out = causal_flash_attention(q, k, kvb, hv=a.v_head_dim,
+                                     v_col=a.qk_nope_head_dim // a.v_head_dim)
+        v = kvb[..., a.qk_nope_head_dim:]
+        return jnp.einsum("bhse,hed->bsd", out, p["wo"]), \
+            (k.swapaxes(1, 2), v.swapaxes(1, 2))
+
+    if mla_core(cfg, b, h, s, "tpu", arange, threshold) != "fused":
+        return xla(p, x)
+    return jax.lax.platform_dependent(p, x, tpu=fused, default=xla)
 
 
 def mla_decode(p, x, cache, pos, cfg):

@@ -279,10 +279,11 @@ def output_head(params, xn, cfg):
 def _make_ctx(batch, cfg, constrain, cache_len=0):
     b, s = batch["tokens"].shape[:2]
     positions = batch.get("positions")
-    if positions is None:
+    arange = positions is None
+    if arange:
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     ctx = {"positions": positions, "constrain": constrain or _identity_constrain,
-           "cache_len": cache_len,
+           "cache_len": cache_len, "arange_positions": arange,
            "causal_skip": getattr(cfg, "attn_causal_skip", False)}
     if "probe" in batch:
         ctx["probe"] = batch["probe"]

@@ -17,9 +17,11 @@ The deadline is judged on a simulated latency: the measured one times
 step is a span of the active ``RunLedger`` (``plan.decide``,
 ``plan.place``, ``plan.run``, ``plan.fidelity``, ``plan.feedback``);
 counters ``plan.layer`` / ``plan.semantic`` (requests by plan),
-``plan.tokens``, and, for the dropless MoE layers of the plan and the
+``plan.tokens``; for the dropless MoE layers of the plan and the
 fidelity forward, ``moe.tokens`` and ``moe.routed_pairs`` (token-expert
-pairs computed on the held experts).
+pairs computed on the held experts); and for their MLA layers, one per
+attention call by the core it lowered to (``attention.mla_core``, known
+per compiled program): ``mla.fused``, ``mla.blockwise``, ``mla.full``.
 """
 from __future__ import annotations
 
@@ -33,6 +35,7 @@ import numpy as np
 
 from repro.core import daso as daso_mod
 from repro.core import mab as mab_mod
+from repro.models.attention import mla_core
 from repro.models.model import MOE_KINDS, forward
 from repro.obs import get_ledger
 from repro.serving.plans import (LAYER_PLAN, SEMANTIC_PLAN, PlanSpec,
@@ -105,6 +108,8 @@ class SplitPlaceEngine:
                             static_argnames=("phi", "gamma"))
         self._moe_layers = sum(k in MOE_KINDS for k in cfg.layer_kinds) \
             if cfg.moe is not None and cfg.moe.dispatch == "dropless" else 0
+        self._mla_layers = sum(k in ("mla", "mla_moe")
+                               for k in cfg.layer_kinds)
         # DASO fragment->mesh-slice placement (the paper's placement
         # sub-problem): per-slice queue depth is the state; fragments are
         # pipeline stages or semantic branches
@@ -208,9 +213,18 @@ class SplitPlaceEngine:
         logits, routes = jax.block_until_ready(fn(self.params, batch))
         return logits, routes, time.perf_counter() - t0
 
-    def _count(self, led, plan, tokens, routes, ref_routes):
+    def _count(self, led, plan, shape, routes, ref_routes):
+        tokens = int(np.prod(shape))
         led.count("plan.semantic" if plan else "plan.layer")
         led.count("plan.tokens", tokens)
+        if self._mla_layers:
+            # the plan's program (a branch holds 1/B of the heads; the
+            # branches are one vmapped call) and the fidelity forward
+            b, s = shape[:2]
+            h = self.cfg.num_heads
+            for heads in (h // self.sem_plan.num_branches if plan else h, h):
+                core = mla_core(self.cfg, b, heads, s, jax.default_backend())
+                led.count(f"mla.{core}", self._mla_layers)
         if self._moe_layers:
             branches = self.sem_plan.num_branches if plan else 1
             led.count("moe.tokens", tokens * self._moe_layers * (branches + 1))
@@ -249,8 +263,7 @@ class SplitPlaceEngine:
                 phi=self.phi, gamma=self.gamma)
             self._daso_feedback(daso_x, reward)
         if led.record:
-            self._count(led, plan, int(np.prod(req.tokens.shape)), routes,
-                        ref_routes)
+            self._count(led, plan, req.tokens.shape, routes, ref_routes)
         return ServeResult(plan, latency, sim_latency, fid, met, reward,
                            logits, routes)
 

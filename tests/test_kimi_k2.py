@@ -4,8 +4,11 @@ float32), each against the plain float32 reference of the benchmark
 layout, ``bench/ref/checkpoint.py``) or an identity it must keep: MLA, full and
 blockwise; the sigmoid router with its selection-only bias; dropless
 dispatch over the held experts; the expert-parallel share's additivity;
-the layer plan's exactness; the semantic plan."""
+the layer plan's exactness; the semantic plan; the causal flash kernel
+MLA's prefill lowers to on the TPU (Pallas interpret mode), and when it
+is chosen."""
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -22,9 +25,13 @@ if ROOT not in sys.path:
 from bench.paths.plan import load_params, program_config  # noqa: E402
 from bench.ref import checkpoint  # noqa: E402
 from bench.ref import model as ref  # noqa: E402
+from repro.kernels.causal_flash import causal_flash_attention  # noqa: E402
 from repro.models import attention, forward, init_params  # noqa: E402
 from repro.models import moe as M  # noqa: E402
 from repro.models.layers import rmsnorm  # noqa: E402
+from repro.models.model import _make_ctx  # noqa: E402
+from repro.obs import RunLedger, use_ledger  # noqa: E402
+from repro.serving.engine import Request, SplitPlaceEngine  # noqa: E402
 from repro.serving.plans import branch_forward, pipeline_forward  # noqa: E402
 
 #: the cell's configuration at CPU widths (every mechanism kept)
@@ -190,3 +197,97 @@ def test_plan_matches_reference(branches):
                                  topk[li, br][pos], br, branches)
             assert np.abs(part).max() > 0
             assert rel(routes["probe_y"][li, br], part) < 1e-4
+
+
+# ------------------------------------------- MLA's fused prefill attention
+
+#: published MLA head widths (qk 128 + 64 RoPE, v 128) at a CPU width
+WIDE_HEADS = dict(qk_nope_head_dim=128, qk_rope_head_dim=64,
+                  v_head_dim=128)
+
+
+def _on_the_tpu_path(monkeypatch):
+    """Lower ``mla_attention``'s TPU branch on the CPU, the kernel in
+    Pallas interpret mode."""
+    monkeypatch.setattr(attention, "causal_flash_attention", functools.partial(
+        causal_flash_attention, interpret=True))
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *args, tpu, default: tpu(*args))
+
+
+@pytest.mark.parametrize("L,branches", [(2048, 1), (1100, 1), (2048, 2),
+                                        (1100, 2)])
+def test_fused_mla_matches_full_attention(monkeypatch, L, branches):
+    """The TPU branch of one MLA layer (heads-major projections, queries
+    scaled by Kimi's YaRN scale in float32, the causal flash kernel
+    reading the values inside the key-value projection) against the XLA
+    core's full attention, float32: at a length that is a multiple of the
+    1024 block and one that is padded, plain and vmapped over branches of
+    half the heads, as the semantic plan runs it."""
+    cfg, mcfg = tiny(**WIDE_HEADS)
+    assert (mcfg.mla.qk_head_dim, mcfg.mla.v_head_dim) == (192, 128)
+    p = load_params(cfg, mcfg, 1)["prefix"][0]["attn"]
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, L, cfg["hidden_size"]))
+    ctx = _make_ctx({"tokens": jnp.zeros((2, L), jnp.int32)}, mcfg, None)
+    assert attention.mla_core(mcfg, 2, 4 // branches, L, "tpu") == "fused"
+
+    def run(p, x):
+        return attention.mla_attention(p, x, ctx, mcfg)[0]
+    if branches > 1:
+        cut = {"wq_b": 1, "wkv_b": 1, "wo": 0}
+        p = {n: jnp.moveaxis(a.reshape(a.shape[:cut[n]] + (branches, -1)
+                                       + a.shape[cut[n] + 1:]), cut[n], 0)
+             if n in cut else a for n, a in p.items()}
+        run = jax.vmap(run, in_axes=({n: 0 if n in cut else None
+                                      for n in p}, None))
+    want = run(p, x)
+    _on_the_tpu_path(monkeypatch)
+    got = run(p, x)
+    assert rel(got, want) < 1e-5
+
+
+@pytest.mark.parametrize("case", ["cpu", "tpu", "own_positions"])
+def test_mla_core_choice(case):
+    """Which attention core an MLA call lowers to: the CPU keeps the XLA
+    core, bit for bit; the TPU lowers the kernel, with no loop left;
+    a batch that brings its own positions keeps the XLA core (blockwise
+    at the plan cell's sizes) on the TPU too."""
+    cfg, mcfg = tiny(**WIDE_HEADS)
+    p = load_params(cfg, mcfg, 1)["prefix"][0]["attn"]
+    L = 256
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, L, cfg["hidden_size"]))
+    batch = {"tokens": jnp.zeros((1, L), jnp.int32)}
+    if case == "own_positions":
+        batch["positions"] = jnp.arange(L)[None]
+    ctx = _make_ctx(batch, mcfg, None)
+    run = jax.jit(lambda p, x: attention.mla_attention(p, x, ctx, mcfg)[0])
+    if case == "cpu":
+        assert "tpu_custom_call" not in run.lower(p, x).as_text()
+        xla = _make_ctx(dict(batch, positions=ctx["positions"]), mcfg, None)
+        np.testing.assert_array_equal(np.asarray(run(p, x)), np.asarray(
+            jax.jit(lambda p, x: attention.mla_attention(p, x, xla,
+                                                         mcfg)[0])(p, x)))
+        return
+    text = run.trace(p, x).lower(lowering_platforms=("tpu",)).as_text()
+    fused = case == "tpu"
+    assert ("tpu_custom_call" in text) == fused
+    assert ctx["arange_positions"] == fused
+    for L, h in ((1024, 64), (4096, 64), (4096, 32)):
+        assert attention.mla_core(mcfg, 4, h, L, "tpu", fused) == \
+            ("fused" if fused else "blockwise")
+        assert attention.mla_core(mcfg, 4, h, L, "cpu", fused) == "blockwise"
+
+
+def test_engine_counts_mla_cores():
+    """The engine counts each MLA call of a request, the plan's and the
+    fidelity forward's, by the core it lowered to: on the CPU none is
+    fused."""
+    cfg, mcfg = tiny(**WIDE_HEADS)
+    eng = SplitPlaceEngine(load_params(cfg, mcfg, 1), mcfg)
+    led = RunLedger("mla-cores")
+    with use_ledger(led):
+        for _ in range(2):
+            eng.serve(Request(tokens=tokens(cfg, L=16), deadline_s=1.0))
+    layers = cfg["num_hidden_layers"]
+    assert led.counters.get("mla.fused", 0) == 0
+    assert led.counters.get("mla.full", 0) == 2 * 2 * layers

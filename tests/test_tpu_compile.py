@@ -12,11 +12,15 @@ intervals per chunk, the 50-worker Table-3 fleet):
     scope of the interval body in its operations' metadata, and the
     DASO ascent's float64 products as int8 dots on the MXU;
   * the sharded grid program (``shard_map`` over a 1-D ``"grid"`` mesh)
-    on four described chips, for an 8-cell (seed x λ) grid.
+    on four described chips, for an 8-cell (seed x λ) grid;
+  * the plan engine's three programs for the Kimi-K2 cut at every request
+    length of the plan cell's pool, MLA's attention in each as the
+    causal flash kernel under the ``mla`` scope.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
+import json
 import os
 
 import numpy as np
@@ -24,6 +28,11 @@ import pytest
 
 HBM_BYTES = 16 * 2**30
 K, T, LAM = 512, 64, 6.0
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(ROOT, "bench", "workloads",
+                       "kimi-k2-ep32.splitplace.plan.json")) as _f:
+    PLAN_TRAFFIC = json.load(_f)
+POOL_LENGTHS = sorted(set(PLAN_TRAFFIC["pool"]["lengths"]))
 
 
 @pytest.fixture(scope="module")
@@ -250,46 +259,61 @@ PLAN_HBM = 15.5e9
 
 
 @pytest.fixture(scope="module")
-def plan_compiled(one_chip):
-    """(the configuration file, the cut's params by shape, {program:
+def plan_setup(one_chip):
+    """(the configuration file, the cut's params by shape, L -> {program:
     compiled}): the plan engine's three programs for the Kimi-K2 cut
     (``bench/configs/kimi-k2-ep32.json``, published widths) compiled for
-    one described v5e chip at the traffic's largest request, with the
-    cell's probe positions."""
-    import json
+    one described v5e chip at a request of the traffic's batch and
+    length L, with the cell's probe positions."""
     import sys
 
     import jax
     import jax.numpy as jnp
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if root not in sys.path:
-        sys.path.insert(0, root)
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
     from bench.paths.plan import program_config
     from repro.models import init_params
     from repro.serving.engine import SplitPlaceEngine
-    with open(os.path.join(root, "bench", "configs",
+    with open(os.path.join(ROOT, "bench", "configs",
                            "kimi-k2-ep32.json")) as f:
         cfg = json.load(f)
-    with open(os.path.join(root, "bench", "workloads",
-                           "kimi-k2-ep32.splitplace.plan.json")) as f:
-        tr = json.load(f)
+    tr = PLAN_TRAFFIC
     mcfg = program_config(cfg)
-    L = max(tr["pool"]["lengths"])
-    b = tr["batch"][str(L)]
     shapes = jax.eval_shape(lambda k: init_params(k, mcfg),
                             jax.random.PRNGKey(0))
     on_chip = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=one_chip), shapes)
     eng = SplitPlaceEngine(shapes, mcfg, num_stages=tr["stages"],
                            num_branches=tr["branches"])
-    batch = {"tokens": jax.ShapeDtypeStruct((b, L), jnp.int32,
-                                            sharding=one_chip),
-             "probe": jax.ShapeDtypeStruct((tr["compare"]["positions"],),
-                                           jnp.int32, sharding=one_chip)}
     progs = {"layer_plan": eng._pipe, "semantic_plan": eng._branch,
              "monolithic": eng._mono}
-    return cfg, shapes, {name: fn.lower(on_chip, batch).compile()
-                         for name, fn in progs.items()}
+
+    def compile_at(L):
+        b = tr["batch"][str(L)]
+        batch = {"tokens": jax.ShapeDtypeStruct((b, L), jnp.int32,
+                                                sharding=one_chip),
+                 "probe": jax.ShapeDtypeStruct((tr["compare"]["positions"],),
+                                               jnp.int32, sharding=one_chip)}
+        return {name: fn.lower(on_chip, batch).compile()
+                for name, fn in progs.items()}
+    return cfg, shapes, compile_at
+
+
+@pytest.fixture(scope="module")
+def plan_compiled(plan_setup):
+    """(the configuration file, the cut's params by shape, {program:
+    compiled}) at the traffic's largest request."""
+    cfg, shapes, compile_at = plan_setup
+    return cfg, shapes, compile_at(max(POOL_LENGTHS))
+
+
+@pytest.fixture(scope="module", params=POOL_LENGTHS)
+def plan_at_length(request, plan_setup, plan_compiled):
+    """(L, {program: compiled}) at each request length of the pool."""
+    L = request.param
+    if L == max(POOL_LENGTHS):
+        return L, plan_compiled[2]
+    return L, plan_setup[2](L)
 
 
 @pytest.mark.parametrize("program", ["layer_plan", "semantic_plan",
@@ -308,17 +332,63 @@ def test_layer_plan_is_the_monolithic_program(plan_compiled):
     """The layer plan and the fidelity forward compile to one program
     (names and source metadata aside), so that they agree bit for bit
     on the chip: the same batch, probe included, reaches both."""
+    _, _, compiled = plan_compiled
+    assert _program_body(compiled["layer_plan"].as_text()) \
+        == _program_body(compiled["monolithic"].as_text())
+
+
+def _program_body(text):
+    """A compiled program's HLO without its names and source metadata."""
+    import re
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r",? ?stack_frame_id=\d+", "", text)
+    text = re.sub(r"^HloModule \S+", "HloModule", text, flags=re.M)
+    return [line for line in text.splitlines()
+            if not re.match(r'^\s*\d+ ["{]', line)]
+
+
+@pytest.mark.parametrize("program", ["layer_plan", "semantic_plan",
+                                     "monolithic"])
+def test_plan_mla_runs_the_fused_kernel(plan_compiled, plan_at_length,
+                                        program):
+    """At every request length of the pool, each plan program runs MLA's
+    attention as the causal flash kernel, one Mosaic call per MLA layer
+    (the semantic plan's branches vmapped into it), each under the
+    ``mla`` scope that ``mla_ms_per_request.plan`` reads (``vmap(mla)``
+    in the semantic plan, as all its MLA operations are); no float32
+    (..., 512, 1024) score block of the blockwise path and no loop under
+    ``mla`` is left; and the program fits one chip."""
     import re
 
-    def body(text):
-        text = re.sub(r", metadata=\{[^}]*\}", "", text)
-        text = re.sub(r",? ?stack_frame_id=\d+", "", text)
-        text = re.sub(r"^HloModule \S+", "HloModule", text, flags=re.M)
-        return [line for line in text.splitlines()
-                if not re.match(r'^\s*\d+ ["{]', line)]
-    _, _, compiled = plan_compiled
-    assert body(compiled["layer_plan"].as_text()) \
-        == body(compiled["monolithic"].as_text())
+    from bench import trace_phases
+    from bench.paths.plan import SCOPES
+    L, compiled = plan_at_length
+    text = compiled[program].as_text()
+    scopes = {op: scope for (_, op), scope
+              in trace_phases.op_scopes(text).items()}
+    kernels = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = .*custom-call\(.*"
+                         r'custom_call_target="tpu_custom_call".*'
+                         r'op_name="[^"]*causal_flash', text, re.M)
+    assert len(kernels) == plan_compiled[0]["num_hidden_layers"], \
+        (L, program, kernels)
+    scope = "vmap(mla)" if program == "semantic_plan" else "mla"
+    assert all(trace_phases.phase_of(scopes[k], SCOPES + (scope,)) == scope
+               for k in kernels), [scopes[k] for k in kernels]
+    assert not re.search(r"f32\[[\d,]*512,1024\]", text)
+    assert not [n for n in re.findall(r' while\(.*op_name="([^"]*)"', text)
+                if "/mla/" in n]
+    mem = compiled[program].memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < PLAN_HBM, (L, program, total)
+
+
+def test_layer_plan_is_the_monolithic_program_at_each_length(plan_at_length):
+    """The layer plan and the fidelity forward are one program at every
+    request length of the pool."""
+    _, compiled = plan_at_length
+    assert _program_body(compiled["layer_plan"].as_text()) \
+        == _program_body(compiled["monolithic"].as_text())
 
 
 def test_semantic_plan_copies_no_weight(plan_compiled):
