@@ -61,13 +61,18 @@ def run(n_intervals=20, substeps=10, sizes=(1, 4, 8, 16, 32, 64),
     from repro.env import jaxsim
     from repro.launch import experiments
 
-    dec = jaxsim.make_static_decider("bestfit-rr")
+    pol = jaxsim.resolve("bestfit-rr")
 
     def compile_cells(cells):
-        return [jaxsim.compile_trace(dec, lam=lam, seed=seed,
+        return [jaxsim.compile_trace(pol.decider, lam=lam, seed=seed,
                                      n_intervals=n_intervals,
                                      substeps=substeps)
                 for lam, seed in cells]
+
+    def run_grid(traces, **kw):
+        return jaxsim.run_grid_engine(pol.engine, traces, pol.state,
+                                      max_active=max_active,
+                                      substep_impl=substep_impl, **kw)
 
     out = {"policy": "bestfit-rr", "n_intervals": n_intervals,
            "substeps": substeps, "max_active": max_active,
@@ -81,9 +86,7 @@ def run(n_intervals=20, substeps=10, sizes=(1, 4, 8, 16, 32, 64),
     cells8 = grid_cells(8)
     traces8 = compile_cells(cells8)
     t0 = time.perf_counter()
-    batched = jaxsim.run_grid_arrays(traces8, max_active=max_active,
-                                     substep_impl=substep_impl,
-                                     telemetry=telemetry)
+    batched = run_grid(traces8, telemetry=telemetry)
     compile_s = time.perf_counter() - t0
     if telemetry == "interval":
         from repro.obs import get_ledger
@@ -112,14 +115,10 @@ def run(n_intervals=20, substeps=10, sizes=(1, 4, 8, 16, 32, 64),
     def measure(size, reps):
         cells = grid_cells(size)
         traces = compile_cells(cells)
-        jaxsim.run_grid_arrays(traces, max_active=max_active,
-                               substep_impl=substep_impl,
-                               telemetry=telemetry)  # warm/compile
+        run_grid(traces, telemetry=telemetry)  # warm/compile
         tb, th = [], []
         for _ in range(reps):
-            tb.append(_timed(lambda: jaxsim.run_grid_arrays(
-                traces, max_active=max_active, substep_impl=substep_impl,
-                telemetry=telemetry)))
+            tb.append(_timed(lambda: run_grid(traces, telemetry=telemetry)))
             th.append(_timed(lambda: [experiments.run_trace(
                 policy=jaxsim.host_policy("bestfit-rr"),
                 n_intervals=n_intervals, lam=lam, seed=seed,
@@ -155,9 +154,7 @@ def run(n_intervals=20, substeps=10, sizes=(1, 4, 8, 16, 32, 64),
 
     # ---- telemetry overhead: the in-carry series must be ~free ---------
     def run8(tel):
-        return jaxsim.run_grid_arrays(traces8, max_active=max_active,
-                                      substep_impl=substep_impl,
-                                      telemetry=tel)
+        return run_grid(traces8, telemetry=tel)
 
     run8("interval")                          # warm/compile interval mode
     run8("summary")                           # warm (cache hit)
@@ -183,14 +180,10 @@ def run(n_intervals=20, substeps=10, sizes=(1, 4, 8, 16, 32, 64),
         nt = len(traces)
 
         def single():
-            return jaxsim.run_grid_arrays(traces, max_active=max_active,
-                                          threads=1,
-                                          substep_impl=substep_impl)
+            return run_grid(traces, threads=1)
 
         def sharded():
-            return jaxsim.run_grid_arrays(traces, max_active=max_active,
-                                          devices=d,
-                                          substep_impl=substep_impl)
+            return run_grid(traces, devices=d)
 
         base, shd = single(), sharded()      # warm/compile both paths
         for i, (b, s) in enumerate(zip(base, shd)):
@@ -219,9 +212,7 @@ def run(n_intervals=20, substeps=10, sizes=(1, 4, 8, 16, 32, 64),
     if profile_dir:
         from repro.obs import get_ledger
         with get_ledger().profile(profile_dir):
-            jaxsim.run_grid_arrays(traces8, max_active=max_active,
-                                   substep_impl=substep_impl,
-                                   telemetry=telemetry)
+            run_grid(traces8, telemetry=telemetry)
 
     if out_json:
         os.makedirs(os.path.dirname(out_json), exist_ok=True)

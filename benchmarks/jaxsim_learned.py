@@ -6,7 +6,8 @@ MAB decider + array-form DASO placer) running inside the jitted interval
 kernel.  Two measurements over (seed × λ) dual-trace grids:
 
   * **parity** — the 8-trace acceptance grid run through
-    ``run_grid_arrays_learned`` must match per-trace host-loop replays
+    the ``"splitplace"`` engine of ``jaxsim.resolve`` must match
+    per-trace host-loop replays
     (``replay_trace_edgesim_learned``: EdgeSim physics + the identical
     shared MAB/DASO pure functions) within ``allclose(rtol=1e-4)`` on
     every summary metric, including the final carried-MAB scalars;
@@ -147,10 +148,12 @@ def _run_ledgered(jaxsim, experiments, led, n_intervals, substeps, sizes,
                                           substeps=substeps)
                 for lam, seed in cells]
 
+    sp = jaxsim.resolve("splitplace", mab_state=pre.mab_state,
+                        daso_theta=pre.daso_theta, daso_cfg=pre.daso_cfg)
+
     def batched(traces, tel=telemetry):
-        return jaxsim.run_grid_arrays_learned(
-            traces, pre.mab_state, daso_theta=pre.daso_theta,
-            daso_cfg=pre.daso_cfg, max_active=max_active, telemetry=tel)
+        return jaxsim.run_grid_engine(sp.engine, traces, sp.state,
+                                      max_active=max_active, telemetry=tel)
 
     def host_loop(traces):
         return [jaxsim.replay_trace_edgesim_learned(
@@ -270,11 +273,15 @@ def run_train(n_intervals=40, substeps=5, max_active=160,
                                         substeps=substeps)
               for lam, seed in grid_cells(8)]
 
+    sp = jaxsim.resolve("splitplace", mode="train",
+                        mab_state=pre.mab_state, daso_theta=pre.daso_theta,
+                        daso_cfg=pre.daso_cfg,
+                        daso_opt_state=pre.daso_opt_state, train_hp=train_hp)
+
     def batched():
-        return jaxsim.run_grid_arrays_trained(
-            traces, pre.mab_state, daso_theta=pre.daso_theta,
-            daso_cfg=pre.daso_cfg, daso_opt_state=pre.daso_opt_state,
-            max_active=max_active, train_hp=train_hp, telemetry=telemetry)
+        return jaxsim.run_grid_engine(sp.engine, traces, sp.state,
+                                      max_active=max_active,
+                                      telemetry=telemetry)
 
     def host_loop():
         return [jaxsim.replay_trace_edgesim_trained(
@@ -344,9 +351,12 @@ def run_baselines(n_intervals=20, substeps=10, max_active=96,
                                      variants=(LAYER, COMPRESSED))
            for lam, seed in grid_cells(8)]
 
+    gillis = jaxsim.resolve("gillis")
+
     def g_batched():
-        return jaxsim.run_grid_arrays_gillis(gtr, max_active=max_active,
-                                             telemetry=telemetry)
+        return jaxsim.run_grid_engine(gillis.engine, gtr, gillis.state,
+                                      max_active=max_active,
+                                      telemetry=telemetry)
 
     def g_host():
         return [jaxsim.replay_trace_edgesim_gillis(tr) for tr in gtr]
@@ -381,10 +391,13 @@ def run_baselines(n_intervals=20, substeps=10, max_active=96,
                                      substeps=substeps)
            for lam, seed in grid_cells(8)]
 
+    gobi = jaxsim.resolve("mab+gobi", mab_state=pre.mab_state,
+                          daso_theta=pre.daso_theta, daso_cfg=pre.daso_cfg)
+
     def b_batched():
-        return jaxsim.run_grid_arrays_learned(
-            btr, pre.mab_state, daso_theta=pre.daso_theta, daso_cfg=blind,
-            max_active=max_active, telemetry=telemetry)
+        return jaxsim.run_grid_engine(gobi.engine, btr, gobi.state,
+                                      max_active=max_active,
+                                      telemetry=telemetry)
 
     def b_host():
         return [jaxsim.replay_trace_edgesim_learned(

@@ -5,11 +5,12 @@
     python chip_smoke.py --chips 4   # four chips: the sharded grid only
 
 One process drives the simulator's main path through its user entry
-points (``launch.experiments.run_stream`` / ``run_grid_batched`` and the
-``jaxsim.run_trace_arrays*`` drivers), phase by phase.  Every check
-raises on failure, so the script exits non-zero unless all phases pass;
-it also exits non-zero, before any phase, when JAX finds no TPU.  The
-last line of standard output is one JSON object naming the device:
+points (``launch.experiments.run_stream`` / ``run_grid_batched`` and
+``jaxsim.resolve`` → ``jaxsim.run_trace_engine``), phase by phase.
+Every check raises on failure, so the script exits non-zero unless all
+phases pass; it also exits non-zero, before any phase, when JAX finds no
+TPU.  The last line of standard output is one JSON object naming the
+device:
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
 
@@ -166,18 +167,20 @@ def phase_parity(inputs, n_intervals=100):
     from repro.env import jaxsim
     mab_state, theta, cfg = inputs
     cpu = jax.devices("cpu")[0]
-    tr = jaxsim.compile_trace(jaxsim.make_static_decider("mc"), lam=LAM,
-                              seed=0, n_intervals=n_intervals)
+    mc = jaxsim.resolve("mc")
+    sp = jaxsim.resolve("splitplace", mab_state=mab_state, daso_theta=theta,
+                        daso_cfg=cfg)
+    tr = jaxsim.compile_trace(mc.decider, lam=LAM, seed=0,
+                              n_intervals=n_intervals)
     dual = jaxsim.compile_trace_dual(lam=LAM, seed=0,
                                      n_intervals=n_intervals)
-    got = jaxsim.run_trace_arrays(tr)
+    got = jaxsim.run_trace_engine(mc.engine, tr, mc.state(tr.seed))
     with jax.default_device(cpu):
         ref = jaxsim.replay_trace_edgesim(tr)
     assert_close("parity mc", ref, got)
     print(f"parity mc: {len(ref)} metrics within rtol={RTOL}, "
           f"{ref['tasks_completed']} tasks", flush=True)
-    got_sp = jaxsim.run_trace_arrays_learned(dual, mab_state,
-                                             daso_theta=theta, daso_cfg=cfg)
+    got_sp = jaxsim.run_trace_engine(sp.engine, dual, sp.state(dual.seed))
     with jax.default_device(cpu):
         ref = jaxsim.replay_trace_edgesim_learned(dual, mab_state,
                                                   daso_theta=theta,

@@ -34,7 +34,7 @@ every shape is compile-time static:
 Workloads are compiled host-side (``arrays.compile_trace``) — Poisson
 arrivals, split decisions from a *static* decider
 (``policies.make_static_decider``), realized fragments, pre-sampled
-accuracies, and mobility multipliers — then ``driver.run_grid_arrays``
+accuracies, and mobility multipliers — then ``driver.run_grid_engine``
 runs the whole grid batched.  Equivalence vs the host ``EdgeSim`` is
 ``allclose`` on per-trace summary metrics (response times, energy, cost,
 utilization-derived quantities) against ``reference.replay_trace_edgesim``,
@@ -45,7 +45,9 @@ Every policy runs through ONE interval program driven by a
 **PolicyEngine** (``engines``): the carry is ``(slot state, metric
 accumulators, engine_state)`` and engines supply the
 ``decide/place/feedback`` hooks — one runner cache, chunk dispatcher
-and summary path for all of them.  Learned policies run **in-kernel**
+and summary path for all of them.  ``policies.resolve`` is the one
+table from a policy name to its engine, starting engine state and trace
+variants.  Learned policies run **in-kernel**
 (``policies.LEARNED_POLICIES``): the SplitPlace MAB decider threads its
 ``MABState`` through the interval carry — online UCB decisions realized
 against dual-variant traces (``arrays.compile_trace_dual``),
@@ -54,14 +56,15 @@ per-interval reward feedback and RBED ε-decay
 (``kernels.daso_requests``) gradient-ascends the pretrained placement
 surrogate between the BestFit request and feasibility-repair stages
 (``"mab+gobi"`` is the decision-blind GOBI ablation of the same
-machinery).  ``mode="train"`` (``run_*_arrays_trained``) moves the full
-§6.3 *training* loop in-kernel too: ε-greedy decisions (eq. 6) from a
-fold-in key threaded through the carry, and online DASO finetuning —
+machinery).  ``mode="train"`` (``resolve(..., mode="train")``) moves
+the full §6.3 *training* loop in-kernel too: ε-greedy decisions (eq. 6)
+from a fold-in key threaded through the carry, and online DASO
+finetuning —
 each interval appends its (packed placement features, O^P) pair into a
 carried fixed 64-row replay window and advances (theta, opt_state)
 with ``daso.train_epoch_weighted`` epochs, so the surrogate the placer
 ascends is the finetuned one.  The Gillis baseline
-(``run_*_arrays_gillis``) carries its contextual ε-greedy Q-table over
+(``resolve("gillis")``) carries its contextual ε-greedy Q-table over
 (LAYER, COMPRESSED) dual traces with per-interval ε-decay and
 sequential TD(0) updates.  The parity references are
 ``reference.replay_trace_edgesim_learned`` /
@@ -74,26 +77,17 @@ from repro.env.jaxsim.arrays import (ClusterArrays, DualTraceArrays,
                                      TraceArrays, chunk_tapes, compile_trace,
                                      compile_trace_dual, default_capacity,
                                      stack_traces)
-from repro.env.jaxsim.driver import (GILLIS_HP, MAB_HP,
-                                     STATIC_DASO_ARMS, TRAIN_HP,
-                                     cache_stats, clear_cache,
-                                     set_cache_limit,
-                                     gillis_init_state, run_grid_arrays,
-                                     run_grid_arrays_gillis,
-                                     run_grid_arrays_learned,
-                                     run_grid_arrays_static_daso,
-                                     run_grid_arrays_trained,
-                                     run_grid_engine, run_trace_arrays,
-                                     run_trace_arrays_gillis,
-                                     run_trace_arrays_learned,
-                                     run_trace_arrays_static_daso,
-                                     run_trace_arrays_trained,
-                                     run_trace_engine, trace_train_key)
+from repro.env.jaxsim.driver import (cache_stats, clear_cache,
+                                     run_grid_engine, run_trace_engine,
+                                     set_cache_limit)
+from repro.env.jaxsim.engines import (GILLIS_HP, MAB_HP, TRAIN_HP,
+                                      gillis_init_state, trace_train_key)
 from repro.env.jaxsim.policies import (DASO_LEARNED_POLICIES,
                                        LEARNED_POLICIES,
                                        MAB_LEARNED_POLICIES,
-                                       STATIC_POLICIES, host_policy,
-                                       make_static_decider)
+                                       STATIC_DASO_ARMS, STATIC_POLICIES,
+                                       host_policy, make_static_decider,
+                                       resolve)
 from repro.env.jaxsim.stream import (RollingMetrics, StreamFeeder,
                                      StreamRunner, make_stream_policy,
                                      replay_stream, serve)
@@ -111,12 +105,7 @@ __all__ = [
     "set_cache_limit", "engines", "gillis_init_state",
     "RollingMetrics", "StreamFeeder", "StreamRunner", "make_stream_policy",
     "replay_stream", "serve",
-    "run_grid_arrays", "run_grid_arrays_gillis", "run_grid_arrays_learned",
-    "run_grid_arrays_static_daso", "run_grid_arrays_trained",
-    "run_grid_engine", "run_trace_arrays",
-    "run_trace_arrays_gillis", "run_trace_arrays_learned",
-    "run_trace_arrays_static_daso", "run_trace_arrays_trained",
-    "run_trace_engine", "trace_train_key",
+    "run_grid_engine", "run_trace_engine", "resolve", "trace_train_key",
     "DASO_LEARNED_POLICIES", "LEARNED_POLICIES", "MAB_LEARNED_POLICIES",
     "STATIC_POLICIES", "host_policy", "make_static_decider",
     "replay_trace_edgesim", "replay_trace_edgesim_gillis",

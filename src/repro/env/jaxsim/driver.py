@@ -10,9 +10,9 @@ runner cache, one static key, one chunk dispatcher and one summary path
 serve every engine — adding a policy adds an engine + a host parity
 oracle, never another driver copy.
 
-``run_trace_arrays*`` / ``run_grid_arrays*`` are thin engine-selecting
-wrappers kept for API stability; ``run_trace_engine`` /
-``run_grid_engine`` are the generic entry points.
+The driver names no policy: ``run_trace_engine`` / ``run_grid_engine``
+run whatever engine and starting state they are handed, and
+``policies.resolve`` turns a policy name into both.
 
 Executables are cached on ``(engine, T, A, K, F, n, substeps,
 interval_s, swap)`` — engines are frozen hashable dataclasses — so a
@@ -29,7 +29,7 @@ import os
 import warnings
 from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
-from typing import Callable, Optional, Sequence
+from typing import Callable, Optional
 
 import numpy as np
 
@@ -38,9 +38,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.env.cluster import Cluster, make_cluster
-from repro.env.jaxsim import engines, kernels
-from repro.env.jaxsim.arrays import (ClusterArrays, DualTraceArrays,
-                                     TraceArrays, default_capacity,
+from repro.env.jaxsim import kernels
+from repro.env.jaxsim.arrays import (ClusterArrays, default_capacity,
                                      stack_traces)
 from repro.env.metrics import TELEMETRY_COLS, series_percentiles
 from repro.obs import get_ledger
@@ -161,22 +160,6 @@ def _donation_ok() -> bool:
                 jax.jit(lambda v: v + 1.0, donate_argnums=0)(probe))
         ok = _DONATION_OK[backend] = bool(probe.is_deleted())
     return ok
-
-#: MAB hyperparameters of the in-kernel learned policies, matching the
-#: host ``MABDecider`` defaults: (ucb_c, phi, gamma, k)
-MAB_HP = (0.5, 0.3, 0.3, 0.1)
-
-#: DASO finetuning hyperparameters, matching the host ``SurrogatePlacer``
-#: defaults: (alpha, beta, train_steps, place_min, train_min) — the last
-#: two are the cold-start gates (ascend the surrogate only after
-#: ``place_min`` replay records, train only after ``train_min``);
-#: lowering them lets short test/benchmark horizons exercise the
-#: finetuned-ascent path the defaults reserve for long traces
-TRAIN_HP = (0.5, 0.5, 4, 32, 8)
-
-#: Gillis baseline hyperparameters, matching the host ``GillisDecider``
-#: defaults: (eps0, lr, decay)
-GILLIS_HP = (0.5, 0.3, 0.995)
 
 #: layout of the packed per-substep metric accumulator (one dot per
 #: substep): [n_fin, Σresp, n_viol, Σacc, Σreward, Σwait, fin_dec·3]
@@ -655,7 +638,7 @@ def _run_grid_sharded(engine, traces, es_builder, cl, cld, K,
     # pretrained theta, carried MAB scalars), so copy instead of
     # aliasing — donation must only consume buffers this call owns
     es0 = jax.tree_util.tree_map(lambda v: jnp.array(v, copy=True),
-                                 es_builder(padded))
+                                 es_builder([t.seed for t in padded]))
     key = _static_key(engine, leaves, K, cl.n, t0.substeps, t0.interval_s,
                       swap_slowdown, substep_impl, telemetry)
     runner = _get_sharded_runner(key, mesh)
@@ -673,6 +656,21 @@ def _run_grid_sharded(engine, traces, es_builder, cl, cld, K,
 # ------------------------------------------------- generic engine runners
 
 
+def _check_variants(engine, traces):
+    """A dual trace's V axis must realize the decision codes the engine
+    decides between (``engine.variants``) — an MAB trace fed to the
+    Gillis engine (or vice versa) would mislabel fragments as the wrong
+    split."""
+    if engine.variants is None:
+        return
+    for t in traces:
+        got = tuple(getattr(t, "variants", (0, 1)))
+        if got != tuple(engine.variants):
+            raise ValueError(
+                f"trace realizes variants {got}, engine needs "
+                f"{tuple(engine.variants)} (compile_trace_dual(variants=...))")
+
+
 def run_trace_engine(engine, trace, es0, cluster: Optional[Cluster] = None,
                      max_active: Optional[int] = None,
                      swap_slowdown: float = 0.5,
@@ -685,6 +683,7 @@ def run_trace_engine(engine, trace, es0, cluster: Optional[Cluster] = None,
     telemetry series in the carry and attaches it (plus percentile
     estimates) to the summary; ``"summary"`` compiles the exact program
     this driver has always run."""
+    _check_variants(engine, [trace])
     tcols = _check_telemetry(engine, telemetry)
     led = get_ledger()
     cluster = cluster or make_cluster()
@@ -719,10 +718,11 @@ def run_grid_engine(engine, traces, es_builder: Callable,
     """Run a whole grid of compiled traces through the jitted vmapped
     engine program; returns one summary dict per trace (same order).
 
-    ``es_builder(chunk)`` produces the engine-state pytree for one trace
-    chunk (shared leaves + any per-cell leaves like PRNG keys, marked by
-    ``engine.batch_axes()``); it runs inside the driver's ``enable_x64``
-    scope so float64 state construction is safe.
+    ``es_builder(seeds)`` produces the engine-state pytree for one trace
+    chunk from its traces' seeds (shared leaves + any per-cell leaves
+    like PRNG keys, marked by ``engine.batch_axes()``) —
+    ``policies.resolve(...).state``; it runs inside the driver's
+    ``enable_x64`` scope so float64 state construction is safe.
 
     Dispatch is two-mode.  Default (``devices=None``): the grid is split
     into ``threads`` equal vmap chunks dispatched from a thread pool —
@@ -735,6 +735,7 @@ def run_grid_engine(engine, traces, es_builder: Callable,
     are independent per trace, so neither chunking nor sharding changes
     anything numerically.
     """
+    _check_variants(engine, traces)
     tcols = _check_telemetry(engine, telemetry)
     led = get_ledger()
     cluster = cluster or make_cluster()
@@ -763,7 +764,8 @@ def run_grid_engine(engine, traces, es_builder: Callable,
                 leaves = {k: jnp.asarray(v)
                           for k, v in stack_traces(chunk, max_arrivals=A,
                                                    max_frags=F).items()}
-                es0 = jax.tree_util.tree_map(jnp.asarray, es_builder(chunk))
+                es0 = jax.tree_util.tree_map(
+                    jnp.asarray, es_builder([t.seed for t in chunk]))
                 key = _static_key(engine, leaves, K, cl.n, t0.substeps,
                                   t0.interval_s, swap_slowdown, impl,
                                   telemetry)
@@ -790,387 +792,3 @@ def run_grid_engine(engine, traces, es_builder: Callable,
                     row, t0.interval_s, t0.n_intervals, cost_total,
                     telemetry_cols=tcols)))
     return results
-
-
-# ------------------------------------------------ engine-state assembly
-
-
-def _check_variants(traces, expected):
-    """A dual trace's V axis must realize the decision codes the engine
-    decides between — an MAB trace fed to the Gillis engine (or vice
-    versa) would mislabel fragments as the wrong split."""
-    for t in traces:
-        got = tuple(getattr(t, "variants", (0, 1)))
-        if got != tuple(expected):
-            raise ValueError(
-                f"trace realizes variants {got}, engine needs "
-                f"{tuple(expected)} (compile_trace_dual(variants=...))")
-
-
-def _check_learned_args(daso_cfg, daso_theta, n):
-    if daso_cfg is None:
-        return ()                         # BestFit placement: no surrogate
-    if daso_theta is None:
-        raise ValueError("the DASO placer needs pretrained theta "
-                         "(see launch.experiments.pretrain)")
-    if daso_cfg.num_workers != n:
-        raise ValueError(f"daso_cfg.num_workers={daso_cfg.num_workers} "
-                         f"!= cluster size {n}")
-    return daso_theta
-
-
-def _trained_opt_state(daso_cfg, theta, daso_opt_state):
-    """The AdamW state the training carry starts from — fresh zeros when
-    the caller didn't hand over the pretraining optimizer moments."""
-    if daso_cfg is None:
-        return ()
-    from repro.optim.optimizers import adamw_init
-    if daso_opt_state is None:
-        return adamw_init(theta)
-    return daso_opt_state
-
-
-def trace_train_key(seed: int):
-    """The per-trace decision PRNG key of the in-kernel training and
-    Gillis loops — shared with ``reference.replay_trace_edgesim_trained``
-    / ``replay_trace_edgesim_gillis`` so both backends draw identical
-    ε-greedy bits."""
-    return jax.random.PRNGKey(seed)
-
-
-def _deploy_es(mab_state, theta):
-    return {"mab": mab_state, "theta": theta}
-
-
-def _train_es(daso_cfg, mab_state, theta, daso_opt_state, keys):
-    """Training-carry starting state; built under ``enable_x64`` so the
-    replay window is float64 like the in-carry appends."""
-    with jax.enable_x64(True):
-        import repro.core.daso as daso_mod
-        win = daso_mod.window_init(daso_cfg) if daso_cfg is not None else {}
-        opt = _trained_opt_state(daso_cfg, theta, daso_opt_state)
-    return {"mab": mab_state, "theta": theta, "opt": opt, "win": win,
-            "key": keys}
-
-
-def gillis_layer_ref(num_apps: int = 3):
-    """The (num_apps,) unloaded layer-chain reference table the Gillis
-    context bucket divides deadlines by (``mab.gillis_bucket``) — built
-    once here so the kernel engine and the host parity oracle consume
-    the identical float64 values."""
-    from repro.env.workload import layer_ref_response_s
-    return np.array([layer_ref_response_s(a) for a in range(num_apps)],
-                    np.float64)
-
-
-def gillis_init_state(num_apps: int = 3, eps0: float = GILLIS_HP[0]):
-    """Fresh host-side Gillis carry pieces (Q-table + ε) — NumPy float64
-    so the driver's ``enable_x64`` asarray keeps full precision.  Pass a
-    previous run's ``{"Q": gillis_q, "eps": gillis_eps}`` instead to
-    continue a pretrained baseline."""
-    return {"Q": np.zeros((num_apps, 2, 2), np.float64),
-            "eps": np.float64(eps0)}
-
-
-def _gillis_es(gillis_state, keys, num_apps: int, eps0: float):
-    st = gillis_state or gillis_init_state(num_apps, eps0)
-    return {"Q": np.asarray(st["Q"], np.float64),
-            "eps": np.float64(st["eps"]), "key": keys,
-            "layer_ref": gillis_layer_ref(num_apps)}
-
-
-# ------------------------------------------------- engine-selecting API
-#
-# Thin wrappers that pick an engine + assemble its starting state; every
-# one funnels into run_trace_engine / run_grid_engine above.  Kept for
-# API stability (benchmarks, experiments, tests) — there is exactly one
-# interval-program family behind them.
-
-
-def run_grid_arrays(traces: Sequence[TraceArrays],
-                    cluster: Optional[Cluster] = None,
-                    max_active: Optional[int] = None,
-                    swap_slowdown: float = 0.5,
-                    threads: Optional[int] = None,
-                    devices=None,
-                    substep_impl: Optional[str] = None,
-                    telemetry: str = "summary") -> list:
-    """Run a grid of statically-decided compiled traces (BestFit
-    placement); returns one §6.4 summary dict per trace."""
-    return run_grid_engine(engines.StaticEngine(), traces,
-                           lambda chunk: (), cluster=cluster,
-                           max_active=max_active,
-                           swap_slowdown=swap_slowdown, threads=threads,
-                           devices=devices, substep_impl=substep_impl,
-                           telemetry=telemetry)
-
-
-def run_trace_arrays(trace: TraceArrays, cluster: Optional[Cluster] = None,
-                     max_active: Optional[int] = None,
-                     swap_slowdown: float = 0.5,
-                     substep_impl: Optional[str] = None,
-                     telemetry: str = "summary") -> dict:
-    """Run one compiled trace through the (unbatched) static program."""
-    return run_trace_engine(engines.StaticEngine(), trace, (),
-                            cluster=cluster, max_active=max_active,
-                            swap_slowdown=swap_slowdown,
-                            substep_impl=substep_impl,
-                            telemetry=telemetry)
-
-
-def run_grid_arrays_learned(traces: Sequence[DualTraceArrays], mab_state,
-                            daso_theta=None, daso_cfg=None,
-                            cluster: Optional[Cluster] = None,
-                            max_active: Optional[int] = None,
-                            swap_slowdown: float = 0.5,
-                            threads: Optional[int] = None,
-                            devices=None,
-                            substep_impl: Optional[str] = None,
-                            telemetry: str = "summary",
-                            mab_hp=MAB_HP) -> list:
-    """Run a grid of dual traces under the in-kernel deploy-mode learned
-    policy — online UCB MAB split decisions, plus the array-form DASO
-    placer when ``daso_cfg``/``daso_theta`` are given (BestFit
-    otherwise; ``daso_cfg.decision_aware=False`` is the GOBI ablation).
-
-    Every grid cell carries its own copy of ``mab_state`` through the
-    interval loop (the pretrained state is the shared starting point, the
-    online feedback trajectories diverge per cell).  Returns one summary
-    dict per trace extended with the final MAB scalars
-    (``mab_eps``/``mab_rho``/``mab_t``)."""
-    _check_variants(traces, engines.MAB_VARIANTS)
-    cluster = cluster or make_cluster()
-    theta = _check_learned_args(daso_cfg, daso_theta, cluster.n)
-    engine = engines.MABDeployEngine(mab_hp=tuple(mab_hp),
-                                     daso_cfg=daso_cfg)
-    return run_grid_engine(engine, traces,
-                           lambda chunk: _deploy_es(mab_state, theta),
-                           cluster=cluster, max_active=max_active,
-                           swap_slowdown=swap_slowdown, threads=threads,
-                           devices=devices, substep_impl=substep_impl,
-                           telemetry=telemetry)
-
-
-def run_trace_arrays_learned(trace: DualTraceArrays, mab_state,
-                             daso_theta=None, daso_cfg=None,
-                             cluster: Optional[Cluster] = None,
-                             max_active: Optional[int] = None,
-                             swap_slowdown: float = 0.5,
-                             substep_impl: Optional[str] = None,
-                             telemetry: str = "summary",
-                             mab_hp=MAB_HP) -> dict:
-    """Run one dual trace through the (unbatched) deploy-mode program."""
-    _check_variants([trace], engines.MAB_VARIANTS)
-    cluster = cluster or make_cluster()
-    theta = _check_learned_args(daso_cfg, daso_theta, cluster.n)
-    engine = engines.MABDeployEngine(mab_hp=tuple(mab_hp),
-                                     daso_cfg=daso_cfg)
-    return run_trace_engine(engine, trace, _deploy_es(mab_state, theta),
-                            cluster=cluster, max_active=max_active,
-                            swap_slowdown=swap_slowdown,
-                            substep_impl=substep_impl,
-                            telemetry=telemetry)
-
-
-def run_grid_arrays_trained(traces: Sequence[DualTraceArrays], mab_state,
-                            daso_theta=None, daso_cfg=None,
-                            daso_opt_state=None,
-                            cluster: Optional[Cluster] = None,
-                            max_active: Optional[int] = None,
-                            swap_slowdown: float = 0.5,
-                            threads: Optional[int] = None,
-                            devices=None,
-                            substep_impl: Optional[str] = None,
-                            telemetry: str = "summary",
-                            mab_hp=MAB_HP, train_hp=TRAIN_HP) -> list:
-    """Run a grid of dual traces with the FULL training loop in-kernel:
-    ε-greedy MAB decisions + Algorithm-1 feedback, and (when
-    ``daso_cfg``/``daso_theta`` are given) online DASO finetuning —
-    replay-window appends and ``train_epoch_weighted`` steps inside the
-    jitted interval program.
-
-    Every grid cell carries its own copies of ``mab_state`` and the
-    DASO trainer (theta, opt_state, replay window); per-cell decision
-    randomness comes from ``trace_train_key(trace.seed)``.  Summaries
-    gain the final MAB scalars and (DASO runs) the finetuned ``theta``
-    pytree under ``"daso_theta"``."""
-    _check_variants(traces, engines.MAB_VARIANTS)
-    cluster = cluster or make_cluster()
-    theta = _check_learned_args(daso_cfg, daso_theta, cluster.n)
-    engine = engines.MABTrainEngine(mab_hp=tuple(mab_hp),
-                                    train_hp=tuple(train_hp),
-                                    daso_cfg=daso_cfg)
-
-    def es_builder(chunk):
-        keys = jnp.stack([trace_train_key(t.seed) for t in chunk])
-        return _train_es(daso_cfg, mab_state, theta, daso_opt_state, keys)
-
-    return run_grid_engine(engine, traces, es_builder, cluster=cluster,
-                           max_active=max_active,
-                           swap_slowdown=swap_slowdown, threads=threads,
-                           devices=devices, substep_impl=substep_impl,
-                           telemetry=telemetry)
-
-
-def run_trace_arrays_trained(trace: DualTraceArrays, mab_state,
-                             daso_theta=None, daso_cfg=None,
-                             daso_opt_state=None,
-                             cluster: Optional[Cluster] = None,
-                             max_active: Optional[int] = None,
-                             swap_slowdown: float = 0.5,
-                             substep_impl: Optional[str] = None,
-                             telemetry: str = "summary",
-                             mab_hp=MAB_HP, train_hp=TRAIN_HP) -> dict:
-    """Run one dual trace through the (unbatched) in-kernel training
-    program."""
-    _check_variants([trace], engines.MAB_VARIANTS)
-    cluster = cluster or make_cluster()
-    theta = _check_learned_args(daso_cfg, daso_theta, cluster.n)
-    engine = engines.MABTrainEngine(mab_hp=tuple(mab_hp),
-                                    train_hp=tuple(train_hp),
-                                    daso_cfg=daso_cfg)
-    es0 = _train_es(daso_cfg, mab_state, theta, daso_opt_state,
-                    trace_train_key(trace.seed))
-    return run_trace_engine(engine, trace, es0, cluster=cluster,
-                            max_active=max_active,
-                            swap_slowdown=swap_slowdown,
-                            substep_impl=substep_impl,
-                            telemetry=telemetry)
-
-
-#: the three static-decider baseline arms of Table 4 and the
-#: ``engines.MAB_VARIANTS`` index each realizes every row (−1 = uniform
-#: random per row, the ``random+daso`` arm)
-STATIC_DASO_ARMS = {"layer+gobi": 0, "semantic+gobi": 1, "random+daso": -1}
-
-
-def _static_daso_engine(policy, daso_cfg, daso_theta, cluster):
-    """Resolve one of the ``STATIC_DASO_ARMS`` into its engine + frozen
-    theta.  The GOBI arms flip ``decision_aware=False`` here (the
-    surrogate input's decision one-hot slice is zeroed — the host
-    ``SurrogatePlacer(decision_aware=False)`` ablation); ``random+daso``
-    keeps the caller's decision-aware cfg."""
-    if policy not in STATIC_DASO_ARMS:
-        raise ValueError(f"policy {policy!r} is not one of "
-                         f"{sorted(STATIC_DASO_ARMS)}")
-    if daso_cfg is None:
-        raise ValueError(f"{policy!r} needs a pretrained DASO surrogate "
-                         "(daso_cfg/daso_theta; see "
-                         "launch.experiments.pretrain)")
-    arm = STATIC_DASO_ARMS[policy]
-    if arm >= 0:
-        daso_cfg = daso_cfg._replace(decision_aware=False)
-    theta = _check_learned_args(daso_cfg, daso_theta, cluster.n)
-    engine = engines.StaticDeciderDASOEngine(arm=arm, daso_cfg=daso_cfg,
-                                             name=policy)
-    return engine, theta, arm
-
-
-def run_grid_arrays_static_daso(traces: Sequence[DualTraceArrays],
-                                policy: str, daso_theta=None,
-                                daso_cfg=None,
-                                cluster: Optional[Cluster] = None,
-                                max_active: Optional[int] = None,
-                                swap_slowdown: float = 0.5,
-                                threads: Optional[int] = None,
-                                devices=None,
-                                substep_impl: Optional[str] = None,
-                                telemetry: str = "summary") -> list:
-    """Run a grid of dual traces under one of the static-decider baseline
-    arms — ``layer+gobi`` / ``semantic+gobi`` (fixed split + decision-
-    blind surrogate placement) or ``random+daso`` (uniform-random split +
-    decision-aware surrogate placement).  Per-cell decision randomness
-    for the random arm comes from ``trace_train_key(trace.seed)``;
-    returns one §6.4 summary dict per trace."""
-    _check_variants(traces, engines.MAB_VARIANTS)
-    cluster = cluster or make_cluster()
-    engine, theta, arm = _static_daso_engine(policy, daso_cfg, daso_theta,
-                                             cluster)
-
-    def es_builder(chunk):
-        es = {"theta": theta}
-        if arm < 0:
-            es["key"] = jnp.stack([trace_train_key(t.seed) for t in chunk])
-        return es
-
-    return run_grid_engine(engine, traces, es_builder, cluster=cluster,
-                           max_active=max_active,
-                           swap_slowdown=swap_slowdown, threads=threads,
-                           devices=devices, substep_impl=substep_impl,
-                           telemetry=telemetry)
-
-
-def run_trace_arrays_static_daso(trace: DualTraceArrays, policy: str,
-                                 daso_theta=None, daso_cfg=None,
-                                 cluster: Optional[Cluster] = None,
-                                 max_active: Optional[int] = None,
-                                 swap_slowdown: float = 0.5,
-                                 substep_impl: Optional[str] = None,
-                                 telemetry: str = "summary") -> dict:
-    """Run one dual trace through the (unbatched) static-decider
-    baseline-arm program (see ``run_grid_arrays_static_daso``)."""
-    _check_variants([trace], engines.MAB_VARIANTS)
-    cluster = cluster or make_cluster()
-    engine, theta, arm = _static_daso_engine(policy, daso_cfg, daso_theta,
-                                             cluster)
-    es0 = {"theta": theta}
-    if arm < 0:
-        es0["key"] = trace_train_key(trace.seed)
-    return run_trace_engine(engine, trace, es0, cluster=cluster,
-                            max_active=max_active,
-                            swap_slowdown=swap_slowdown,
-                            substep_impl=substep_impl,
-                            telemetry=telemetry)
-
-
-def run_grid_arrays_gillis(traces: Sequence[DualTraceArrays],
-                           gillis_state=None,
-                           cluster: Optional[Cluster] = None,
-                           max_active: Optional[int] = None,
-                           swap_slowdown: float = 0.5,
-                           threads: Optional[int] = None,
-                           devices=None,
-                           substep_impl: Optional[str] = None,
-                           telemetry: str = "summary",
-                           gillis_hp=GILLIS_HP, num_apps: int = 3) -> list:
-    """Run a grid of LAYER/COMPRESSED dual traces under the in-kernel
-    Gillis baseline — contextual ε-greedy Q-learning with per-interval
-    ε-decay and per-leaving-task TD(0) updates, entirely in the carry.
-
-    Traces must be compiled with ``compile_trace_dual(variants=(LAYER,
-    COMPRESSED))``.  Every cell carries its own (Q, ε) copy from
-    ``gillis_state`` (fresh zeros/ε₀ when None); per-cell randomness
-    comes from ``trace_train_key(trace.seed)``.  Summaries gain
-    ``gillis_eps`` and the final Q-table under ``"gillis_q"``."""
-    _check_variants(traces, engines.GILLIS_VARIANTS)
-    engine = engines.GillisEngine(gillis_hp=tuple(gillis_hp))
-
-    def es_builder(chunk):
-        keys = jnp.stack([trace_train_key(t.seed) for t in chunk])
-        return _gillis_es(gillis_state, keys, num_apps, gillis_hp[0])
-
-    return run_grid_engine(engine, traces, es_builder, cluster=cluster,
-                           max_active=max_active,
-                           swap_slowdown=swap_slowdown, threads=threads,
-                           devices=devices, substep_impl=substep_impl,
-                           telemetry=telemetry)
-
-
-def run_trace_arrays_gillis(trace: DualTraceArrays, gillis_state=None,
-                            cluster: Optional[Cluster] = None,
-                            max_active: Optional[int] = None,
-                            swap_slowdown: float = 0.5,
-                            substep_impl: Optional[str] = None,
-                            telemetry: str = "summary",
-                            gillis_hp=GILLIS_HP, num_apps: int = 3) -> dict:
-    """Run one LAYER/COMPRESSED dual trace through the (unbatched)
-    in-kernel Gillis program."""
-    _check_variants([trace], engines.GILLIS_VARIANTS)
-    engine = engines.GillisEngine(gillis_hp=tuple(gillis_hp))
-    es0 = _gillis_es(gillis_state, trace_train_key(trace.seed), num_apps,
-                     gillis_hp[0])
-    return run_trace_engine(engine, trace, es0, cluster=cluster,
-                            max_active=max_active,
-                            swap_slowdown=swap_slowdown,
-                            substep_impl=substep_impl,
-                            telemetry=telemetry)

@@ -19,6 +19,10 @@ theta, optimizer moments, replay window, PRNG key, Gillis Q-table…).
 
 Protocol (duck-typed; every engine below implements it):
 
+  * ``variants`` — a class constant, not a field (so it stays out of the
+    hash and the cache key): the decision codes of the dual trace's V
+    axis the engine decides between, or ``None`` for a static
+    (pre-realized) trace; the driver checks every trace against it;
   * ``batch_axes()``  — vmap ``in_axes`` prefix for ``es`` under the
     batched grid runner (``0`` for per-cell leaves like PRNG keys,
     ``None`` for shared starting state);
@@ -44,9 +48,10 @@ Protocol (duck-typed; every engine below implements it):
     END-of-interval ``es`` (after feedback), or ``None`` when the
     engine has no columns.
 
-Adding a policy = adding one engine here (plus its host parity oracle
-in ``reference.py``); the driver, runner cache, chunk dispatcher and
-summary path are shared and untouched.
+Adding a policy = adding one engine here (plus its row in
+``policies.resolve`` and its host parity oracle in ``reference.py``);
+the driver, runner cache, chunk dispatcher and summary path are shared
+and untouched.
 """
 from __future__ import annotations
 
@@ -73,6 +78,48 @@ VAR_KEYS = ("vacc", "vchain", "vnfrag", "vinstr", "vram", "vout")
 #: the dual-trace variant codes each engine family decides between
 MAB_VARIANTS = (LAYER, SEMANTIC)
 GILLIS_VARIANTS = (LAYER, COMPRESSED)
+
+#: MAB hyperparameters of the in-kernel learned policies, matching the
+#: host ``MABDecider`` defaults: (ucb_c, phi, gamma, k)
+MAB_HP = (0.5, 0.3, 0.3, 0.1)
+
+#: DASO finetuning hyperparameters, matching the host ``SurrogatePlacer``
+#: defaults: (alpha, beta, train_steps, place_min, train_min) — the last
+#: two are the cold-start gates (ascend the surrogate only after
+#: ``place_min`` replay records, train only after ``train_min``);
+#: lowering them lets short test/benchmark horizons exercise the
+#: finetuned-ascent path the defaults reserve for long traces
+TRAIN_HP = (0.5, 0.5, 4, 32, 8)
+
+#: Gillis baseline hyperparameters, matching the host ``GillisDecider``
+#: defaults: (eps0, lr, decay)
+GILLIS_HP = (0.5, 0.3, 0.995)
+
+
+def trace_train_key(seed: int):
+    """The per-trace decision PRNG key of the in-kernel training, Gillis
+    and ``random+daso`` loops — shared with the host parity oracles in
+    ``reference`` so both backends draw identical ε-greedy bits."""
+    return jax.random.PRNGKey(seed)
+
+
+def gillis_layer_ref(num_apps: int = 3):
+    """The (num_apps,) unloaded layer-chain reference table the Gillis
+    context bucket divides deadlines by (``mab.gillis_bucket``) — built
+    once here so the kernel engine and the host parity oracle consume
+    the identical float64 values."""
+    from repro.env.workload import layer_ref_response_s
+    return np.array([layer_ref_response_s(a) for a in range(num_apps)],
+                    np.float64)
+
+
+def gillis_init_state(num_apps: int = 3, eps0: float = GILLIS_HP[0]):
+    """Fresh host-side Gillis carry pieces (Q-table + ε) — NumPy float64
+    so the driver's ``enable_x64`` asarray keeps full precision.  Pass a
+    previous run's ``{"Q": gillis_q, "eps": gillis_eps}`` instead to
+    continue a pretrained baseline."""
+    return {"Q": np.zeros((num_apps, 2, 2), np.float64),
+            "eps": np.float64(eps0)}
 
 
 def _interval_rows(trace, t):
@@ -109,6 +156,7 @@ class StaticEngine:
     is a pure slice of the compiled arrays."""
 
     name: str = "static"
+    variants = None
 
     def batch_axes(self):
         return None
@@ -153,6 +201,7 @@ class StaticDeciderDASOEngine:
     arm: int
     daso_cfg: DASOConfig
     name: str = "static-daso"
+    variants = MAB_VARIANTS
 
     def batch_axes(self):
         if self.arm < 0:
@@ -210,6 +259,7 @@ class MABDeployEngine:
     mab_hp: Tuple[float, float, float, float]
     daso_cfg: Optional[DASOConfig] = None
     name: str = "mab-deploy"
+    variants = MAB_VARIANTS
 
     def batch_axes(self):
         return None
@@ -262,6 +312,7 @@ class MABTrainEngine:
     train_hp: Tuple[float, float, int, int, int]
     daso_cfg: Optional[DASOConfig] = None
     name: str = "mab-train"
+    variants = MAB_VARIANTS
 
     def batch_axes(self):
         return {"mab": None, "theta": None, "opt": None, "win": None,
@@ -342,12 +393,14 @@ class GillisEngine:
     multiplicative ε-decay per interval and sequential per-leaving-task
     TD(0) updates — the array form of ``splitplace.GillisDecider``
     against dual traces compiled with ``variants=(LAYER, COMPRESSED)``.
-    ``gillis_hp = (eps0, lr, decay)``; eps0 seeds ``es["eps"]`` (the
-    driver owns state construction).  Placement is plain BestFit.
+    ``gillis_hp = (eps0, lr, decay)``; eps0 seeds ``es["eps"]``
+    (``policies.resolve`` owns state construction).  Placement is plain
+    BestFit.
     ``es = {"Q", "eps", "key", "layer_ref"}``."""
 
     gillis_hp: Tuple[float, float, float]
     name: str = "gillis"
+    variants = GILLIS_VARIANTS
 
     def batch_axes(self):
         return {"Q": None, "eps": None, "key": 0, "layer_ref": None}

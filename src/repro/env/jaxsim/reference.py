@@ -8,7 +8,7 @@ Mobility needs no scripting: ``EdgeSim`` seeds its own ``MobilityModel``
 with ``seed + 1`` exactly as ``compile_trace`` did, so the bandwidth
 multipliers line up by construction.
 
-``tests/test_jaxsim_parity.py`` pins ``run_trace_arrays`` ≈ this replay
+``tests/test_jaxsim_parity.py`` pins ``run_trace_engine`` ≈ this replay
 (allclose on summary metrics) — the relaxed successor of the SoA↔legacy
 bit-exactness contract.
 """
@@ -87,7 +87,7 @@ def replay_trace_edgesim(trace: TraceArrays,
                          cluster: Optional[Cluster] = None,
                          placer=None, telemetry: str = "summary") -> dict:
     """Drive ``EdgeSim`` + BestFit through the compiled trace; returns the
-    same summary schema as ``driver.run_trace_arrays``."""
+    same summary schema as a static policy's ``driver.run_trace_engine``."""
     from repro.core.splitplace import BestFitPlacer
     tel = telemetry == "interval"
     sim = EdgeSim(cluster=cluster, lam=trace.lam, seed=trace.seed,
@@ -220,7 +220,7 @@ def replay_trace_edgesim_trained(trace, mab_state, daso_theta=None,
     and (when ``daso_cfg`` is given) online DASO finetuning: per-interval
     (packed placement features, O^P) replay-window appends and
     ``train_epoch_weighted`` steps through the identical shared pure
-    functions.  The parity oracle for ``driver.run_*_arrays_trained``;
+    functions.  The parity oracle for ``resolve(..., mode="train")``;
     returns the same summary schema including the final MAB scalars and
     (DASO runs) the finetuned ``theta`` under ``"daso_theta"``."""
     import jax
@@ -229,7 +229,7 @@ def replay_trace_edgesim_trained(trace, mab_state, daso_theta=None,
     from repro.core import daso as daso_mod
     from repro.core import mab as mab_mod
     from repro.core.splitplace import BestFitPlacer
-    from repro.env.jaxsim.driver import MAB_HP, TRAIN_HP, trace_train_key
+    from repro.env.jaxsim.engines import MAB_HP, TRAIN_HP, trace_train_key
     from repro.optim.optimizers import adamw_init
 
     _, phi, gamma, k_rbed = mab_hp or MAB_HP
@@ -358,15 +358,15 @@ def replay_trace_edgesim_learned(trace, mab_state, daso_theta=None,
                                  telemetry: str = "summary") -> dict:
     """Drive ``EdgeSim`` through a dual compiled trace under the learned
     policy (online UCB MAB decider; DASO placer when ``daso_cfg`` is
-    given, BestFit otherwise) — the parity reference for
-    ``driver.run_trace_arrays_learned``.  Returns the same summary
+    given, BestFit otherwise) — the parity reference for the deploy-mode
+    MAB engines of ``policies.resolve``.  Returns the same summary
     schema, including the final MAB scalars."""
     import jax
     import jax.numpy as jnp
 
     from repro.core import mab as mab_mod
     from repro.core.splitplace import BestFitPlacer
-    from repro.env.jaxsim.driver import MAB_HP
+    from repro.env.jaxsim.engines import MAB_HP
 
     ucb_c, phi, gamma, k_rbed = mab_hp or MAB_HP
     tel = telemetry == "interval"
@@ -440,8 +440,8 @@ def replay_trace_edgesim_static_daso(trace, policy: str, daso_theta=None,
     ``random+daso`` uniform-random splits (the kernel engine's per-row
     fold-in bitstream, so both backends realize identical decisions)
     with decision-aware placement.  The parity oracle for
-    ``driver.run_*_arrays_static_daso``; returns the plain §6.4 summary
-    schema.
+    ``policies.resolve``'s ``STATIC_DASO_ARMS``; returns the plain §6.4
+    summary schema.
 
     Note the random arm pins the *in-kernel* decider (JAX PRNG), not the
     object-loop ``splitplace.RandomDecider`` (NumPy ``RandomState``) —
@@ -450,7 +450,8 @@ def replay_trace_edgesim_static_daso(trace, policy: str, daso_theta=None,
     import jax.numpy as jnp
 
     from repro.core.splitplace import BestFitPlacer
-    from repro.env.jaxsim.driver import STATIC_DASO_ARMS, trace_train_key
+    from repro.env.jaxsim.engines import trace_train_key
+    from repro.env.jaxsim.policies import STATIC_DASO_ARMS
 
     arm = STATIC_DASO_ARMS[policy]
     if arm >= 0:
@@ -497,7 +498,7 @@ def replay_trace_edgesim_gillis(trace, gillis_state=None,
     Q-learning decisions from the shared fold-in key choreography,
     per-interval ε-decay, and sequential per-leaving-task TD(0) updates
     through the identical shared pure functions (``mab.gillis_*``).  The
-    parity oracle for ``driver.run_*_arrays_gillis``; returns the same
+    parity oracle for ``resolve("gillis")``; returns the same
     summary schema including the final ``gillis_eps`` scalar and
     ``gillis_q`` table.
 
@@ -509,8 +510,8 @@ def replay_trace_edgesim_gillis(trace, gillis_state=None,
 
     from repro.core import mab as mab_mod
     from repro.core.splitplace import BestFitPlacer
-    from repro.env.jaxsim.driver import (GILLIS_HP, gillis_layer_ref,
-                                         trace_train_key)
+    from repro.env.jaxsim.engines import (GILLIS_HP, gillis_layer_ref,
+                                          trace_train_key)
     from repro.env.workload import LAYER
 
     eps0, lr, decay = gillis_hp or GILLIS_HP

@@ -57,7 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.env.cluster import Cluster, make_cluster
-from repro.env.jaxsim import driver, engines, kernels
+from repro.env.jaxsim import driver, kernels, policies
 from repro.env.jaxsim.arrays import (ClusterArrays, chunk_tapes,
                                      default_capacity)
 from repro.env.metrics import TELEMETRY_COLS, series_percentiles
@@ -537,46 +537,21 @@ def make_stream_policy(policy: str, *, cluster: Optional[Cluster] = None,
                        seed: int = 0, mab_state=None, daso_theta=None,
                        daso_cfg=None, gillis_state=None, num_apps: int = 3):
     """Resolve a policy name into ``(engine, es0, feeder_kwargs)`` for
-    the serving loop — the streaming analogue of the
-    ``run_*_arrays*`` wrapper layer.
+    the serving loop, in deploy mode, through ``policies.resolve``.
 
-    Static BestFit policies (``policies.STATIC_POLICIES``) get a host
-    decider feeder; the learned policies get dual-variant feeders with
-    their engine state: ``"mab"``/``"splitplace"`` continue a pretrained
-    ``mab_state`` (fresh ``mab.init_state`` when None — cold-start
-    serving), ``"splitplace"``/``"mab+gobi"`` add the frozen DASO
-    surrogate — ``daso_cfg``/``daso_theta`` are required for them, since
-    without a surrogate the engine would not be SplitPlace's placer —
-    and ``"gillis"`` carries its Q-table/ε."""
-    cluster = cluster or make_cluster()
-    from repro.env.jaxsim import policies as pol
-    if policy in pol.STATIC_POLICIES:
-        dec = pol.make_static_decider(policy, mab_state=mab_state)
-        return engines.StaticEngine(), (), {"decider": dec}
-    if policy in pol.MAB_LEARNED_POLICIES:
-        if mab_state is None:
-            from repro.core import mab
-            mab_state = mab.init_state(num_apps)
-        cfg = daso_cfg
-        if policy in pol.DASO_LEARNED_POLICIES and cfg is None:
-            raise ValueError(f"policy {policy!r} places with the DASO "
-                             "surrogate: pass daso_cfg/daso_theta (from "
-                             "launch.experiments.pretrain or "
-                             "seeded_surrogate)")
-        if policy == "mab+gobi":
-            cfg = cfg._replace(decision_aware=False)
-        if policy == "mab":
-            cfg = None
-        theta = driver._check_learned_args(cfg, daso_theta, cluster.n)
-        engine = engines.MABDeployEngine(mab_hp=tuple(driver.MAB_HP),
-                                         daso_cfg=cfg)
-        return engine, driver._deploy_es(mab_state, theta), \
-            {"variants": engines.MAB_VARIANTS}
-    if policy == "gillis":
-        engine = engines.GillisEngine(gillis_hp=tuple(driver.GILLIS_HP))
-        es0 = driver._gillis_es(gillis_state,
-                                driver.trace_train_key(seed), num_apps,
-                                driver.GILLIS_HP[0])
-        return engine, es0, {"variants": engines.GILLIS_VARIANTS}
-    raise ValueError(f"unknown streaming policy {policy!r} (want one of "
-                     f"{pol.STATIC_POLICIES + ('mab', 'splitplace', 'mab+gobi', 'gillis')})")
+    Static BestFit policies get a host decider feeder; every other
+    policy a dual-variant feeder with its engine state.  The MAB family
+    continues a pretrained ``mab_state`` (fresh ``mab.init_state`` when
+    None — cold-start serving); the surrogate placers (``"splitplace"``,
+    ``"mab+gobi"`` and the static-decider arms) need
+    ``daso_cfg``/``daso_theta``, since without a surrogate the engine
+    would not be their placer; ``"gillis"`` carries its Q-table/ε."""
+    if mab_state is None and policy in policies.MAB_LEARNED_POLICIES:
+        from repro.core import mab
+        mab_state = mab.init_state(num_apps)
+    r = policies.resolve(policy, cluster=cluster, mab_state=mab_state,
+                         daso_theta=daso_theta, daso_cfg=daso_cfg,
+                         gillis_state=gillis_state, num_apps=num_apps)
+    feeder_kw = {"decider": r.decider} if r.variants is None \
+        else {"variants": r.variants}
+    return r.engine, r.state(seed), feeder_kw

@@ -18,12 +18,9 @@ interval loop (``run_trace``, Algorithm 1) and a grid driver
     object-level reference for every policy) — and ``backend="jax"`` —
     the fixed-capacity jitted simulator (``repro.env.jaxsim``), where
     ``run_grid_batched`` runs a whole (seed × λ) grid as one compiled
-    vmapped call: static BestFit policies plus the in-kernel learned
-    engines ``"mab"`` / ``"splitplace"`` (online UCB/ε-greedy MAB,
-    Algorithm-1 feedback and the array-form DASO placer inside the
-    kernel, deploying — or in ``mode="train"`` finetuning — the states
-    ``pretrain`` produced), the decision-blind ``"mab+gobi"`` ablation,
-    and the ``"gillis"`` contextual Q-learning baseline.
+    vmapped call under any policy of ``jaxsim.resolve``'s table,
+    deploying — or in ``mode="train"`` finetuning — the states
+    ``pretrain`` produced.
 
 ``repro.core.splitplace.run_experiment`` and the Table 4 / sensitivity
 benchmarks are thin wrappers over these entry points.
@@ -40,10 +37,9 @@ from repro.core import splitplace as sp
 from repro.core.policies import Policy
 from repro.env.cluster import FLEET_SPEC, make_cluster
 from repro.env.metrics import TELEMETRY_COLS, MetricsAccumulator
+from repro.env.jaxsim.policies import (GILLIS_POLICIES, LEARNED_POLICIES,
+                                       MAB_LEARNED_POLICIES, spec)
 from repro.env.simulator import EdgeSim
-
-#: policies whose decider consumes a pretrained MAB state
-MAB_STATE_POLICIES = ("splitplace", "mab+gobi", "mab")
 
 
 class PretrainState(NamedTuple):
@@ -66,6 +62,34 @@ class PretrainState(NamedTuple):
     daso_opt_state: Optional[object] = None
 
 
+def _products(pretrain_state, **given):
+    """The pretraining products an entry runs with: each one passed in
+    wins over ``pretrain_state``'s field of the same name."""
+    if pretrain_state is None:
+        return given
+    return {k: getattr(pretrain_state, k) if v is None else v
+            for k, v in given.items()}
+
+
+def _jax_traces(policy, cells, trace_kw, **resolve_kw):
+    """The jitted backend's entries by name: resolve ``policy`` through
+    ``jaxsim.resolve`` (refusing a missing pretrained ``mab_state``), then
+    compile one trace per ``(lam, seed)`` cell — both split variants
+    when the engine decides in-kernel, else through the static decider.
+    Returns ``(resolved, traces)``."""
+    from repro.env import jaxsim
+    r = jaxsim.resolve(policy, **resolve_kw)
+    if r.needs_mab and resolve_kw.get("mab_state") is None:
+        raise ValueError(f"policy {policy!r} needs a pretrained "
+                         "mab_state (see pretrain())")
+    if r.variants is None:
+        return r, [jaxsim.compile_trace(r.decider, lam=lam, seed=seed,
+                                        **trace_kw) for lam, seed in cells]
+    return r, [jaxsim.compile_trace_dual(lam=lam, seed=seed,
+                                         variants=r.variants, **trace_kw)
+               for lam, seed in cells]
+
+
 def run_trace(policy_name: Optional[str] = None, n_intervals: int = 100,
               lam: float = 6.0, seed: int = 0, mab_state=None,
               train: bool = False, cluster=None, apps=None,
@@ -80,27 +104,20 @@ def run_trace(policy_name: Optional[str] = None, n_intervals: int = 100,
     Pass ``policy`` to continue a pre-trained policy object (used to
     pretrain the Gillis baseline's Q-learner, mirroring the MAB's
     pretraining phase).  ``backend="jax"`` compiles the workload and runs
-    the jitted fixed-capacity simulator — static BestFit policies, plus
-    the in-kernel learned engines: ``"mab"`` (online MAB + BestFit),
-    ``"splitplace"`` (online MAB + array-form DASO; needs
-    ``daso_theta``/``daso_cfg`` from ``pretrain``), ``"mab+gobi"``
-    (same surrogate machinery, decision-blind input) and ``"gillis"``
-    (contextual ε-greedy Q-learning, always online — ``mode`` is
-    ignored for it).  ``mode`` selects
-    the learned policies' in-kernel loop: ``"deploy"`` (UCB decisions,
+    the jitted fixed-capacity simulator under any policy name of the
+    table (``jaxsim.resolve``: engine, starting state, trace variants),
+    with the ``pretrain()`` products it needs.  ``mode`` selects the
+    learned policies' in-kernel loop: ``"deploy"`` (UCB decisions,
     frozen surrogate) or ``"train"`` (ε-greedy decisions + in-kernel
     DASO finetuning; pass ``daso_opt_state`` to continue the pretrain
     optimizer trajectory).  On the host backend ``mode="train"`` is the
-    ε-greedy training flag (same as ``train=True``).  The static-decider
-    surrogate arms (``jaxsim.STATIC_DASO_ARMS``: ``"semantic+gobi"``,
-    ``"layer+gobi"``, ``"random+daso"``) also run in-kernel on
-    ``backend="jax"`` — pass ``daso_theta``/``daso_cfg`` from
-    ``pretrain()``.  ``substep_impl`` selects the jitted backend's
-    substep physics implementation (``"xla"``/``"pallas"``/``"ref"``;
-    None → env/default).  ``telemetry="interval"`` records the
-    per-interval telemetry series on either backend and adds response/
-    wait percentiles to the summary (exact on the host; binned with a
-    reported error bound on the jitted backend)."""
+    ε-greedy training flag (same as ``train=True``).  ``substep_impl``
+    selects the jitted backend's substep physics implementation
+    (``"xla"``/``"pallas"``/``"ref"``; None → env/default).
+    ``telemetry="interval"`` records the per-interval telemetry series
+    on either backend and adds response/wait percentiles to the summary
+    (exact on the host; binned with a reported error bound on the
+    jitted backend)."""
     if mode not in ("deploy", "train"):
         raise ValueError(f"unknown mode {mode!r}")
     if backend == "jax":
@@ -109,78 +126,15 @@ def run_trace(policy_name: Optional[str] = None, n_intervals: int = 100,
                              "(no policy objects; ε-greedy training is "
                              "mode='train' on the learned policies)")
         from repro.env import jaxsim
-        if policy_name == "gillis":
-            # the Gillis baseline's ε-greedy Q-loop is inherently online
-            # (mode is moot); its dual traces realize layer vs compressed
-            from repro.env.workload import COMPRESSED, LAYER
-            tr = jaxsim.compile_trace_dual(
-                lam=lam, seed=seed, n_intervals=n_intervals,
-                interval_s=interval_s, substeps=substeps, apps=apps,
-                cluster=cluster, variants=(LAYER, COMPRESSED))
-            out = jaxsim.run_trace_arrays_gillis(tr, cluster=cluster,
-                                                 substep_impl=substep_impl,
-                                                 telemetry=telemetry)
-            out["policy"] = policy_name
-            return out
-        if policy_name in jaxsim.LEARNED_POLICIES:
-            if mab_state is None:
-                raise ValueError(f"policy {policy_name!r} needs a "
-                                 "pretrained mab_state (see pretrain())")
-            if policy_name in jaxsim.DASO_LEARNED_POLICIES and \
-                    (daso_theta is None or daso_cfg is None):
-                raise ValueError(f"policy {policy_name!r} needs daso_theta/"
-                                 "daso_cfg (see pretrain())")
-            tr = jaxsim.compile_trace_dual(
-                lam=lam, seed=seed, n_intervals=n_intervals,
-                interval_s=interval_s, substeps=substeps, apps=apps,
-                cluster=cluster)
-            use_daso = policy_name in jaxsim.DASO_LEARNED_POLICIES
-            # mab+gobi = identical surrogate machinery, decision one-hot
-            # masked out of the surrogate input (the paper's
-            # decision-blind GOBI ablation)
-            cfg = daso_cfg._replace(decision_aware=False) \
-                if policy_name == "mab+gobi" else daso_cfg
-            if mode == "train":
-                out = jaxsim.run_trace_arrays_trained(
-                    tr, mab_state, cluster=cluster,
-                    daso_theta=daso_theta if use_daso else None,
-                    daso_cfg=cfg if use_daso else None,
-                    daso_opt_state=daso_opt_state if use_daso else None,
-                    substep_impl=substep_impl, telemetry=telemetry)
-            else:
-                out = jaxsim.run_trace_arrays_learned(
-                    tr, mab_state, cluster=cluster,
-                    daso_theta=daso_theta if use_daso else None,
-                    daso_cfg=cfg if use_daso else None,
-                    substep_impl=substep_impl, telemetry=telemetry)
-            out["policy"] = policy_name
-            return out
-        if mode == "train":
-            raise ValueError(f"policy {policy_name!r} is static — "
-                             "mode='train' needs a learned policy "
-                             f"({jaxsim.LEARNED_POLICIES})")
-        if policy_name in jaxsim.STATIC_DASO_ARMS:
-            # static decider + frozen surrogate placer, fully in-kernel
-            if daso_theta is None or daso_cfg is None:
-                raise ValueError(f"policy {policy_name!r} needs daso_theta/"
-                                 "daso_cfg (see pretrain())")
-            tr = jaxsim.compile_trace_dual(
-                lam=lam, seed=seed, n_intervals=n_intervals,
-                interval_s=interval_s, substeps=substeps, apps=apps,
-                cluster=cluster)
-            out = jaxsim.run_trace_arrays_static_daso(
-                tr, policy_name, daso_theta=daso_theta, daso_cfg=daso_cfg,
-                cluster=cluster, substep_impl=substep_impl,
-                telemetry=telemetry)
-            out["policy"] = policy_name
-            return out
-        dec = jaxsim.make_static_decider(policy_name, mab_state=mab_state,
-                                         seed=seed)
-        tr = jaxsim.compile_trace(dec, lam=lam, seed=seed,
-                                  n_intervals=n_intervals,
-                                  interval_s=interval_s, substeps=substeps,
-                                  apps=apps, cluster=cluster)
-        out = jaxsim.run_trace_arrays(tr, cluster=cluster,
+        r, (tr,) = _jax_traces(
+            policy_name, [(lam, seed)],
+            dict(n_intervals=n_intervals, interval_s=interval_s,
+                 substeps=substeps, apps=apps, cluster=cluster),
+            mode=mode, cluster=cluster, mab_state=mab_state,
+            daso_theta=daso_theta, daso_cfg=daso_cfg,
+            daso_opt_state=daso_opt_state)
+        out = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                      cluster=cluster,
                                       substep_impl=substep_impl,
                                       telemetry=telemetry)
         out["policy"] = policy_name
@@ -239,7 +193,7 @@ def pretrain(n_intervals: int, lam: float = 6.0, seed: int = 7,
     ``mab_state`` and DASO ``theta`` then flow into either backend —
     host deciders/placers or the jitted in-kernel learned policies."""
     out = PretrainState()
-    if any(p in MAB_STATE_POLICIES for p in policies):
+    if any(p in MAB_LEARNED_POLICIES for p in policies):
         r = run_trace("splitplace", n_intervals=n_intervals, lam=lam,
                       seed=seed, train=True, substeps=substeps,
                       interval_s=interval_s)
@@ -247,7 +201,7 @@ def pretrain(n_intervals: int, lam: float = 6.0, seed: int = 7,
         out = out._replace(mab_state=r["mab_state"],
                            daso_theta=placer.theta, daso_cfg=placer.cfg,
                            daso_opt_state=placer.opt_state)
-    if "gillis" in policies:
+    if any(p in GILLIS_POLICIES for p in policies):
         r = run_trace("gillis", n_intervals=n_intervals, lam=lam, seed=seed,
                       substeps=substeps, interval_s=interval_s)
         out = out._replace(gillis_policy=r["policy_obj"])
@@ -280,41 +234,20 @@ def run_grid_batched(policy: str = "mc", seeds: Sequence[int] = (0,),
     call on the jitted backend; one record per trace, in
     ``itertools.product(lams, seeds)`` order (matching ``run_grid``).
 
-    Besides the static BestFit policies, every in-kernel learned policy
-    (``jaxsim.LEARNED_POLICIES``) is accepted — each is an engine over
-    the unified interval program, carrying its state through the jitted
-    carry with online decisions and per-interval feedback inside the
-    kernel, one state copy per grid cell:
-
-      * ``"mab"`` / ``"splitplace"`` — the pretrained ``MABState``
-        (plus, for splitplace, the DASO surrogate theta);
-      * ``"mab+gobi"`` — the decision-blind GOBI ablation: identical
-        surrogate machinery with the decision one-hot masked out of the
-        surrogate input (Table 4's M+G row);
-      * ``"gillis"`` — the Gillis baseline's contextual ε-greedy
-        Q-learner (layer vs compressed) — no pretraining products
-        needed; pass ``gillis_state={"Q":..., "eps":...}`` to continue
-        one (records keep only scalar metrics, so obtain the Q-table to
-        continue from by calling ``jaxsim.run_grid_arrays_gillis``
-        directly — its summaries carry ``"gillis_q"``).  Its Q-loop is
-        inherently online, so ``mode`` is ignored.
-
+    Every policy name of the table (``jaxsim.resolve``) is accepted:
+    the static BestFit policies, the in-kernel learned engines (one
+    carried state copy per grid cell, online decisions and per-interval
+    feedback inside the kernel), and the static-decider surrogate arms.
     ``mode="train"`` switches the MAB policies to the full §6.3
-    in-kernel training loop: ε-greedy decisions (eq. 6) and, for the
-    surrogate placers, online DASO finetuning (replay-window appends +
-    ``train_epoch_weighted`` steps in the carry).  ``mab_hp`` /
-    ``train_hp`` override the driver defaults (the α×λ sensitivity
-    sweep drives eq. 10's α/β through ``train_hp``).  Pass the
-    pretraining products either as ``pretrain_state`` (the
-    ``pretrain()`` result) or as the individual ``mab_state``/
-    ``daso_theta``/``daso_cfg``/``daso_opt_state`` fields.
-
-    The static-decider surrogate arms (``jaxsim.STATIC_DASO_ARMS``:
-    ``"semantic+gobi"``, ``"layer+gobi"``, ``"random+daso"``) run as one
-    dual-trace engine — a fixed (or fold-in-random) split decision with
-    the frozen DASO surrogate placer in-kernel; they need
-    ``daso_theta``/``daso_cfg`` like ``"splitplace"`` but no
-    ``mab_state``.
+    in-kernel training loop; ``mab_hp`` / ``train_hp`` override the
+    engine defaults (the α×λ sensitivity sweep drives eq. 10's α/β
+    through ``train_hp``).  Pass the pretraining products either as
+    ``pretrain_state`` (the ``pretrain()`` result) or as the individual
+    ``mab_state``/``daso_theta``/``daso_cfg``/``daso_opt_state``
+    fields; ``gillis_state={"Q":..., "eps":...}`` continues a Gillis
+    Q-table (records keep only scalar metrics: the final ``"gillis_q"``
+    comes from running the resolved engine with
+    ``jaxsim.run_grid_engine``).
 
     ``devices`` routes the grid through the shard_map dispatcher (1-D
     ``"grid"`` device mesh; ``"auto"`` = every visible device) instead of
@@ -324,8 +257,9 @@ def run_grid_batched(policy: str = "mc", seeds: Sequence[int] = (0,),
 
     ``telemetry="interval"`` threads the driver's per-interval telemetry
     knob through every arm; records keep only the scalar percentile
-    fields (``_record`` drops the non-scalar series payload) — call the
-    ``jaxsim.run_grid_arrays*`` functions directly for the full series.
+    fields (``_record`` drops the non-scalar series payload) — run the
+    ``jaxsim.resolve`` engine with ``jaxsim.run_grid_engine`` for the
+    full series.
 
     Workload compilation is host-side and cheap; the interval dynamics
     (decisions + placement + substep physics + metric accumulators) run
@@ -334,97 +268,19 @@ def run_grid_batched(policy: str = "mc", seeds: Sequence[int] = (0,),
     contract — records report ``dropped_tasks`` (0 unless ``max_active``
     was forced too small)."""
     from repro.env import jaxsim
-    if mode not in ("deploy", "train"):
-        raise ValueError(f"unknown mode {mode!r}")
-    if pretrain_state is not None:
-        mab_state = mab_state if mab_state is not None \
-            else pretrain_state.mab_state
-        daso_theta = daso_theta if daso_theta is not None \
-            else pretrain_state.daso_theta
-        daso_cfg = daso_cfg if daso_cfg is not None \
-            else pretrain_state.daso_cfg
-        daso_opt_state = daso_opt_state if daso_opt_state is not None \
-            else pretrain_state.daso_opt_state
     cells = list(itertools.product(lams, seeds))
-    if policy == "gillis":
-        from repro.env.workload import COMPRESSED, LAYER
-        traces = [jaxsim.compile_trace_dual(
-            lam=lam, seed=seed + seed_offset, n_intervals=n_intervals,
-            interval_s=interval_s, substeps=substeps, apps=apps,
-            cluster=cluster, variants=(LAYER, COMPRESSED))
-            for lam, seed in cells]
-        kw = {} if gillis_state is None else {"gillis_state": gillis_state}
-        outs = jaxsim.run_grid_arrays_gillis(
-            traces, cluster=cluster, max_active=max_active,
-            threads=threads, devices=devices, substep_impl=substep_impl,
-            telemetry=telemetry, **kw)
-        return [_record(policy, seed, lam, out)
-                for (lam, seed), out in zip(cells, outs)]
-    if policy in jaxsim.STATIC_DASO_ARMS:
-        if daso_theta is None or daso_cfg is None:
-            raise ValueError(f"policy {policy!r} needs daso_theta/"
-                             "daso_cfg (see pretrain())")
-        traces = [jaxsim.compile_trace_dual(
-            lam=lam, seed=seed + seed_offset, n_intervals=n_intervals,
-            interval_s=interval_s, substeps=substeps, apps=apps,
-            cluster=cluster) for lam, seed in cells]
-        outs = jaxsim.run_grid_arrays_static_daso(
-            traces, policy, daso_theta=daso_theta, daso_cfg=daso_cfg,
-            cluster=cluster, max_active=max_active, threads=threads,
-            devices=devices, substep_impl=substep_impl,
-            telemetry=telemetry)
-        return [_record(policy, seed, lam, out)
-                for (lam, seed), out in zip(cells, outs)]
-    if policy in jaxsim.LEARNED_POLICIES:
-        if mab_state is None:
-            raise ValueError(f"policy {policy!r} needs a pretrained "
-                             "mab_state (see pretrain())")
-        if policy in jaxsim.DASO_LEARNED_POLICIES and \
-                (daso_theta is None or daso_cfg is None):
-            raise ValueError(f"policy {policy!r} needs daso_theta/"
-                             "daso_cfg (see pretrain())")
-        traces = [jaxsim.compile_trace_dual(
-            lam=lam, seed=seed + seed_offset, n_intervals=n_intervals,
-            interval_s=interval_s, substeps=substeps, apps=apps,
-            cluster=cluster) for lam, seed in cells]
-        use_daso = policy in jaxsim.DASO_LEARNED_POLICIES
-        cfg = daso_cfg._replace(decision_aware=False) \
-            if policy == "mab+gobi" else daso_cfg
-        hp_kw = {} if mab_hp is None else {"mab_hp": tuple(mab_hp)}
-        if mode == "train":
-            if train_hp is not None:
-                hp_kw["train_hp"] = tuple(train_hp)
-            outs = jaxsim.run_grid_arrays_trained(
-                traces, mab_state, cluster=cluster, max_active=max_active,
-                threads=threads, devices=devices,
-                substep_impl=substep_impl, telemetry=telemetry,
-                daso_theta=daso_theta if use_daso else None,
-                daso_cfg=cfg if use_daso else None,
-                daso_opt_state=daso_opt_state if use_daso else None,
-                **hp_kw)
-        else:
-            outs = jaxsim.run_grid_arrays_learned(
-                traces, mab_state, cluster=cluster, max_active=max_active,
-                threads=threads, devices=devices,
-                substep_impl=substep_impl, telemetry=telemetry,
-                daso_theta=daso_theta if use_daso else None,
-                daso_cfg=cfg if use_daso else None, **hp_kw)
-        return [_record(policy, seed, lam, out)
-                for (lam, seed), out in zip(cells, outs)]
-    if mode == "train":
-        raise ValueError(f"policy {policy!r} is static — mode='train' "
-                         f"needs a learned policy "
-                         f"({jaxsim.LEARNED_POLICIES})")
-    dec = jaxsim.make_static_decider(policy, mab_state=mab_state)
-    traces = [jaxsim.compile_trace(dec, lam=lam, seed=seed + seed_offset,
-                                   n_intervals=n_intervals,
-                                   interval_s=interval_s, substeps=substeps,
-                                   apps=apps, cluster=cluster)
-              for lam, seed in cells]
-    outs = jaxsim.run_grid_arrays(traces, cluster=cluster,
+    r, traces = _jax_traces(
+        policy, [(lam, seed + seed_offset) for lam, seed in cells],
+        dict(n_intervals=n_intervals, interval_s=interval_s,
+             substeps=substeps, apps=apps, cluster=cluster),
+        mode=mode, cluster=cluster, gillis_state=gillis_state,
+        mab_hp=mab_hp, train_hp=train_hp,
+        **_products(pretrain_state, mab_state=mab_state,
+                    daso_theta=daso_theta, daso_cfg=daso_cfg,
+                    daso_opt_state=daso_opt_state))
+    outs = jaxsim.run_grid_engine(r.engine, traces, r.state, cluster=cluster,
                                   max_active=max_active, threads=threads,
-                                  devices=devices,
-                                  substep_impl=substep_impl,
+                                  devices=devices, substep_impl=substep_impl,
                                   telemetry=telemetry)
     return [_record(policy, seed, lam, out)
             for (lam, seed), out in zip(cells, outs)]
@@ -459,27 +315,19 @@ def run_stream(policy: str = "mc", lam: float = 6.0, seed: int = 0,
     thread fills the next chunk's arrival tape while the device executes
     the current one.
 
-    Accepts the same policy names and pretraining products as
-    ``run_grid_batched`` (static BestFit policies run a host decider
-    feeder; ``"mab"``/``"splitplace"``/``"mab+gobi"``/``"gillis"``
-    serve their in-kernel engines, continuing ``pretrain_state`` when
-    given and cold-starting otherwise).  Returns the serving report —
+    Accepts every policy name of the table (``jaxsim.resolve``, deploy
+    mode) and the same pretraining products as ``run_grid_batched``;
+    the MAB policies cold-start when no ``mab_state`` is given.
+    Returns the serving report —
     admission ledger, ring occupancy, rolling-window QPS / percentile /
     violation metrics, and the cumulative §6.4 summary — annotated with
     the grid coordinates."""
     from repro.env.jaxsim import stream
     cluster = cluster or make_cluster()
-    if pretrain_state is not None:
-        mab_state = mab_state if mab_state is not None \
-            else pretrain_state.mab_state
-        daso_theta = daso_theta if daso_theta is not None \
-            else pretrain_state.daso_theta
-        daso_cfg = daso_cfg if daso_cfg is not None \
-            else pretrain_state.daso_cfg
     engine, es0, feeder_kw = stream.make_stream_policy(
-        policy, cluster=cluster, seed=seed, mab_state=mab_state,
-        daso_theta=daso_theta, daso_cfg=daso_cfg,
-        gillis_state=gillis_state)
+        policy, cluster=cluster, seed=seed, gillis_state=gillis_state,
+        **_products(pretrain_state, mab_state=mab_state,
+                    daso_theta=daso_theta, daso_cfg=daso_cfg))
     feeder = stream.StreamFeeder(lam=lam, seed=seed, interval_s=interval_s,
                                  substeps=substeps, cluster=cluster,
                                  apps=apps, max_arrivals=max_arrivals,
@@ -513,44 +361,33 @@ def run_grid(policies: Sequence[str], seeds: Sequence[int] = (0,),
 
     ``backend="jax"`` routes every policy through ``run_grid_batched`` —
     one compiled call per policy instead of a Python loop per cell;
-    record order matches the host backend.  Static BestFit policies and
-    the in-kernel learned policies ("mab"/"splitplace") are both
-    accepted; the pretraining pass (host-side, shared) runs when a
-    learned policy needs states that weren't passed in.  ``mode="train"``
+    record order matches the host backend.  Every policy name of
+    ``jaxsim.resolve``'s table is accepted; the pretraining pass
+    (host-side, shared) runs when a policy needs states (the table's
+    ``needs_mab`` / ``needs_daso``) that weren't passed in.  ``mode="train"``
     selects the in-kernel §6.3 training loop for the learned policies on
     the jitted backend (ε-greedy decisions + DASO finetuning in the
     carry) and the host training flag on ``backend="soa"``."""
     if mode not in ("deploy", "train"):
         raise ValueError(f"unknown mode {mode!r}")
     if backend == "jax":
-        from repro.env.jaxsim import (DASO_LEARNED_POLICIES,
-                                      LEARNED_POLICIES,
-                                      MAB_LEARNED_POLICIES,
-                                      STATIC_DASO_ARMS)
-        # pretrain only for what the requested policies actually consume:
-        # the MAB-family learned policies need mab_state, the surrogate
-        # placers (splitplace / mab+gobi) need the DASO products, and
-        # the in-kernel Gillis baseline needs nothing (fresh Q/ε per
-        # grid).  The pass is a full host-loop trace — the most
-        # expensive step in the pipeline.
-        needs_mab = any(p in MAB_LEARNED_POLICIES for p in policies) \
-            and mab_state is None
-        needs_daso = any(p in DASO_LEARNED_POLICIES
-                         or p in STATIC_DASO_ARMS for p in policies) \
-            and daso_theta is None
+        # pretrain only for what the requested policies actually consume
+        # (the table's needs_mab / needs_daso; the in-kernel Gillis
+        # baseline needs nothing — fresh Q/ε per grid).  The pass is a
+        # full host-loop trace — the most expensive step in the pipeline.
+        rows = [spec(p) for p in policies]
+        needs_mab = any(r.needs_mab for r in rows) and mab_state is None
+        needs_daso = any(r.needs_daso for r in rows) and daso_theta is None
+        pre = None
         if pretrain_intervals and (needs_mab or needs_daso):
             pre = pretrain(pretrain_intervals,
                            lam=pretrain_lam if pretrain_lam is not None
                            else lams[0],
                            seed=pretrain_seed, substeps=substeps,
                            interval_s=interval_s)
-            mab_state = mab_state if mab_state is not None \
-                else pre.mab_state
-            daso_theta = daso_theta if daso_theta is not None \
-                else pre.daso_theta
-            daso_cfg = daso_cfg if daso_cfg is not None else pre.daso_cfg
-            daso_opt_state = daso_opt_state if daso_opt_state is not None \
-                else pre.daso_opt_state
+        products = _products(pre, mab_state=mab_state,
+                             daso_theta=daso_theta, daso_cfg=daso_cfg,
+                             daso_opt_state=daso_opt_state)
         records = []
         for pol in policies:
             # mab_state passes through untouched to static policies: only
@@ -564,9 +401,8 @@ def run_grid(policies: Sequence[str], seeds: Sequence[int] = (0,),
                 pol, seeds=seeds, lams=lams, n_intervals=n_intervals,
                 substeps=substeps, interval_s=interval_s, apps=apps,
                 cluster=cluster_factory() if cluster_factory else None,
-                mab_state=mab_state, daso_theta=daso_theta,
-                daso_cfg=daso_cfg, daso_opt_state=daso_opt_state,
-                mode=mode if pol in LEARNED_POLICIES else "deploy")
+                mode=mode if pol in LEARNED_POLICIES else "deploy",
+                **products)
         # run_grid order is (lam, policy, seed); per-policy batches are
         # (lam, seed) — reorder to match the host backend exactly
         by_cell = {(r["lam"], r["policy"], r["seed"]): r for r in records}
@@ -586,22 +422,23 @@ def run_grid(policies: Sequence[str], seeds: Sequence[int] = (0,),
                        seed=pretrain_seed, substeps=substeps,
                        interval_s=interval_s,
                        policies=[p for p in policies
-                                 if (p in MAB_STATE_POLICIES
+                                 if (p in MAB_LEARNED_POLICIES
                                      and mab_state is None)
-                                 or (p == "gillis"
+                                 or (p in GILLIS_POLICIES
                                      and gillis_policy is None)])
         mab_state = mab_state if mab_state is not None else pre.mab_state
         gillis_policy = gillis_policy if gillis_policy is not None \
             else pre.gillis_policy
     records = []
     for lam, pol, seed in itertools.product(lams, policies, seeds):
-        ms = mab_state if pol in MAB_STATE_POLICIES else None
+        ms = mab_state if pol in MAB_LEARNED_POLICIES else None
         r = run_trace(pol, n_intervals=n_intervals, lam=lam, seed=seed,
                       mab_state=ms, train=mode == "train",
                       substeps=substeps,
                       interval_s=interval_s, apps=apps,
                       cluster=cluster_factory() if cluster_factory else None,
-                      policy=gillis_policy if pol == "gillis" else None)
+                      policy=gillis_policy if pol in GILLIS_POLICIES
+                      else None)
         records.append(_record(pol, seed, lam, r))
         if progress:
             rec = records[-1]

@@ -25,7 +25,7 @@ import numpy as np
 
 def _stream_main(args):
     from repro.env.cluster import make_cluster
-    from repro.env.jaxsim import DASO_LEARNED_POLICIES
+    from repro.env.jaxsim import policies
     from repro.launch import experiments
 
     pretrain_state = None
@@ -34,14 +34,15 @@ def _stream_main(args):
         wants = ("splitplace",) if args.policy != "gillis" else ("gillis",)
         pretrain_state = experiments.pretrain(args.pretrain, lam=args.lam,
                                               policies=wants)
-    elif args.policy in DASO_LEARNED_POLICIES:
+    elif (row := policies.spec(args.policy)).needs_daso:
         theta, cfg = experiments.seeded_surrogate(make_cluster().n,
                                                   seed=args.seed)
         pretrain_state = experiments.PretrainState(daso_theta=theta,
                                                    daso_cfg=cfg)
         print(f"{args.policy}: DASO placer on an untrained surrogate "
               f"(random weights from seed {args.seed}; --pretrain N "
-              f"trains one), cold-start MAB")
+              f"trains one)" + (", cold-start MAB" if row.needs_mab
+                                else ""))
 
     def progress(i, runner, rolling):
         if i % args.report_every:
@@ -119,8 +120,8 @@ def main(argv=None):
                     help="run the always-on edge-sim serving loop "
                          "instead of model-plan selection")
     ap.add_argument("--policy", default="mc",
-                    help="stream mode: policy name (static BestFit or "
-                         "mab/splitplace/mab+gobi/gillis)")
+                    help="stream mode: a policy name of "
+                         "jaxsim.policies.TABLE")
     ap.add_argument("--lam", type=float, default=6.0)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--tasks", type=int, default=10_000,

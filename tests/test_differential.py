@@ -8,15 +8,18 @@ asserts the cross-backend allclose(rtol=1e-4) contract on every summary
 metric (plus the final MAB scalars and, in train mode, the finetuned
 DASO theta).  Three oracle pairs are covered:
 
-  * **static** — ``run_trace_arrays`` vs ``replay_trace_edgesim``;
-  * **deploy** — ``run_trace_arrays_learned`` vs
+Each jitted run is ``jaxsim.resolve`` → ``run_trace_engine``:
+
+  * **static** — a static policy vs ``replay_trace_edgesim``;
+  * **deploy** — ``"mab"`` / ``"splitplace"`` vs
     ``replay_trace_edgesim_learned`` (online UCB MAB ± frozen DASO);
-  * **train**  — ``run_trace_arrays_trained`` vs
+  * **train**  — the same in ``mode="train"`` vs
     ``replay_trace_edgesim_trained`` (ε-greedy MAB + in-kernel DASO
     finetuning);
-  * **gobi**   — the deploy pair with ``decision_aware=False`` (the
-    decision-blind GOBI surrogate ablation; DASO always on);
-  * **gillis** — ``run_trace_arrays_gillis`` vs
+  * **gobi**   — ``"mab+gobi"`` vs the deploy replay with
+    ``decision_aware=False`` (the decision-blind GOBI surrogate
+    ablation; DASO always on);
+  * **gillis** — ``"gillis"`` vs
     ``replay_trace_edgesim_gillis`` (in-kernel contextual ε-greedy
     Q-learning over (LAYER, COMPRESSED) dual traces, incl. the final
     Q-table/ε).
@@ -198,13 +201,14 @@ def check_case(case: dict):
     tel = case.get("telemetry", "summary")
     ctx = f"case={case!r}"
     if case["mode"] == "static":
-        dec = jaxsim.make_static_decider(case["policy"])
+        r = jaxsim.resolve(case["policy"])
         tr = jaxsim.compile_trace(
-            dec, lam=case["lam"], seed=case["seed"],
+            r.decider, lam=case["lam"], seed=case["seed"],
             n_intervals=case["n_intervals"], substeps=case["substeps"],
             cluster=cl, max_arrivals=48)
         ref = jaxsim.replay_trace_edgesim(tr, cluster=cl, telemetry=tel)
-        jx = jaxsim.run_trace_arrays(tr, cluster=cl, max_active=MAX_ACTIVE,
+        jx = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                     cluster=cl, max_active=MAX_ACTIVE,
                                      telemetry=tel)
         assert jx["dropped_tasks"] == 0, ctx
         assert_close(ref, jx, ctx)
@@ -220,9 +224,11 @@ def check_case(case: dict):
         ref = jaxsim.replay_trace_edgesim_gillis(
             tr, gillis_state=st, cluster=cl, gillis_hp=case["gillis_hp"],
             telemetry=tel)
-        jx = jaxsim.run_trace_arrays_gillis(
-            tr, gillis_state=st, cluster=cl, max_active=MAX_ACTIVE,
-            gillis_hp=case["gillis_hp"], telemetry=tel)
+        r = jaxsim.resolve("gillis", gillis_state=st,
+                           gillis_hp=case["gillis_hp"])
+        jx = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                     cluster=cl, max_active=MAX_ACTIVE,
+                                     telemetry=tel)
         assert jx["dropped_tasks"] == 0, ctx
         assert_close(ref, jx, ctx)
         return
@@ -230,6 +236,12 @@ def check_case(case: dict):
     theta = cfg = None
     if case["daso"] is not None:
         theta, cfg = _daso(case["daso"], cl.n, rng)
+    policy = "mab" if cfg is None else "splitplace"
+    r = jaxsim.resolve(
+        "mab+gobi" if case["mode"] == "gobi" else policy,
+        mode="train" if case["mode"] == "train" else "deploy", cluster=cl,
+        mab_state=st, daso_theta=theta, daso_cfg=cfg,
+        mab_hp=case["mab_hp"], train_hp=case.get("train_hp"))
     if case["mode"] == "gobi":
         cfg = cfg._replace(decision_aware=False)
     tr = jaxsim.compile_trace_dual(
@@ -240,18 +252,17 @@ def check_case(case: dict):
         ref = jaxsim.replay_trace_edgesim_learned(
             tr, st, daso_theta=theta, daso_cfg=cfg, cluster=cl,
             mab_hp=case["mab_hp"], telemetry=tel)
-        jx = jaxsim.run_trace_arrays_learned(
-            tr, st, daso_theta=theta, daso_cfg=cfg, cluster=cl,
-            max_active=MAX_ACTIVE, mab_hp=case["mab_hp"], telemetry=tel)
+        jx = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                     cluster=cl, max_active=MAX_ACTIVE,
+                                     telemetry=tel)
     else:
         ref = jaxsim.replay_trace_edgesim_trained(
             tr, st, daso_theta=theta, daso_cfg=cfg, cluster=cl,
             mab_hp=case["mab_hp"], train_hp=case["train_hp"],
             telemetry=tel)
-        jx = jaxsim.run_trace_arrays_trained(
-            tr, st, daso_theta=theta, daso_cfg=cfg, cluster=cl,
-            max_active=MAX_ACTIVE, mab_hp=case["mab_hp"],
-            train_hp=case["train_hp"], telemetry=tel)
+        jx = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                     cluster=cl, max_active=MAX_ACTIVE,
+                                     telemetry=tel)
     assert jx["dropped_tasks"] == 0, ctx
     assert_close(ref, jx, ctx)
 
@@ -308,11 +319,11 @@ def test_regression_ram_pressure_repair_static():
     from repro.env import jaxsim
     from repro.env.cluster import make_cluster
     cl = make_cluster(ram_scale=0.3)
-    dec = jaxsim.make_static_decider("mc")
-    tr = jaxsim.compile_trace(dec, lam=14.0, seed=5, n_intervals=12,
+    r = jaxsim.resolve("mc")
+    tr = jaxsim.compile_trace(r.decider, lam=14.0, seed=5, n_intervals=12,
                               substeps=4, cluster=cl)
     ref = jaxsim.replay_trace_edgesim(tr, cluster=cl)
-    jx = jaxsim.run_trace_arrays(tr, cluster=cl)
+    jx = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed), cluster=cl)
     assert ref["wait_intervals"] > 0      # repair actually failed tasks
     assert_close(ref, jx, "ram-pressure static")
 
@@ -334,9 +345,10 @@ def test_regression_ram_pressure_repair_train():
     ref = jaxsim.replay_trace_edgesim_trained(tr, st, daso_theta=theta,
                                               daso_cfg=cfg, cluster=cl,
                                               train_hp=hp)
-    jx = jaxsim.run_trace_arrays_trained(tr, st, daso_theta=theta,
-                                         daso_cfg=cfg, cluster=cl,
-                                         train_hp=hp)
+    r = jaxsim.resolve("splitplace", mode="train", cluster=cl,
+                       mab_state=st, daso_theta=theta, daso_cfg=cfg,
+                       train_hp=hp)
+    jx = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed), cluster=cl)
     assert ref["wait_intervals"] > 0 or ref["response_intervals"] > 1.0
     assert_close(ref, jx, "ram-pressure train")
 
@@ -354,7 +366,8 @@ def test_regression_eps_boundary_decisions():
     for eps in (0.0, 1.0):
         st = _mab_state(rng)._replace(eps=jnp.asarray(eps, jnp.float32))
         ref = jaxsim.replay_trace_edgesim_trained(tr, st)
-        jx = jaxsim.run_trace_arrays_trained(tr, st)
+        r = jaxsim.resolve("mab", mode="train", mab_state=st)
+        jx = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed))
         assert_close(ref, jx, f"eps={eps}")
 
 
@@ -369,7 +382,8 @@ def test_regression_gillis_eps_boundaries():
                                    variants=(LAYER, COMPRESSED))
     for hp in ((0.0, 0.3, 0.995), (1.0, 1.0, 1.0)):
         ref = jaxsim.replay_trace_edgesim_gillis(tr, gillis_hp=hp)
-        jx = jaxsim.run_trace_arrays_gillis(tr, gillis_hp=hp)
+        r = jaxsim.resolve("gillis", gillis_hp=hp)
+        jx = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed))
         assert_close(ref, jx, f"gillis hp={hp}")
 
 
@@ -388,7 +402,8 @@ def test_regression_gillis_ram_pressure():
                                    variants=(LAYER, COMPRESSED))
     ref = jaxsim.replay_trace_edgesim_gillis(tr, gillis_state=st,
                                              cluster=cl)
-    jx = jaxsim.run_trace_arrays_gillis(tr, gillis_state=st, cluster=cl)
+    r = jaxsim.resolve("gillis", gillis_state=st)
+    jx = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed), cluster=cl)
     assert ref["wait_intervals"] > 0 or ref["response_intervals"] > 1.0
     assert_close(ref, jx, "gillis ram pressure")
 
@@ -398,14 +413,17 @@ def test_regression_capacity_drop_counting():
     count is deterministic, and batched grid rows agree with solo runs
     even while dropping."""
     from repro.env import jaxsim
-    dec = jaxsim.make_static_decider("mc")
-    tr = jaxsim.compile_trace(dec, lam=10.0, seed=1, n_intervals=8,
+    r = jaxsim.resolve("mc")
+    tr = jaxsim.compile_trace(r.decider, lam=10.0, seed=1, n_intervals=8,
                               substeps=3)
-    jx1 = jaxsim.run_trace_arrays(tr, max_active=8)
-    jx2 = jaxsim.run_trace_arrays(tr, max_active=8)
+    jx1 = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                  max_active=8)
+    jx2 = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                  max_active=8)
     assert jx1["dropped_tasks"] > 0
     assert jx1 == jx2                      # drop accounting deterministic
-    grid = jaxsim.run_grid_arrays([tr, tr], max_active=8, threads=1)
+    grid = jaxsim.run_grid_engine(r.engine, [tr, tr], r.state, max_active=8,
+                                  threads=1)
     for row in grid:
         for k in jx1:
             assert np.isclose(jx1[k], row[k], rtol=1e-12, atol=1e-12)

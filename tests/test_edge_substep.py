@@ -8,7 +8,7 @@ Three implementations of one interval of substep physics must agree:
     (interpret mode on CPU), same formulas expressed as an in-kernel
     ``fori_loop`` over VMEM-resident refs;
   * the driver's inline XLA path (``kernels.run_substeps``) — checked
-    end-to-end through ``run_trace_arrays(substep_impl=...)``.
+    end-to-end through ``run_trace_engine(substep_impl=...)``.
 
 The fuzz draws synthetic-but-consistent slot states from a seeded
 ``numpy.random.RandomState`` (no hypothesis dependency): padding
@@ -114,11 +114,12 @@ def test_driver_impls_agree_end_to_end():
     """substep_impl="xla" / "ref" / "pallas" must produce the same trace
     summaries through the real driver (dense vs incremental census is
     exact: the counts are small integers)."""
-    from repro.env.jaxsim import compile_trace, make_static_decider, \
-        run_trace_arrays
-    tr = compile_trace(make_static_decider("mc"), lam=5.0, seed=3,
-                       n_intervals=4, substeps=4)
-    outs = {impl: run_trace_arrays(tr, substep_impl=impl)
+    from repro.env.jaxsim import compile_trace, resolve, run_trace_engine
+    r = resolve("mc")
+    tr = compile_trace(r.decider, lam=5.0, seed=3, n_intervals=4,
+                       substeps=4)
+    outs = {impl: run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                   substep_impl=impl)
             for impl in ("xla", "ref", "pallas")}
     base = outs["xla"]
     for impl in ("ref", "pallas"):

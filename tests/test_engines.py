@@ -65,8 +65,11 @@ def test_train_engine_gates_closed_matches_deploy():
     hp = (0.0, 0.3, 0.3, 0.1)          # ucb_c = 0 → pure Q argmax
     tr = jaxsim.compile_trace_dual(lam=5.0, seed=1, n_intervals=8,
                                    substeps=4)
-    dep = jaxsim.run_trace_arrays_learned(tr, st, mab_hp=hp)
-    trn = jaxsim.run_trace_arrays_trained(tr, st, mab_hp=hp)
+    outs = []
+    for mode in ("deploy", "train"):
+        r = jaxsim.resolve("mab", mode=mode, mab_state=st, mab_hp=hp)
+        outs.append(jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed)))
+    dep, trn = outs
     assert dep["tasks_completed"] > 0
     assert set(dep) == set(trn)
     for k in dep:
@@ -122,9 +125,11 @@ def test_gillis_continues_from_state():
     tr = jaxsim.compile_trace_dual(lam=5.0, seed=3, n_intervals=6,
                                    substeps=3,
                                    variants=(LAYER, COMPRESSED))
-    fresh = jaxsim.run_trace_arrays_gillis(tr)
+    r = jaxsim.resolve("gillis")
+    fresh = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed))
     st = {"Q": fresh["gillis_q"], "eps": fresh["gillis_eps"]}
-    cont = jaxsim.run_trace_arrays_gillis(tr, gillis_state=st)
+    r = jaxsim.resolve("gillis", gillis_state=st)
+    cont = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed))
     decay = jaxsim.GILLIS_HP[2]
     assert np.isclose(cont["gillis_eps"],
                       fresh["gillis_eps"] * decay ** tr.n_intervals,

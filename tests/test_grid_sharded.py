@@ -3,7 +3,7 @@ devices.
 
 The acceptance contract for the device-scale dispatcher: an *uneven*
 grid (G not a multiple of the mesh size, so dead padded cells are in
-play) run through ``run_grid_arrays(devices=8)`` must match the
+play) run through ``run_grid_engine(devices=8)`` must match the
 thread-chunk path within ``allclose(rtol=1e-4)`` on every summary
 metric, for the static engine AND the splitplace learned engine in both
 deploy and train modes.  Runs in a subprocess so the forced host-device
@@ -36,14 +36,16 @@ def check(name, thr, shd):
     print(f"{name}: {len(thr)} rows match OK")
 
 # uneven: 5 traces on an 8-device mesh -> 3 dead padded cells
-dec = jaxsim.make_static_decider("mc")
-traces = [jaxsim.compile_trace(dec, lam=lam, seed=s, n_intervals=4,
+mc = jaxsim.resolve("mc")
+traces = [jaxsim.compile_trace(mc.decider, lam=lam, seed=s, n_intervals=4,
                                substeps=4)
           for lam in (3.0, 6.0) for s in (0, 1, 2)][:5]
 from repro.obs import RunLedger, use_ledger
 with use_ledger(RunLedger("sharded")) as led:
-    sharded = jaxsim.run_grid_arrays(traces, devices=8)
-check("static", jaxsim.run_grid_arrays(traces, threads=2), sharded)
+    sharded = jaxsim.run_grid_engine(mc.engine, traces, mc.state, devices=8)
+check("static",
+      jaxsim.run_grid_engine(mc.engine, traces, mc.state, threads=2),
+      sharded)
 # every device holds one (possibly dead, padded) cell of the 8-cell mesh
 spread = {k: v for k, v in led.counters.items()
           if k.startswith("grid.cells_on_device.")}
@@ -61,29 +63,21 @@ theta = daso.init_surrogate(jax.random.PRNGKey(0), cfg)
 dtr = [jaxsim.compile_trace_dual(lam=lam, seed=s, n_intervals=4,
                                  substeps=4)
        for lam in (3.0, 6.0) for s in (0, 1, 2)][:5]
-check("splitplace deploy",
-      jaxsim.run_grid_arrays_learned(dtr, st, daso_theta=theta,
-                                     daso_cfg=cfg, threads=2),
-      jaxsim.run_grid_arrays_learned(dtr, st, daso_theta=theta,
-                                     daso_cfg=cfg, devices=8))
-check("splitplace train",
-      jaxsim.run_grid_arrays_trained(dtr, st, daso_theta=theta,
-                                     daso_cfg=cfg, threads=2),
-      jaxsim.run_grid_arrays_trained(dtr, st, daso_theta=theta,
-                                     daso_cfg=cfg, devices=8))
-check("static-daso random arm",
-      jaxsim.run_grid_arrays_static_daso(dtr, "random+daso",
-                                         daso_theta=theta, daso_cfg=cfg,
-                                         threads=2),
-      jaxsim.run_grid_arrays_static_daso(dtr, "random+daso",
-                                         daso_theta=theta, daso_cfg=cfg,
-                                         devices=8))
+for name, policy, mode in (("splitplace deploy", "splitplace", "deploy"),
+                           ("splitplace train", "splitplace", "train"),
+                           ("static-daso random arm", "random+daso",
+                            "deploy")):
+    r = jaxsim.resolve(policy, mode=mode, mab_state=st, daso_theta=theta,
+                       daso_cfg=cfg)
+    check(name,
+          jaxsim.run_grid_engine(r.engine, dtr, r.state, threads=2),
+          jaxsim.run_grid_engine(r.engine, dtr, r.state, devices=8))
 
 # devices="auto" takes the whole fleet; bogus counts raise
-out = jaxsim.run_grid_arrays(traces, devices="auto")
+out = jaxsim.run_grid_engine(mc.engine, traces, mc.state, devices="auto")
 assert len(out) == 5
 try:
-    jaxsim.run_grid_arrays(traces, devices=9)
+    jaxsim.run_grid_engine(mc.engine, traces, mc.state, devices=9)
 except ValueError as e:
     print("devices=9 rejected:", e)
 else:

@@ -12,9 +12,8 @@ import numpy as np
 import pytest
 
 from repro.env.cluster import make_cluster
-from repro.env.jaxsim import (compile_trace, make_static_decider,
-                              replay_trace_edgesim, run_grid_arrays,
-                              run_trace_arrays)
+from repro.env.jaxsim import (compile_trace, replay_trace_edgesim, resolve,
+                              run_grid_engine, run_trace_engine)
 
 RTOL, ATOL = 1e-4, 1e-9
 
@@ -29,10 +28,11 @@ def assert_summaries_close(ref, jx, rtol=RTOL, atol=ATOL):
 @pytest.mark.parametrize("lam", [4.0, 9.0])
 def test_bestfit_trace_parity_two_lams(lam):
     """20-interval mixed-decision BestFit trace at two arrival rates."""
-    dec = make_static_decider("bestfit-rr")
-    tr = compile_trace(dec, lam=lam, seed=0, n_intervals=20, substeps=10)
+    r = resolve("bestfit-rr")
+    tr = compile_trace(r.decider, lam=lam, seed=0, n_intervals=20,
+                       substeps=10)
     ref = replay_trace_edgesim(tr)
-    jx = run_trace_arrays(tr)
+    jx = run_trace_engine(r.engine, tr, r.state(tr.seed))
     assert ref["tasks_completed"] > 0
     assert jx["dropped_tasks"] == 0
     assert_summaries_close(ref, jx)
@@ -42,21 +42,22 @@ def test_parity_under_ram_pressure():
     """Squeezed RAM exercises the repair fallback, placement failure
     (waiting tasks) and swap-slowdown paths on both backends."""
     cl = make_cluster(ram_scale=0.35)
-    dec = make_static_decider("mc")
-    tr = compile_trace(dec, lam=14.0, seed=2, n_intervals=12, substeps=8,
-                       cluster=cl)
+    r = resolve("mc")
+    tr = compile_trace(r.decider, lam=14.0, seed=2, n_intervals=12,
+                       substeps=8, cluster=cl)
     ref = replay_trace_edgesim(tr, cluster=cl)
-    jx = run_trace_arrays(tr, cluster=cl)
+    jx = run_trace_engine(r.engine, tr, r.state(tr.seed), cluster=cl)
     assert ref["wait_intervals"] > 0        # repair actually failed tasks
     assert_summaries_close(ref, jx)
 
 
 def test_layer_chain_parity():
     """Pure layer-split load: stage precedence + activation transfers."""
-    dec = make_static_decider("bestfit-layer")
-    tr = compile_trace(dec, lam=8.0, seed=3, n_intervals=15, substeps=10)
+    r = resolve("bestfit-layer")
+    tr = compile_trace(r.decider, lam=8.0, seed=3, n_intervals=15,
+                       substeps=10)
     ref = replay_trace_edgesim(tr)
-    jx = run_trace_arrays(tr)
+    jx = run_trace_engine(r.engine, tr, r.state(tr.seed))
     assert ref["layer_fraction"] == 1.0
     assert_summaries_close(ref, jx)
 
@@ -64,12 +65,13 @@ def test_layer_chain_parity():
 def test_vmap_grid_rows_match_solo_runs():
     """Batched grid row i must equal the solo run of trace i (vmap and
     chunked-thread dispatch change nothing numerically)."""
-    dec = make_static_decider("bestfit-rr")
-    traces = [compile_trace(dec, lam=lam, seed=s, n_intervals=10, substeps=6)
+    r = resolve("bestfit-rr")
+    traces = [compile_trace(r.decider, lam=lam, seed=s, n_intervals=10,
+                            substeps=6)
               for lam in (4.0, 8.0) for s in (0, 1)]
-    grid = run_grid_arrays(traces, threads=2)
+    grid = run_grid_engine(r.engine, traces, r.state, threads=2)
     for i, tr in enumerate(traces):
-        solo = run_trace_arrays(tr)
+        solo = run_trace_engine(r.engine, tr, r.state(tr.seed))
         for k in solo:
             assert np.isclose(solo[k], grid[i][k], rtol=1e-12, atol=1e-12), \
                 f"row {i} {k}: solo={solo[k]!r} grid={grid[i][k]!r}"
@@ -77,9 +79,10 @@ def test_vmap_grid_rows_match_solo_runs():
 
 def test_capacity_overflow_is_counted_not_silent():
     """Arrivals beyond ``max_active`` must surface in ``dropped_tasks``."""
-    dec = make_static_decider("mc")
-    tr = compile_trace(dec, lam=10.0, seed=0, n_intervals=8, substeps=4)
-    jx = run_trace_arrays(tr, max_active=8)
+    r = resolve("mc")
+    tr = compile_trace(r.decider, lam=10.0, seed=0, n_intervals=8,
+                       substeps=4)
+    jx = run_trace_engine(r.engine, tr, r.state(tr.seed), max_active=8)
     assert jx["dropped_tasks"] > 0
 
 
@@ -135,12 +138,12 @@ def test_inkernel_mab_trace_parity():
     must reproduce the host replay: decisions, both split variants, and
     the final MAB scalars (eps/rho/t fingerprint the RBED trajectory)."""
     from repro.env.jaxsim import (compile_trace_dual,
-                                  replay_trace_edgesim_learned,
-                                  run_trace_arrays_learned)
+                                  replay_trace_edgesim_learned)
     st = _mab_state()
     tr = compile_trace_dual(lam=5.0, seed=1, n_intervals=10, substeps=6)
     ref = replay_trace_edgesim_learned(tr, st)
-    jx = run_trace_arrays_learned(tr, st)
+    r = resolve("mab", mab_state=st)
+    jx = run_trace_engine(r.engine, tr, r.state(tr.seed))
     assert ref["tasks_completed"] > 0
     assert 0.0 < ref["layer_fraction"] < 1.0   # both arms actually taken
     assert jx["mab_t"] == tr.n_intervals + int(st.t)
@@ -151,14 +154,14 @@ def test_inkernel_splitplace_parity():
     """MAB decider + array-form DASO placer (surrogate ascent, BestFit
     warm start, feasibility-repair fallback) vs the host replay."""
     from repro.env.jaxsim import (compile_trace_dual,
-                                  replay_trace_edgesim_learned,
-                                  run_trace_arrays_learned)
+                                  replay_trace_edgesim_learned)
     st = _mab_state()
     theta, cfg = _daso()
     tr = compile_trace_dual(lam=5.0, seed=1, n_intervals=10, substeps=6)
     ref = replay_trace_edgesim_learned(tr, st, daso_theta=theta,
                                        daso_cfg=cfg)
-    jx = run_trace_arrays_learned(tr, st, daso_theta=theta, daso_cfg=cfg)
+    r = resolve("splitplace", mab_state=st, daso_theta=theta, daso_cfg=cfg)
+    jx = run_trace_engine(r.engine, tr, r.state(tr.seed))
     assert ref["tasks_completed"] > 0
     assert_summaries_close(ref, jx)
 
@@ -166,20 +169,17 @@ def test_inkernel_splitplace_parity():
 def test_learned_vmap_rows_match_solo():
     """Each grid row carries its own MABState copy: batched rows must be
     bit-close to solo runs, including the final carried-state scalars."""
-    from repro.env.jaxsim import (compile_trace_dual,
-                                  run_grid_arrays_learned,
-                                  run_trace_arrays_learned)
+    from repro.env.jaxsim import compile_trace_dual
     st = _mab_state()
     theta, cfg = _daso()
     traces = [compile_trace_dual(lam=lam, seed=s, n_intervals=6, substeps=4)
               for lam in (4.0, 7.0) for s in (0, 1)]
-    grid = run_grid_arrays_learned(traces, st, daso_theta=theta,
-                                   daso_cfg=cfg, threads=2)
+    r = resolve("splitplace", mab_state=st, daso_theta=theta, daso_cfg=cfg)
+    grid = run_grid_engine(r.engine, traces, r.state, threads=2)
     eps = {g["mab_eps"] for g in grid}
     assert len(eps) > 1          # per-row online trajectories diverged
     for i, tr in enumerate(traces):
-        solo = run_trace_arrays_learned(tr, st, daso_theta=theta,
-                                        daso_cfg=cfg)
+        solo = run_trace_engine(r.engine, tr, r.state(tr.seed))
         for k in solo:
             assert np.isclose(solo[k], grid[i][k], rtol=1e-12,
                               atol=1e-12), \
@@ -200,12 +200,12 @@ def test_inkernel_mab_train_parity():
     the shared fold-in key choreography, both arms taken, and the final
     MAB scalars fingerprinting the RBED trajectory."""
     from repro.env.jaxsim import (compile_trace_dual,
-                                  replay_trace_edgesim_trained,
-                                  run_trace_arrays_trained)
+                                  replay_trace_edgesim_trained)
     st = _mab_state()
     tr = compile_trace_dual(lam=5.0, seed=1, n_intervals=10, substeps=6)
     ref = replay_trace_edgesim_trained(tr, st)
-    jx = run_trace_arrays_trained(tr, st)
+    r = resolve("mab", mode="train", mab_state=st)
+    jx = run_trace_engine(r.engine, tr, r.state(tr.seed))
     assert ref["tasks_completed"] > 0
     assert 0.0 < ref["layer_fraction"] < 1.0   # both arms actually taken
     assert jx["mab_t"] == tr.n_intervals + int(st.t)
@@ -221,15 +221,16 @@ def test_inkernel_splitplace_train_parity():
     surrogate's ascended placements are actually deployed."""
     from repro.core import daso
     from repro.env.jaxsim import (compile_trace_dual,
-                                  replay_trace_edgesim_trained,
-                                  run_trace_arrays_trained)
+                                  replay_trace_edgesim_trained)
     st = _mab_state()
     theta0, cfg = _daso()
     tr = compile_trace_dual(lam=5.0, seed=3, n_intervals=40, substeps=5)
     assert tr.n_intervals > daso.PLACE_MIN     # ascended placements used
     ref = replay_trace_edgesim_trained(tr, st, daso_theta=theta0,
                                        daso_cfg=cfg)
-    jx = run_trace_arrays_trained(tr, st, daso_theta=theta0, daso_cfg=cfg)
+    r = resolve("splitplace", mode="train", mab_state=st,
+                daso_theta=theta0, daso_cfg=cfg)
+    jx = run_trace_engine(r.engine, tr, r.state(tr.seed))
     assert ref["tasks_completed"] > 0
     theta_ref = ref.pop("daso_theta")
     theta_jx = jx.pop("daso_theta")
@@ -247,20 +248,18 @@ def test_trained_vmap_rows_match_solo():
     """Each grid cell carries its own (MABState, theta, opt, window):
     batched rows must be bit-close to solo runs, incl. the finetuned
     theta, with per-cell ε-greedy keys diverging the trajectories."""
-    from repro.env.jaxsim import (compile_trace_dual,
-                                  run_grid_arrays_trained,
-                                  run_trace_arrays_trained)
+    from repro.env.jaxsim import compile_trace_dual
     st = _mab_state()
     theta, cfg = _daso()
     traces = [compile_trace_dual(lam=lam, seed=s, n_intervals=6, substeps=4)
               for lam in (4.0, 7.0) for s in (0, 1)]
-    grid = run_grid_arrays_trained(traces, st, daso_theta=theta,
-                                   daso_cfg=cfg, threads=2)
+    r = resolve("splitplace", mode="train", mab_state=st, daso_theta=theta,
+                daso_cfg=cfg)
+    grid = run_grid_engine(r.engine, traces, r.state, threads=2)
     eps = {g["mab_eps"] for g in grid}
     assert len(eps) > 1          # per-cell online trajectories diverged
     for i, tr in enumerate(traces):
-        solo = run_trace_arrays_trained(tr, st, daso_theta=theta,
-                                        daso_cfg=cfg)
+        solo = run_trace_engine(r.engine, tr, r.state(tr.seed))
         _theta_allclose(solo.pop("daso_theta"), grid[i].pop("daso_theta"),
                         rtol=1e-12, atol=1e-12)
         for k in solo:
@@ -297,6 +296,8 @@ def test_experiments_train_mode_backend_jax():
     with pytest.raises(ValueError):
         run_trace("mc", n_intervals=6, lam=5.0, seed=1, substeps=4,
                   backend="jax", mode="train")
+    with pytest.raises(ValueError, match="is static"):
+        resolve("mc", mode="train")
 
 
 def test_inkernel_gillis_parity():
@@ -305,13 +306,13 @@ def test_inkernel_gillis_parity():
     TD(0) updates — must reproduce the host replay, incl. the final
     Q-table and ε (the Q-trajectory fingerprint)."""
     from repro.env.jaxsim import (compile_trace_dual,
-                                  replay_trace_edgesim_gillis,
-                                  run_trace_arrays_gillis)
+                                  replay_trace_edgesim_gillis)
     from repro.env.workload import COMPRESSED, LAYER
     tr = compile_trace_dual(lam=5.0, seed=1, n_intervals=10, substeps=6,
                             variants=(LAYER, COMPRESSED))
     ref = replay_trace_edgesim_gillis(tr)
-    jx = run_trace_arrays_gillis(tr)
+    r = resolve("gillis")
+    jx = run_trace_engine(r.engine, tr, r.state(tr.seed))
     assert ref["tasks_completed"] > 0
     assert 0.0 < ref["layer_fraction"] < 1.0   # both arms actually taken
     q_ref = ref.pop("gillis_q")
@@ -325,17 +326,16 @@ def test_inkernel_gillis_parity():
 def test_gillis_vmap_rows_match_solo():
     """Each grid cell carries its own (Q, ε) copy: batched rows must be
     bit-close to solo runs, incl. the final Q-table."""
-    from repro.env.jaxsim import (compile_trace_dual,
-                                  run_grid_arrays_gillis,
-                                  run_trace_arrays_gillis)
+    from repro.env.jaxsim import compile_trace_dual
     from repro.env.workload import COMPRESSED, LAYER
     traces = [compile_trace_dual(lam=lam, seed=s, n_intervals=6,
                                  substeps=4, variants=(LAYER, COMPRESSED))
               for lam in (4.0, 7.0) for s in (0, 1)]
-    grid = run_grid_arrays_gillis(traces, threads=2)
+    r = resolve("gillis")
+    grid = run_grid_engine(r.engine, traces, r.state, threads=2)
     assert len({tuple(np.ravel(g["gillis_q"])) for g in grid}) > 1
     for i, tr in enumerate(traces):
-        solo = run_trace_arrays_gillis(tr)
+        solo = run_trace_engine(r.engine, tr, r.state(tr.seed))
         np.testing.assert_allclose(grid[i].pop("gillis_q"),
                                    solo.pop("gillis_q"),
                                    rtol=1e-12, atol=1e-12)
@@ -351,15 +351,17 @@ def test_inkernel_gobi_parity():
     the ascent trajectories must coincide exactly like decision-aware
     DASO's."""
     from repro.env.jaxsim import (compile_trace_dual,
-                                  replay_trace_edgesim_learned,
-                                  run_trace_arrays_learned)
+                                  replay_trace_edgesim_learned)
     st = _mab_state()
     theta, cfg = _daso()
     blind = cfg._replace(decision_aware=False)
     tr = compile_trace_dual(lam=5.0, seed=1, n_intervals=10, substeps=6)
     ref = replay_trace_edgesim_learned(tr, st, daso_theta=theta,
                                        daso_cfg=blind)
-    jx = run_trace_arrays_learned(tr, st, daso_theta=theta, daso_cfg=blind)
+    # the table applies the GOBI rule to the decision-aware cfg
+    r = resolve("mab+gobi", mab_state=st, daso_theta=theta, daso_cfg=cfg)
+    assert r.engine.daso_cfg == blind
+    jx = run_trace_engine(r.engine, tr, r.state(tr.seed))
     assert ref["tasks_completed"] > 0
     assert_summaries_close(ref, jx)
 
@@ -390,6 +392,8 @@ def test_experiments_gillis_gobi_backend_jax():
     with pytest.raises(ValueError):
         run_grid_batched("mab+gobi", seeds=(1,), lams=(5.0,),
                          n_intervals=6, substeps=4, mab_state=st)
+    with pytest.raises(ValueError, match="daso_cfg/daso_theta"):
+        resolve("mab+gobi", mab_state=st)
 
 
 def test_experiments_learned_backend_jax():
@@ -413,6 +417,8 @@ def test_experiments_learned_backend_jax():
     with pytest.raises(ValueError):
         run_grid_batched("splitplace", seeds=(1,), lams=(5.0,),
                          n_intervals=6, substeps=4, mab_state=st)
+    with pytest.raises(ValueError, match="daso_cfg/daso_theta"):
+        resolve("splitplace", mab_state=st, daso_cfg=cfg)
 
 
 def test_static_daso_arms_parity():
@@ -421,16 +427,15 @@ def test_static_daso_arms_parity():
     decider) feeding the frozen DASO placer, decision-blind for the GOBI
     arms — vs the host replay with the identical shared pure functions."""
     from repro.env.jaxsim import (compile_trace_dual,
-                                  replay_trace_edgesim_static_daso,
-                                  run_trace_arrays_static_daso)
+                                  replay_trace_edgesim_static_daso)
     theta, cfg = _daso()
     tr = compile_trace_dual(lam=5.0, seed=1, n_intervals=6, substeps=4)
     fractions = {}
     for pol in ("layer+gobi", "semantic+gobi", "random+daso"):
         ref = replay_trace_edgesim_static_daso(tr, pol, daso_theta=theta,
                                                daso_cfg=cfg)
-        jx = run_trace_arrays_static_daso(tr, pol, daso_theta=theta,
-                                          daso_cfg=cfg)
+        r = resolve(pol, daso_theta=theta, daso_cfg=cfg)
+        jx = run_trace_engine(r.engine, tr, r.state(tr.seed))
         assert ref["tasks_completed"] > 0, pol
         assert_summaries_close(ref, jx)
         fractions[pol] = ref["layer_fraction"]
@@ -458,3 +463,5 @@ def test_experiments_static_daso_backend_jax():
     with pytest.raises(ValueError):
         run_grid_batched("random+daso", seeds=(1,), lams=(5.0,),
                          n_intervals=6, substeps=4)
+    with pytest.raises(ValueError, match="daso_cfg/daso_theta"):
+        resolve("random+daso", daso_theta=theta)

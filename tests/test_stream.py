@@ -82,13 +82,13 @@ def test_replay_parity_learned():
     """MABDeployEngine's UCB counters ride the carry across chunk
     boundaries; decision parity requires the global interval index."""
     from repro.env import jaxsim
-    from repro.env.jaxsim import driver, stream
+    from repro.env.jaxsim import stream
     st = _mab_state()
     tr = jaxsim.compile_trace_dual(lam=4.0, seed=3, n_intervals=12,
                                    substeps=4)
-    eng = jaxsim.engines.MABDeployEngine(mab_hp=tuple(driver.MAB_HP))
-    ref = jaxsim.run_trace_engine(eng, tr, driver._deploy_es(st, ()))
-    got = stream.replay_stream(eng, tr, driver._deploy_es(st, ()),
+    r = jaxsim.resolve("mab", mab_state=st)
+    ref = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed))
+    got = stream.replay_stream(r.engine, tr, r.state(tr.seed),
                                chunk_intervals=5)
     _summaries_close(ref, got, "learned")
 
@@ -98,19 +98,15 @@ def test_replay_parity_gillis():
     strictest chunk-boundary contract: any chunk-local t would pass
     static parity but desync every decision here."""
     from repro.env import jaxsim
-    from repro.env.jaxsim import driver, stream
+    from repro.env.jaxsim import stream
     from repro.env.workload import COMPRESSED, LAYER
     tr = jaxsim.compile_trace_dual(lam=4.0, seed=2, n_intervals=12,
                                    substeps=4,
                                    variants=(LAYER, COMPRESSED))
-    eng = jaxsim.engines.GillisEngine(gillis_hp=tuple(driver.GILLIS_HP))
-
-    def es0():
-        return driver._gillis_es(None, driver.trace_train_key(2), 3,
-                                 driver.GILLIS_HP[0])
-
-    ref = jaxsim.run_trace_engine(eng, tr, es0())
-    got = stream.replay_stream(eng, tr, es0(), chunk_intervals=5)
+    r = jaxsim.resolve("gillis")
+    ref = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed))
+    got = stream.replay_stream(r.engine, tr, r.state(tr.seed),
+                               chunk_intervals=5)
     _summaries_close(ref, got, "gillis")
 
 

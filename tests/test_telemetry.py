@@ -66,11 +66,12 @@ def _series_close(ref, jx, ctx):
 def test_series_parity_static():
     from repro.env import jaxsim
     from repro.env.metrics import TELEMETRY_COLS
-    dec = jaxsim.make_static_decider("bestfit-rr")
-    tr = jaxsim.compile_trace(dec, lam=5.0, seed=0, n_intervals=8,
+    r = jaxsim.resolve("bestfit-rr")
+    tr = jaxsim.compile_trace(r.decider, lam=5.0, seed=0, n_intervals=8,
                               substeps=4)
     ref = jaxsim.replay_trace_edgesim(tr, telemetry="interval")
-    jx = jaxsim.run_trace_arrays(tr, telemetry="interval")
+    jx = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                 telemetry="interval")
     assert jx["telemetry"]["cols"] == list(TELEMETRY_COLS)
     assert np.asarray(jx["telemetry"]["series"]).shape == (8, 18)
     _series_close(ref, jx, "static")
@@ -85,7 +86,9 @@ def test_series_parity_learned():
     tr = jaxsim.compile_trace_dual(lam=5.0, seed=3, n_intervals=6,
                                    substeps=3)
     ref = jaxsim.replay_trace_edgesim_learned(tr, st, telemetry="interval")
-    jx = jaxsim.run_trace_arrays_learned(tr, st, telemetry="interval")
+    r = jaxsim.resolve("mab", mab_state=st)
+    jx = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                 telemetry="interval")
     assert tuple(jx["telemetry"]["cols"][-4:]) == MAB_TELEMETRY_COLS
     _series_close(ref, jx, "learned")
     # the MAB decision counter actually advanced over the trace
@@ -114,9 +117,10 @@ def test_series_parity_trained():
     ref = jaxsim.replay_trace_edgesim_trained(
         tr, st, daso_theta=theta, daso_cfg=cfg, train_hp=hp,
         telemetry="interval")
-    jx = jaxsim.run_trace_arrays_trained(
-        tr, st, daso_theta=theta, daso_cfg=cfg, train_hp=hp,
-        telemetry="interval")
+    r = jaxsim.resolve("splitplace", mode="train", mab_state=st,
+                       daso_theta=theta, daso_cfg=cfg, train_hp=hp)
+    jx = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                 telemetry="interval")
     cols = jx["telemetry"]["cols"]
     assert cols[-2:] == ["daso_win_fill", "daso_last_loss"]
     _series_close(ref, jx, "trained")
@@ -132,7 +136,9 @@ def test_series_parity_gillis():
                                    substeps=3,
                                    variants=(LAYER, COMPRESSED))
     ref = jaxsim.replay_trace_edgesim_gillis(tr, telemetry="interval")
-    jx = jaxsim.run_trace_arrays_gillis(tr, telemetry="interval")
+    r = jaxsim.resolve("gillis")
+    jx = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                 telemetry="interval")
     cols = jx["telemetry"]["cols"]
     assert cols[-3:] == ["gillis_eps", "gillis_q_min", "gillis_q_max"]
     _series_close(ref, jx, "gillis")
@@ -150,11 +156,12 @@ def test_interval_mode_preserves_summary():
     and adds the row, so everything the ``"summary"`` run reports is
     reproduced at 1e-12."""
     from repro.env import jaxsim
-    dec = jaxsim.make_static_decider("bestfit-rr")
-    tr = jaxsim.compile_trace(dec, lam=5.0, seed=0, n_intervals=8,
+    r = jaxsim.resolve("bestfit-rr")
+    tr = jaxsim.compile_trace(r.decider, lam=5.0, seed=0, n_intervals=8,
                               substeps=4)
-    off = jaxsim.run_trace_arrays(tr)
-    on = jaxsim.run_trace_arrays(tr, telemetry="interval")
+    off = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed))
+    on = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                 telemetry="interval")
     for k, v in off.items():
         assert np.isclose(on[k], v, rtol=1e-12, atol=1e-12), \
             f"{k}: summary={v!r} interval={on[k]!r}"
@@ -162,11 +169,12 @@ def test_interval_mode_preserves_summary():
 
 def test_percentiles_within_reported_bound():
     from repro.env import jaxsim
-    dec = jaxsim.make_static_decider("bestfit-rr")
-    tr = jaxsim.compile_trace(dec, lam=5.0, seed=0, n_intervals=8,
+    r = jaxsim.resolve("bestfit-rr")
+    tr = jaxsim.compile_trace(r.decider, lam=5.0, seed=0, n_intervals=8,
                               substeps=4)
     ref = jaxsim.replay_trace_edgesim(tr, telemetry="interval")
-    jx = jaxsim.run_trace_arrays(tr, telemetry="interval")
+    jx = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                 telemetry="interval")
     assert ref["percentile_err_s"] == 0.0      # host path is exact
     assert jx["percentile_err_s"] >= 0.0
     for q in (50, 95, 99):
@@ -181,11 +189,12 @@ def test_telemetry_knob_validation():
     import pytest
 
     from repro.env import jaxsim
-    dec = jaxsim.make_static_decider("mc")
-    tr = jaxsim.compile_trace(dec, lam=3.0, seed=0, n_intervals=4,
+    r = jaxsim.resolve("mc")
+    tr = jaxsim.compile_trace(r.decider, lam=3.0, seed=0, n_intervals=4,
                               substeps=3)
     with pytest.raises(ValueError, match="telemetry"):
-        jaxsim.run_trace_arrays(tr, telemetry="everything")
+        jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                telemetry="everything")
 
 
 # ------------------------------------------------- cache + ledger layer
@@ -193,12 +202,12 @@ def test_telemetry_knob_validation():
 
 def test_cache_stats_hits_and_misses():
     from repro.env import jaxsim
-    dec = jaxsim.make_static_decider("mc")
-    tr = jaxsim.compile_trace(dec, lam=3.0, seed=0, n_intervals=4,
+    r = jaxsim.resolve("mc")
+    tr = jaxsim.compile_trace(r.decider, lam=3.0, seed=0, n_intervals=4,
                               substeps=3)
-    jaxsim.run_trace_arrays(tr)                    # warm (maybe a miss)
+    jaxsim.run_trace_engine(r.engine, tr, ())      # warm (maybe a miss)
     before = jaxsim.cache_stats()
-    jaxsim.run_trace_arrays(tr)                    # definitely a hit
+    jaxsim.run_trace_engine(r.engine, tr, ())      # definitely a hit
     after = jaxsim.cache_stats()
     assert after["hits"] == before["hits"] + 1
     assert after["misses"] == before["misses"]
@@ -228,13 +237,14 @@ def test_recompile_warning_on_ledger():
 def test_ledger_round_trip_and_report(tmp_path):
     from repro.env import jaxsim
     from repro.obs import RunLedger, load_ledger_lines, use_ledger
-    dec = jaxsim.make_static_decider("mc")
-    tr = jaxsim.compile_trace(dec, lam=3.0, seed=0, n_intervals=4,
+    r = jaxsim.resolve("mc")
+    tr = jaxsim.compile_trace(r.decider, lam=3.0, seed=0, n_intervals=4,
                               substeps=3)
     led = RunLedger("round-trip")
     led.stamp(telemetry="interval")
     with use_ledger(led):
-        out = jaxsim.run_trace_arrays(tr, telemetry="interval")
+        out = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed),
+                                      telemetry="interval")
         led.add_series("trace", out["telemetry"]["cols"],
                        out["telemetry"]["series"])
         led.add_cache_stats(jaxsim.cache_stats())

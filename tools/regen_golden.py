@@ -68,10 +68,10 @@ def theta_fingerprint(theta):
 def compute_static():
     """Golden case 1: static mixed-decision BestFit trace."""
     from repro.env import jaxsim
-    dec = jaxsim.make_static_decider("bestfit-rr")
-    tr = jaxsim.compile_trace(dec, lam=5.0, seed=0, n_intervals=8,
+    r = jaxsim.resolve("bestfit-rr")
+    tr = jaxsim.compile_trace(r.decider, lam=5.0, seed=0, n_intervals=8,
                               substeps=4)
-    out = jaxsim.run_trace_arrays(tr)
+    out = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed))
     return {"case": "static bestfit-rr lam=5 seed=0 T=8 substeps=4",
             "summary": {k: float(v) for k, v in out.items()}}
 
@@ -84,8 +84,9 @@ def compute_train():
     tr = jaxsim.compile_trace_dual(lam=5.0, seed=3, n_intervals=12,
                                    substeps=4)
     theta, cfg = _daso(50)
-    out = jaxsim.run_trace_arrays_trained(tr, st, daso_theta=theta,
-                                          daso_cfg=cfg)
+    r = jaxsim.resolve("splitplace", mode="train", mab_state=st,
+                       daso_theta=theta, daso_cfg=cfg)
+    out = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed))
     theta_fin = out.pop("daso_theta")
     return {"case": "train splitplace lam=5 seed=3 T=12 substeps=4",
             "summary": {k: float(v) for k, v in out.items()},
@@ -101,7 +102,8 @@ def compute_gillis():
     tr = jaxsim.compile_trace_dual(lam=5.0, seed=2, n_intervals=12,
                                    substeps=4,
                                    variants=(LAYER, COMPRESSED))
-    out = jaxsim.run_trace_arrays_gillis(tr)
+    r = jaxsim.resolve("gillis")
+    out = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed))
     q = out.pop("gillis_q")
     return {"case": "gillis lam=5 seed=2 T=12 substeps=4",
             "summary": {k: float(v) for k, v in out.items()},
@@ -117,9 +119,9 @@ def compute_gobi():
     theta, cfg = _daso(50)
     tr = jaxsim.compile_trace_dual(lam=5.0, seed=4, n_intervals=10,
                                    substeps=4)
-    out = jaxsim.run_trace_arrays_learned(
-        tr, st, daso_theta=theta,
-        daso_cfg=cfg._replace(decision_aware=False))
+    r = jaxsim.resolve("mab+gobi", mab_state=st, daso_theta=theta,
+                       daso_cfg=cfg)
+    out = jaxsim.run_trace_engine(r.engine, tr, r.state(tr.seed))
     return {"case": "deploy mab+gobi lam=5 seed=4 T=10 substeps=4",
             "summary": {k: float(v) for k, v in out.items()}}
 
